@@ -15,6 +15,7 @@ import argparse
 import numpy as np
 
 from repro.core import AifConfig
+from repro.compile_cache import enable_compile_cache
 from repro.envsim import AifRouter, SimConfig, evaluate_strategy, table1
 
 
@@ -39,6 +40,7 @@ def run(duration_s: float, n_runs: int) -> None:
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--duration", type=float, default=600.0)
     ap.add_argument("--runs", type=int, default=2)

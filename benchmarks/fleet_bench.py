@@ -77,6 +77,7 @@ import jax
 import jax.numpy as jnp
 
 from repro import api
+from repro.compile_cache import enable_compile_cache
 from repro.core import AifConfig, fleet
 from repro.envsim import SimConfig, batched, scenarios
 
@@ -626,8 +627,14 @@ def run(quick: bool = False, use_pallas: bool = False,
         rows.append(bench_fleet(16, 60, fused=True, use_pallas=True,
                                 scenario=scenario))
         _print_row(rows[-1])
-        rows.append(bench_mega(4, 20, use_pallas=True, scenario=scenario))
-        _print_row(rows[-1])
+        sc = scenarios.build_scenario(scenario, SimConfig(), 4, 20)
+        if sc.forced_down is None and sc.speed is None:
+            rows.append(bench_mega(4, 20, use_pallas=True,
+                                   scenario=scenario))
+            _print_row(rows[-1])
+        else:
+            print(f"fleet_mega_pallas: not run — {scenario!r} injects "
+                  f"faults, which the Pallas megakernel does not implement")
     return rows
 
 
@@ -720,6 +727,7 @@ def _bench_summary(rows: list[dict], existing: dict | None = None,
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--quick", action="store_true",
                     help="CI smoke subset (acceptance workload only)")

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import AifConfig, generative, policies, spaces
+from repro.compile_cache import enable_compile_cache
 from repro.kernels.attention.ref import decode_ref, mha_ref
 from repro.kernels.efe.ops import fleet_efe
 from repro.kernels.ssd.ref import ssd_ref
@@ -99,6 +100,7 @@ def run(quick: bool = False) -> list[tuple[str, float, str]]:
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--quick", action="store_true",
                     help="smaller problem sizes (CI smoke step)")

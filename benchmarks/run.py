@@ -9,8 +9,11 @@ import argparse
 import os
 import time
 
+from repro.compile_cache import enable_compile_cache
+
 
 def main(argv=None) -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true")
     ap.add_argument("--skip-routing", action="store_true")

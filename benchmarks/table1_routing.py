@@ -24,6 +24,8 @@ import argparse
 import json
 import time
 
+from repro.compile_cache import enable_compile_cache
+
 
 def run_event(duration_s: float, n_runs: int, out_json: str | None = None,
               strategies: tuple = ("aif", "uniform", "capacity",
@@ -102,6 +104,7 @@ def run_batched(routers: tuple[str, ...], scenario_names: tuple[str, ...],
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--engine", choices=("event", "batched"),
                     default="batched",

@@ -40,7 +40,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.api.router import Router, RouterObs
@@ -407,6 +406,13 @@ def _rollout_core(carry0,
     t00 = (jnp.asarray(0, jnp.int32) if t_begin is None
            else jnp.asarray(t_begin, jnp.int32))
     k_slow0 = jax.random.split(key, r)   # dummy; overwritten every tick
+    if rows is not None:
+        # under shard_map every per-cell carry varies across the mesh (as
+        # the shard's row offset does); the scan needs the initial carry
+        # typed that way too
+        (carry0, env_state, obs0, util0, up0, queue0, mask0, k_slow0,
+         stats0) = _vary((carry0, env_state, obs0, util0, up0, queue0,
+                          mask0, k_slow0, stats0), jax.typeof(rows[0]).vma)
     carry = (carry0, env_state, obs0, util0, up0, queue0, mask0, key, k_slow0,
              stats0)
     traces = []
@@ -832,9 +838,9 @@ def _sharded_impl(env_state,
             rows=(row0, n_cells, r_pad), reducer=reducer, stats0=stats0)
         return carry[0], carry[1], reducer.finalize(carry[-1], axis)
 
-    return shard_map(body, mesh=mesh,
-                     in_specs=(P(axis), P()),
-                     out_specs=(P(axis), P(axis), P()))(env_state, key)
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(P(axis), P()),
+                         out_specs=(P(axis), P(axis), P()))(env_state, key)
 
 
 @functools.partial(jax.jit,
@@ -937,7 +943,10 @@ def _sharded_mega_impl(env_state,
             stats = reducer.update_window(stats, t_start, tr)
             return (state, est, obs, k, stats)
 
-        carry = (state0, est, obs0, k, stats0)
+        # the per-shard carry varies across the mesh from the first window
+        # on; the scan needs it typed that way from the start
+        carry = (*_vary((state0, est, obs0), (axis,)), k,
+                 _vary(stats0, (axis,)))
         n_periods, n_rem = divmod(n_steps, period)
         if n_periods:
             def period_body(c, p_idx):
@@ -951,12 +960,21 @@ def _sharded_mega_impl(env_state,
         state, est_out, _, _, stats = carry
         return state, est_out, reducer.finalize(stats, axis)
 
-    return shard_map(body, mesh=mesh,
-                     in_specs=(P(axis), P(), P(), P(), P(), P(), P(), P(),
-                               P()),
-                     out_specs=(P(axis), P(axis), P()))(
-                         env_state, key, params, arrival, hazard, obs_valid,
-                         forced_down, speed, graph)
+    return jax.shard_map(
+        body, mesh=mesh,
+        in_specs=(P(axis), P(), P(), P(), P(), P(), P(), P(), P()),
+        out_specs=(P(axis), P(axis), P()))(
+            env_state, key, params, arrival, hazard, obs_valid, forced_down,
+            speed, graph)
+
+
+def _vary(tree, axes):
+    """Type every leaf of a shard-local carry as varying over the mesh
+    ``axes`` (shard_map's scan and cond need carries that agree on it)."""
+    def cast(x):
+        missing = tuple(a for a in axes if a not in jax.typeof(x).vma)
+        return jax.lax.pcast(x, missing, to="varying") if missing else x
+    return jax.tree_util.tree_map(cast, tree)
 
 
 # ------------------------------------------------------- checkpointed chunking
@@ -1149,8 +1167,8 @@ def _sharded_finalize_impl(stats, *, spec, reducer):
         local = jax.tree_util.tree_map(lambda a: a[0], s)
         return reducer.finalize(local, axis)
 
-    return shard_map(body, mesh=mesh, in_specs=(P(axis),),
-                     out_specs=P())(stats)
+    return jax.shard_map(body, mesh=mesh, in_specs=(P(axis),),
+                         out_specs=P())(stats)
 
 
 @functools.partial(jax.jit,
@@ -1213,7 +1231,8 @@ def _sharded_chunk_impl(env_state,
         stats_out = jax.tree_util.tree_map(lambda a: a[None], carry[-1])
         return carry[0], carry[1], obs_out, stats_out
 
-    return shard_map(body, mesh=mesh,
-                     in_specs=(P(axis), P(), P(), P(axis), P(axis), P(axis)),
-                     out_specs=(P(axis), P(axis), P(axis), P(axis)))(
-                         env_state, key, t_begin, carry_in, obs_in, stats_in)
+    return jax.shard_map(
+        body, mesh=mesh,
+        in_specs=(P(axis), P(), P(), P(axis), P(axis), P(axis)),
+        out_specs=(P(axis), P(axis), P(axis), P(axis)))(
+            env_state, key, t_begin, carry_in, obs_in, stats_in)

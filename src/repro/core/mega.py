@@ -47,6 +47,7 @@ rollout-parity tests at 1e-4 (actions bit-equal).
 """
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple
 
 import jax
@@ -57,6 +58,12 @@ from repro.core import agent as agent_mod
 from repro.core import belief as belief_mod
 from repro.core import generative, learning, policies, preferences, spaces
 from repro.envsim import batched
+
+# Contractions run in float32 on every backend: a TPU's default float32
+# matmul is one bfloat16 pass, which would round the beliefs, slot payloads
+# and one-hot count gathers this oracle is the reference for.
+_einsum = functools.partial(jnp.einsum,
+                            precision=jax.lax.Precision.HIGHEST)
 
 
 class MegaSlots(NamedTuple):
@@ -199,10 +206,10 @@ def _refresh_cache(a_counts: jnp.ndarray, slots: MegaSlots,
         col0 = cfg.b_prior_uniform + cfg.b_prior_sticky
     else:
         col0 = jnp.sum(b_base, axis=-2)                           # (R, A, S)
-    colsum = col0 + jnp.einsum("rja,rjs->ras",
+    colsum = col0 + _einsum("rja,rjs->ras",
                                coefact * sumqn[..., None], qp)
     proj, projsum, logna = _a_cache(a_counts, topo)
-    qnproj = jnp.einsum("rps,rjs->rjp", proj, qn)
+    qnproj = _einsum("rps,rjs->rjp", proj, qn)
     return MegaCache(colsum=colsum, proj=proj, projsum=projsum,
                      qnproj=qnproj, sumqn=sumqn, coefw=coefw,
                      coefact=coefact, logna=logna, b_base=b_base)
@@ -230,13 +237,13 @@ def _advance_cache(cache: MegaCache, a_counts: jnp.ndarray,
     w = learning.settle_weight(dt_b, cfg) * valid                 # (R, n)
     oh = jax.nn.one_hot(action_b, a_n, dtype=jnp.float32) * w[..., None]
     sumqn_b = jnp.sum(q_next_b, axis=-1)                          # (R, n)
-    d_col = cfg.alpha_b * jnp.einsum("rna,rns->ras",
+    d_col = cfg.alpha_b * _einsum("rna,rns->ras",
                                      oh * sumqn_b[..., None], q_prev_b)
     qn = slots.q_next.astype(jnp.float32)
     coefw, coefact = slot_coefficients(slots, cfg, a_n)
     sumqn = jnp.sum(qn, axis=-1)
     proj, projsum, logna = _a_cache(a_counts, topo)
-    qnproj = jnp.einsum("rps,rjs->rjp", proj, qn)
+    qnproj = _einsum("rps,rjs->rjp", proj, qn)
     return MegaCache(colsum=cache.colsum + d_col, proj=proj,
                      projsum=projsum, qnproj=qnproj, sumqn=sumqn,
                      coefw=coefw, coefact=coefact, logna=logna,
@@ -373,8 +380,8 @@ def factored_prior(cache: MegaCache, slots: MegaSlots, belief: jnp.ndarray,
     qt = belief / csum
     cw = jnp.take_along_axis(
         cache.coefact, prev_action[:, None, None], axis=2)[..., 0]  # (R, J)
-    pend = cw * jnp.einsum("rjs,rs->rj", qp, qt)
-    slot_term = jnp.einsum("rj,rjt->rt", pend, qn)
+    pend = cw * _einsum("rjs,rs->rj", qp, qt)
+    slot_term = _einsum("rj,rjt->rt", pend, qn)
     if cache.b_base is None:
         u = cfg.b_prior_uniform / s
         d = cfg.b_prior_sticky
@@ -382,7 +389,7 @@ def factored_prior(cache: MegaCache, slots: MegaSlots, belief: jnp.ndarray,
     else:
         brow = jnp.take_along_axis(
             cache.b_base, prev_action[:, None, None, None], axis=1)[:, 0]
-        num = jnp.einsum("rts,rs->rt", brow, qt) + slot_term
+        num = _einsum("rts,rs->rt", brow, qt) + slot_term
     return num / jnp.maximum(jnp.sum(num, -1, keepdims=True), 1e-30)
 
 
@@ -406,20 +413,20 @@ def factored_efe(cache: MegaCache, slots: MegaSlots, q: jnp.ndarray,
 
     qa = q[:, None, :] / cache.colsum                             # (R, A, S)
     sqa = jnp.sum(qa, axis=-1)                                    # (R, A)
-    dots = jnp.einsum("rjs,ras->rja", qp, qa)                     # (R, J, A)
+    dots = _einsum("rjs,ras->rja", qp, qa)                     # (R, J, A)
     pend = cache.coefact * dots
-    slot_o = jnp.einsum("rja,rjp->rap", pend, cache.qnproj)       # (R, A, P)
-    slot_den = jnp.einsum("rja,rj->ra", pend, cache.sumqn)
+    slot_o = _einsum("rja,rjp->rap", pend, cache.qnproj)       # (R, A, P)
+    slot_den = _einsum("rja,rj->ra", pend, cache.sumqn)
     if cache.b_base is None:
         u = cfg.b_prior_uniform / s
         d = cfg.b_prior_sticky
         o_num = (u * sqa[:, :, None] * cache.projsum[:, None, :]
-                 + d * jnp.einsum("rps,ras->rap", cache.proj, qa)
+                 + d * _einsum("rps,ras->rap", cache.proj, qa)
                  + slot_o)
         sden = jnp.maximum((u * s + d) * sqa + slot_den, 1e-30)
     else:
-        s_num = jnp.einsum("rats,ras->rat", cache.b_base, qa)     # (R, A, S)
-        o_num = jnp.einsum("rpt,rat->rap", cache.proj, s_num) + slot_o
+        s_num = _einsum("rats,ras->rat", cache.b_base, qa)     # (R, A, S)
+        o_num = _einsum("rpt,rat->rap", cache.proj, s_num) + slot_o
         sden = jnp.maximum(jnp.sum(s_num, axis=-1) + slot_den, 1e-30)
     o_pred = o_num / sden[..., None]
 
@@ -645,7 +652,7 @@ def mega_slow_step(state: MegaFleetState, k_slow: jax.Array,
     dt_b = jnp.take_along_axis(slots.dt_since_change, idx, axis=1)
     onehot = spaces.one_hot_observation(ob_b, topo.max_bins)     # (R,n,M,NB)
     wgt = onehot * valid[..., None, None] * om_b[..., None]
-    a_counts = state.a_counts + cfg.alpha_a * jnp.einsum(
+    a_counts = state.a_counts + cfg.alpha_a * _einsum(
         "rnmb,rns->rmbs", wgt, qn_b)
 
     # slot-hit counts: the B update's sufficient statistic
@@ -777,7 +784,7 @@ def to_agent_state(state: MegaFleetState,
     # one action at a time keeps the peak temp at (R, J, S) not (R, A, S, S)
     b_counts = jnp.stack(
         [base_rows[a]
-         + jnp.einsum("rj,rjt,rjs->rts", coefact[:, :, a], qn, qp)
+         + _einsum("rj,rjt,rjs->rts", coefact[:, :, a], qn, qp)
          for a in range(a_n)], axis=1)
 
     cap = cfg.replay_capacity

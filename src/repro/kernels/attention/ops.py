@@ -6,14 +6,9 @@ validation, not speed).
 """
 from __future__ import annotations
 
-import jax
-
 from repro.kernels.attention.flash import flash_decode, flash_prefill
 from repro.kernels.attention.ref import decode_ref, mha_ref
-
-
-def on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+from repro.kernels.efe.ops import on_tpu
 
 
 def attention(q, k, v, *, causal: bool = True, window: int = 0,

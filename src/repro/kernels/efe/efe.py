@@ -3,115 +3,162 @@
 The paper's action-selection hot loop — ``B_a·q → A·ŝ → risk/ambiguity`` —
 batched over a fleet of R routers (one per service cell) at 1 Hz.  Per
 (router-block, action) grid step the kernel keeps one action's transition
-tile (BR, S̄, S̄) in VMEM, does the batched mat-vec on the MXU, and fuses the
-observation projection + risk/ambiguity reductions so predicted
-state/observation distributions never round-trip to HBM.
+tile (BR, S, S) in VMEM and fuses the mat-vec, the observation projection
+and the risk/ambiguity reductions, so predicted state/observation
+distributions never round-trip to HBM.
 
 Every dimension derives from the input shapes, which in turn derive from the
-:class:`~repro.core.topology.Topology`: the state count S is padded to the
-next lane-width multiple S̄ (243 → 256 for the paper's 3-tier topology,
-128 → 128 for the binary-level 5-tier preset), and the router block size is
-chosen so the B tile stays well under the VMEM budget.
+:class:`~repro.core.topology.Topology`.  The state axis keeps its true width
+S (243 for the paper's 3-tier topology: blocks that span a whole axis may be
+unaligned, and Mosaic masks the partial lane tile), so the (R, A, S, S)
+transition stack is read in place, never padded or copied.  The fleet axis
+R is padded to the router block, a multiple of the 8 sublanes (padded rows
+repeat the last router and are cropped from the result).
 
-VMEM budget at BR=8, S̄=256: B tile 8·256·256·4B = 2.1 MB (+ small operands)
-— comfortably under the ~16 MB/core budget, with the (S̄×S̄) mat-vec dims
-128-aligned for the MXU.
+TPU layout: the cell axis sits on the sublane dim of every block, never on
+the lane dim; the (M, NB) observation axes are flattened in the wrapper
+(Mosaic cannot reshape an unaligned tiled dim); the per-action cost lives
+in SMEM and G is accumulated into one full-width (BR, A) output block
+across the sequential action axis.  The mat-vecs are VPU multiply-and-sum
+reductions in float32, so no MXU precision mode enters the result.
+
+VMEM at BR=8, S=243: the B tile is 8·243·256·4 B = 2.0 MB (lane-padded),
+double-buffered, plus small operands — well inside the default scoped limit.
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-_LANES = 128         # TPU lane width: pad S to a multiple of this
+_LANES = 128         # TPU lane width (VMEM tiles pad the minor dim to it)
+SUBLANES = 8         # TPU sublane count: router blocks are multiples of this
 
-# Keep the per-step B tile at or below this many bytes when auto-sizing BR.
-_VMEM_TILE_BUDGET = 4 * 1024 * 1024
-
-
-def pad_states(s: int) -> int:
-    """S rounded up to the lane-width multiple the kernel tiles on."""
-    return max(_LANES, -(-s // _LANES) * _LANES)
+#: Per-core VMEM of a TPU v5e (and v6e) TensorCore; the scoped limit a
+#: kernel may request is capped below it.
+VMEM_CAPACITY = 128 * 1024 * 1024
 
 
-def default_block_r(r: int, s: int) -> int:
-    """Largest power-of-two router block that divides R and fits the VMEM
-    tile budget for this topology's padded state count."""
-    s_pad = pad_states(s)
-    budget = max(1, _VMEM_TILE_BUDGET // (s_pad * s_pad * 4))
-    br = 1
-    while br * 2 <= min(budget, 8) and r % (br * 2) == 0:
-        br *= 2
-    return br
+def pad_rows(r: int, block_r: int = SUBLANES) -> int:
+    """R rounded up to a whole number of router blocks."""
+    return -(-r // block_r) * block_r
 
 
-def _efe_compute(b, q, a_norm, logc, amb, cost, maskb=None):
+def pad_cells(x: jnp.ndarray, r_pad: int, axis: int = 0) -> jnp.ndarray:
+    """Pad the cell axis to ``r_pad`` rows by repeating the last router.
+
+    Padded rows are finite copies of a real router (every op in these
+    kernels is row-local), and the wrappers crop them from the results.
+    """
+    extra = r_pad - x.shape[axis]
+    if extra == 0:
+        return x
+    widths = [(0, 0)] * x.ndim
+    widths[axis] = (0, extra)
+    return jnp.pad(x, widths, mode="edge")
+
+
+def block_vmem_bytes(shape, dtype) -> int:
+    """VMEM footprint of one block: the two minor dims padded to the
+    (sublane, lane) tile of ``dtype``."""
+    itemsize = jnp.dtype(dtype).itemsize
+    sub = SUBLANES * 4 // itemsize
+    shape = (1,) * max(0, 2 - len(shape)) + tuple(shape)
+    *lead, s2, s1 = shape
+    return (math.prod(lead) * (-(-s2 // sub) * sub)
+            * (-(-s1 // _LANES) * _LANES) * itemsize)
+
+
+def _efe_compute(b, q, a_proj, logc, amb, cost, maskb=None):
     """Shared EFE math for one (router-block, action) tile.
 
-    b: (BR, S̄, S̄) transition tile, q: (BR, S̄) beliefs,
-    a_norm: (BR, M, NB, S̄), logc: (BR, M, NB), amb: (BR, S̄),
-    cost: () this action's Cost(a), maskb: optional (BR, M, NB)
+    b: (BR, S, S) transition tile, q: (BR, S) beliefs,
+    a_proj: (BR, M·NB, S) observation rows, logc: (BR, M·NB), amb: (BR, S),
+    cost: () this action's Cost(a), maskb: optional (BR, M·NB)
     observation-validity mask broadcast over bins — masked modalities drop
     out of the risk reduction (ambiguity masking happens upstream via the
-    effective ``amb`` operand).  Returns G (BR,).
+    effective ``amb`` operand).  Returns G (BR, 1).
     """
-    # ŝ_a = B_a q — batched mat-vec on the MXU.
-    s_pred = jax.lax.dot_general(
-        b, q[..., None],
-        dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)[..., 0]    # (BR, S̄)
+    # ŝ_a = B_a q: VPU multiply + lane reduction per predicted state
+    s_pred = jnp.sum(b * q[:, None, :], axis=-1)               # (BR, S)
     s_pred = s_pred / jnp.maximum(
         jnp.sum(s_pred, axis=-1, keepdims=True), 1e-30)
-
-    # ô_m = A_m ŝ_a for every modality/bin.
-    br, m, nb, s = a_norm.shape
-    o_pred = jax.lax.dot_general(
-        a_norm.reshape(br, m * nb, s), s_pred[..., None],
-        dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)[..., 0]    # (BR, M·NB)
+    # ô_m = A_m ŝ_a for every modality/bin
+    o_pred = jnp.sum(a_proj * s_pred[:, None, :], axis=-1)     # (BR, M·NB)
 
     terms = jnp.where(
         o_pred > 1e-20,
-        o_pred * (jnp.log(jnp.maximum(o_pred, 1e-30))
-                  - logc.reshape(br, m * nb)),
+        o_pred * (jnp.log(jnp.maximum(o_pred, 1e-30)) - logc),
         0.0)
     if maskb is not None:
-        terms = terms * maskb.reshape(br, m * nb)
-    risk = jnp.sum(terms, axis=-1)                    # (BR,)
-
-    ambiguity = jnp.sum(s_pred * amb, axis=-1)
+        terms = terms * maskb
+    risk = jnp.sum(terms, axis=-1, keepdims=True)              # (BR, 1)
+    ambiguity = jnp.sum(s_pred * amb, axis=-1, keepdims=True)
     return risk + ambiguity + cost
+
+
+def _store_action_column(out_ref, g):
+    """Write G (BR, 1) into column ``a`` of the resident (BR, A) block (the
+    action axis is the innermost, sequential grid dimension)."""
+    a = pl.program_id(1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, out_ref.shape, 1)
+    out_ref[...] = jnp.where(lane == a, g, out_ref[...])
 
 
 def _efe_kernel(b_ref, q_ref, a_ref, logc_ref, amb_ref, cost_ref, out_ref):
     """One (router-block, action) grid step.
 
-    b_ref:    (BR, 1, S̄, S̄)   transition tile for this action
-    q_ref:    (BR, S̄)          beliefs
-    a_ref:    (BR, M, NB, S̄)   observation model
-    logc_ref: (BR, M, NB)      log-preferences
-    amb_ref:  (BR, S̄)          per-state ambiguity
-    cost_ref: (1, 1)           this action's Cost(a)
-    out_ref:  (BR, 1)          G(r, a)
+    b_ref:    (BR, 1, S, S)    transition tile for this action
+    q_ref:    (BR, S)          beliefs
+    a_ref:    (BR, M·NB, S)    observation model rows
+    logc_ref: (BR, M·NB)       log-preferences
+    amb_ref:  (BR, S)          per-state ambiguity
+    cost_ref: (1, A) SMEM      Cost(a) of every action
+    out_ref:  (BR, A)          G, resident across the action axis
     """
-    out_ref[:, 0] = _efe_compute(b_ref[:, 0], q_ref[...], a_ref[...],
-                                 logc_ref[...], amb_ref[...], cost_ref[0, 0])
+    g = _efe_compute(b_ref[:, 0], q_ref[...], a_ref[...], logc_ref[...],
+                     amb_ref[...], cost_ref[0, pl.program_id(1)])
+    _store_action_column(out_ref, g)
 
 
 def _efe_kernel_masked(b_ref, q_ref, a_ref, logc_ref, mask_ref, amb_ref,
                        cost_ref, out_ref):
     """Mask-aware twin of :func:`_efe_kernel`.
 
-    mask_ref: (BR, M, NB) per-modality observation-validity, pre-broadcast
+    mask_ref: (BR, M·NB) per-modality observation-validity, pre-broadcast
     over bins; the ``amb`` operand is expected to already be the
     mask-effective ambiguity (see ``repro.core.generative.masked_ambiguity``).
     """
-    out_ref[:, 0] = _efe_compute(b_ref[:, 0], q_ref[...], a_ref[...],
-                                 logc_ref[...], amb_ref[...], cost_ref[0, 0],
-                                 maskb=mask_ref[...])
+    g = _efe_compute(b_ref[:, 0], q_ref[...], a_ref[...], logc_ref[...],
+                     amb_ref[...], cost_ref[0, pl.program_id(1)],
+                     maskb=mask_ref[...])
+    _store_action_column(out_ref, g)
+
+
+def _fleet_operands(b_norm, q, a_norm, logc, amb, obs_mask, block_r):
+    """Pad R to the router block, flatten (M, NB)."""
+    r = b_norm.shape[0]
+    m, nb = a_norm.shape[1], a_norm.shape[2]
+    r_pad = pad_rows(r, block_r)
+    a_proj = a_norm.reshape(r, m * nb, -1)
+    logc = logc.reshape(r, m * nb)
+    maskb = (None if obs_mask is None else jnp.repeat(
+        obs_mask.astype(jnp.float32), nb, axis=-1))          # (R, M·NB)
+    cells = [x.astype(jnp.float32)
+             for x in (b_norm, q, a_proj, logc, amb)]
+    if maskb is not None:
+        cells.append(maskb)
+    return r_pad, [pad_cells(x, r_pad) for x in cells]
+
+
+def _check_block_r(block_r: int) -> None:
+    if block_r <= 0 or block_r % SUBLANES:
+        raise ValueError(f"block_r must be a positive multiple of "
+                         f"{SUBLANES} (the TPU sublane count), got {block_r}")
 
 
 @functools.partial(jax.jit, static_argnames=("block_r", "interpret"))
@@ -119,13 +166,13 @@ def efe_fleet_pallas(b_norm: jnp.ndarray, q: jnp.ndarray,
                      a_norm: jnp.ndarray, logc: jnp.ndarray,
                      amb: jnp.ndarray, cost: jnp.ndarray,
                      obs_mask: jnp.ndarray | None = None,
-                     *, block_r: int = 8,
+                     *, block_r: int = SUBLANES,
                      interpret: bool) -> jnp.ndarray:
     """G (R, A) for a fleet.  See ref.py for input semantics.
 
-    Shape-generic: works for any (R, A, S, S) / (R, M, NB, S) operands; S is
-    padded to the lane-width multiple internally.  ``block_r`` must divide R
-    (:func:`repro.kernels.efe.ops.fleet_efe` picks a valid one).
+    Shape-generic: works for any (R, A, S, S) / (R, M, NB, S) operands; R is
+    padded to a multiple of ``block_r`` (itself a multiple of 8)
+    internally.
 
     ``obs_mask`` ((R, M) float 0/1) selects the mask-aware kernel: masked
     modalities drop out of the risk reduction, and the ``amb`` operand must
@@ -136,57 +183,41 @@ def efe_fleet_pallas(b_norm: jnp.ndarray, q: jnp.ndarray,
     auto-detects the backend, so a direct caller can't silently run the
     interpret-mode emulator on a real TPU.
     """
+    _check_block_r(block_r)
     r, a, s, _ = b_norm.shape
-    m, nb = a_norm.shape[1], a_norm.shape[2]
-    assert r % block_r == 0, (r, block_r)
-    s_pad = pad_states(s)
-    pad = s_pad - s
-    if pad > 0:
-        b_norm = jnp.pad(b_norm, ((0, 0), (0, 0), (0, pad), (0, pad)))
-        q = jnp.pad(q, ((0, 0), (0, pad)))
-        a_norm = jnp.pad(a_norm, ((0, 0), (0, 0), (0, 0), (0, pad)))
-        amb = jnp.pad(amb, ((0, 0), (0, pad)))
-
-    grid = (r // block_r, a)
+    mnb = a_norm.shape[1] * a_norm.shape[2]
+    r_pad, operands = _fleet_operands(b_norm, q, a_norm, logc, amb,
+                                      obs_mask, block_r)
     bspec = [
-        pl.BlockSpec((block_r, 1, s_pad, s_pad), lambda i, j: (i, j, 0, 0)),
-        pl.BlockSpec((block_r, s_pad), lambda i, j: (i, 0)),
-        pl.BlockSpec((block_r, m, nb, s_pad), lambda i, j: (i, 0, 0, 0)),
-        pl.BlockSpec((block_r, m, nb), lambda i, j: (i, 0, 0)),
-        pl.BlockSpec((block_r, s_pad), lambda i, j: (i, 0)),
-        pl.BlockSpec((1, 1), lambda i, j: (0, j)),
+        pl.BlockSpec((block_r, 1, s, s), lambda i, j: (i, j, 0, 0)),
+        pl.BlockSpec((block_r, s), lambda i, j: (i, 0)),
+        pl.BlockSpec((block_r, mnb, s), lambda i, j: (i, 0, 0)),
+        pl.BlockSpec((block_r, mnb), lambda i, j: (i, 0)),
+        pl.BlockSpec((block_r, s), lambda i, j: (i, 0)),
     ]
-    operands = [b_norm.astype(jnp.float32), q.astype(jnp.float32),
-                a_norm.astype(jnp.float32), logc.astype(jnp.float32),
-                amb.astype(jnp.float32), cost.astype(jnp.float32)[None, :]]
     kernel = _efe_kernel
     if obs_mask is not None:
         kernel = _efe_kernel_masked
-        maskb = jnp.broadcast_to(
-            obs_mask.astype(jnp.float32)[:, :, None], (r, m, nb))
-        bspec.insert(4, pl.BlockSpec((block_r, m, nb),
-                                     lambda i, j: (i, 0, 0)))
-        operands.insert(4, maskb)
+        bspec.insert(4, pl.BlockSpec((block_r, mnb), lambda i, j: (i, 0)))
+        operands.insert(4, operands.pop())
+    bspec.append(pl.BlockSpec(memory_space=pltpu.SMEM))
+    operands.append(cost.astype(jnp.float32)[None, :])
     out = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(r_pad // block_r, a),
         in_specs=bspec,
-        out_specs=pl.BlockSpec((block_r, 1), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((r, a), jnp.float32),
+        out_specs=pl.BlockSpec((block_r, a), lambda i, j: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((r_pad, a), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(*operands)
-    return out
+    return out[:r]
 
 
 # ---------------------------------------------------------------------------
 # Fused belief update → EFE (one control tick, belief never leaves VMEM)
 # ---------------------------------------------------------------------------
-# Padded log-likelihood value for the padded state slots: large enough in
-# magnitude that exp(pad - max) flushes to exactly 0, small enough to stay
-# finite in f32 arithmetic.
-_LOGLIK_PAD = -1e9
-
-
 def _belief_update_into_scratch(bprev_ref, qprev_ref, ll_ref, qout_ref,
                                 q_scr):
     """Posterior (Eq. 2) at the first action step, parked in VMEM scratch.
@@ -202,12 +233,8 @@ def _belief_update_into_scratch(bprev_ref, qprev_ref, ll_ref, qout_ref,
 
     @pl.when(j == 0)
     def _():
-        bp = bprev_ref[...]                           # (BR, S̄, S̄)
-        qp = qprev_ref[...]                           # (BR, S̄)
-        prior = jax.lax.dot_general(
-            bp, qp[..., None],
-            dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)[..., 0]
+        prior = jnp.sum(bprev_ref[...] * qprev_ref[...][:, None, :],
+                        axis=-1)                               # (BR, S)
         prior = prior / jnp.maximum(
             jnp.sum(prior, axis=-1, keepdims=True), 1e-30)
         logp = ll_ref[...] + jnp.log(jnp.maximum(prior, 1e-30))
@@ -222,12 +249,12 @@ def _belief_efe_kernel(bprev_ref, qprev_ref, ll_ref, b_ref, a_ref, logc_ref,
                        amb_ref, cost_ref, g_ref, qout_ref, q_scr):
     """One (router-block, action) grid step of the fused tick.
 
-    bprev_ref: (BR, S̄, S̄)  previously-applied action's transition row
-    qprev_ref: (BR, S̄)      beliefs before the tick
-    ll_ref:    (BR, S̄)      observation log-likelihood (padded _LOGLIK_PAD)
+    bprev_ref: (BR, S, S)   previously-applied action's transition row
+    qprev_ref: (BR, S)      beliefs before the tick
+    ll_ref:    (BR, S)      observation log-likelihood
     b/a/logc/amb/cost/g:     as in :func:`_efe_kernel`
-    qout_ref:  (BR, S̄)      posterior after the tick (written once)
-    q_scr:     (BR, S̄)      VMEM scratch carrying q across action steps
+    qout_ref:  (BR, S)      posterior after the tick (written once)
+    q_scr:     (BR, S)      VMEM scratch carrying q across action steps
     """
     _belief_update_into_scratch(bprev_ref, qprev_ref, ll_ref, qout_ref, q_scr)
     _efe_kernel(b_ref, q_scr, a_ref, logc_ref, amb_ref, cost_ref, g_ref)
@@ -239,7 +266,7 @@ def _belief_efe_kernel_masked(bprev_ref, qprev_ref, ll_ref, b_ref, a_ref,
     """Mask-aware twin of :func:`_belief_efe_kernel`: the mask already zeroed
     the per-modality evidence feeding the VMEM-carried posterior (via
     ``ll_ref``), and additionally drops masked modalities from the EFE risk
-    reduction (``mask_ref``, (BR, M, NB)) — the ``amb`` operand carries the
+    reduction (``mask_ref``, (BR, M·NB)) — the ``amb`` operand carries the
     mask-effective ambiguity."""
     _belief_update_into_scratch(bprev_ref, qprev_ref, ll_ref, qout_ref, q_scr)
     _efe_kernel_masked(b_ref, q_scr, a_ref, logc_ref, mask_ref, amb_ref,
@@ -252,7 +279,7 @@ def belief_efe_fleet_pallas(b_prev: jnp.ndarray, q_prev: jnp.ndarray,
                             a_norm: jnp.ndarray, logc: jnp.ndarray,
                             amb: jnp.ndarray, cost: jnp.ndarray,
                             obs_mask: jnp.ndarray | None = None,
-                            *, block_r: int = 8,
+                            *, block_r: int = SUBLANES,
                             interpret: bool
                             ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Fused (belief update → EFE) tick: (G (R, A), posterior q (R, S)).
@@ -266,57 +293,46 @@ def belief_efe_fleet_pallas(b_prev: jnp.ndarray, q_prev: jnp.ndarray,
     :func:`efe_fleet_pallas`, ``interpret`` must be passed explicitly
     (the ops wrapper auto-detects the backend).
     """
+    _check_block_r(block_r)
     r, a, s, _ = b_norm.shape
-    m, nb = a_norm.shape[1], a_norm.shape[2]
-    assert r % block_r == 0, (r, block_r)
-    s_pad = pad_states(s)
-    pad = s_pad - s
-    if pad > 0:
-        b_prev = jnp.pad(b_prev, ((0, 0), (0, pad), (0, pad)))
-        q_prev = jnp.pad(q_prev, ((0, 0), (0, pad)))
-        loglik = jnp.pad(loglik, ((0, 0), (0, pad)),
-                         constant_values=_LOGLIK_PAD)
-        b_norm = jnp.pad(b_norm, ((0, 0), (0, 0), (0, pad), (0, pad)))
-        a_norm = jnp.pad(a_norm, ((0, 0), (0, 0), (0, 0), (0, pad)))
-        amb = jnp.pad(amb, ((0, 0), (0, pad)))
+    mnb = a_norm.shape[1] * a_norm.shape[2]
+    r_pad, efe_ops = _fleet_operands(b_norm, q_prev, a_norm, logc, amb,
+                                     obs_mask, block_r)
+    q_prev = efe_ops.pop(1)              # the EFE's q is the fused posterior
+    operands = [pad_cells(b_prev.astype(jnp.float32), r_pad), q_prev,
+                pad_cells(loglik.astype(jnp.float32), r_pad)] + efe_ops
 
-    grid = (r // block_r, a)
     bspec = [
-        pl.BlockSpec((block_r, s_pad, s_pad), lambda i, j: (i, 0, 0)),
-        pl.BlockSpec((block_r, s_pad), lambda i, j: (i, 0)),
-        pl.BlockSpec((block_r, s_pad), lambda i, j: (i, 0)),
-        pl.BlockSpec((block_r, 1, s_pad, s_pad),
-                     lambda i, j: (i, j, 0, 0)),
-        pl.BlockSpec((block_r, m, nb, s_pad), lambda i, j: (i, 0, 0, 0)),
-        pl.BlockSpec((block_r, m, nb), lambda i, j: (i, 0, 0)),
-        pl.BlockSpec((block_r, s_pad), lambda i, j: (i, 0)),
-        pl.BlockSpec((1, 1), lambda i, j: (0, j)),
+        pl.BlockSpec((block_r, s, s), lambda i, j: (i, 0, 0)),
+        pl.BlockSpec((block_r, s), lambda i, j: (i, 0)),
+        pl.BlockSpec((block_r, s), lambda i, j: (i, 0)),
+        pl.BlockSpec((block_r, 1, s, s), lambda i, j: (i, j, 0, 0)),
+        pl.BlockSpec((block_r, mnb, s), lambda i, j: (i, 0, 0)),
+        pl.BlockSpec((block_r, mnb), lambda i, j: (i, 0)),
+        pl.BlockSpec((block_r, s), lambda i, j: (i, 0)),
     ]
-    operands = [b_prev.astype(jnp.float32), q_prev.astype(jnp.float32),
-                loglik.astype(jnp.float32), b_norm.astype(jnp.float32),
-                a_norm.astype(jnp.float32), logc.astype(jnp.float32),
-                amb.astype(jnp.float32), cost.astype(jnp.float32)[None, :]]
     kernel = _belief_efe_kernel
     if obs_mask is not None:
         kernel = _belief_efe_kernel_masked
-        maskb = jnp.broadcast_to(
-            obs_mask.astype(jnp.float32)[:, :, None], (r, m, nb))
-        bspec.insert(6, pl.BlockSpec((block_r, m, nb),
-                                     lambda i, j: (i, 0, 0)))
-        operands.insert(6, maskb)
+        bspec.insert(6, pl.BlockSpec((block_r, mnb), lambda i, j: (i, 0)))
+        operands.insert(6, operands.pop())
+    bspec.append(pl.BlockSpec(memory_space=pltpu.SMEM))
+    operands.append(cost.astype(jnp.float32)[None, :])
     g, q = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(r_pad // block_r, a),
         in_specs=bspec,
         out_specs=[
-            pl.BlockSpec((block_r, 1), lambda i, j: (i, j)),
-            pl.BlockSpec((block_r, s_pad), lambda i, j: (i, 0)),
+            pl.BlockSpec((block_r, a), lambda i, j: (i, 0)),
+            pl.BlockSpec((block_r, s), lambda i, j: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((r, a), jnp.float32),
-            jax.ShapeDtypeStruct((r, s_pad), jnp.float32),
+            jax.ShapeDtypeStruct((r_pad, a), jnp.float32),
+            jax.ShapeDtypeStruct((r_pad, s), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((block_r, s_pad), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((block_r, s), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(*operands)
-    return g, q[:, :s]
+    return g[:r], q[:r]

@@ -24,30 +24,20 @@ import jax.numpy as jnp
 
 from repro.core import generative, policies
 from repro.core import mega as mega_core
-from repro.kernels.efe.efe import (belief_efe_fleet_pallas, default_block_r,
+from repro.kernels.efe.efe import (SUBLANES, belief_efe_fleet_pallas,
                                    efe_fleet_pallas)
 from repro.kernels.efe.ref import (belief_efe_fleet_ref, belief_posterior_ref,
                                    efe_fleet_ref)
 
 
-def largest_pow2_divisor(n: int) -> int:
-    """Largest power of two dividing ``n`` (1 for odd ``n``; ``n >= 1``)."""
-    return n & -n
+def on_tpu() -> bool:
+    """Whether the default backend is a TPU (compiled kernels) — elsewhere
+    the Pallas kernels run in interpret mode."""
+    return jax.default_backend() == "tpu"
 
 
 def _auto_interpret() -> bool:
-    from repro.kernels.attention.ops import on_tpu
     return not on_tpu()
-
-
-def _resolve_block_r(r: int, s: int, block_r: int | None) -> int:
-    if block_r is None:
-        br = default_block_r(r, s)
-    elif block_r > 0 and r % block_r == 0:
-        br = block_r
-    else:
-        br = min(largest_pow2_divisor(r), largest_pow2_divisor(block_r))
-    return max(br, 1)
 
 
 def _gather_prev_b(nb: jnp.ndarray, prev_action: jnp.ndarray) -> jnp.ndarray:
@@ -105,18 +95,16 @@ def fleet_efe_cached(nb: jnp.ndarray, na: jnp.ndarray, logc: jnp.ndarray,
         term).
       interpret: None (default) auto-detects — compiled kernel on TPU,
         interpret-mode emulation elsewhere (Pallas does not lower to CPU).
-      block_r: router block size; honored as-is when it divides R, else
-        reduced to the largest power-of-two divisor of R (1 for odd/prime
-        R, which degrades throughput but stays correct).  None picks a
-        power-of-two divisor within the kernel's VMEM budget.
+      block_r: router block size, a multiple of 8 (the TPU sublane
+        count); R is padded to a whole number of blocks.  None picks 8.
     """
     cost = cfg.cost_weight * policies.policy_concentration_cost(cfg.topology)
     if use_pallas:
         if interpret is None:
             interpret = _auto_interpret()
-        br = _resolve_block_r(beliefs.shape[0], beliefs.shape[-1], block_r)
         return efe_fleet_pallas(nb, beliefs, na, logc, amb, cost, obs_mask,
-                                block_r=br, interpret=interpret)
+                                block_r=block_r or SUBLANES,
+                                interpret=interpret)
     return efe_fleet_ref(nb, beliefs, na, logc, amb, cost, obs_mask)
 
 
@@ -148,10 +136,10 @@ def fleet_belief_efe(nb: jnp.ndarray, na: jnp.ndarray, logc: jnp.ndarray,
     if use_pallas:
         if interpret is None:
             interpret = _auto_interpret()
-        br = _resolve_block_r(beliefs.shape[0], beliefs.shape[-1], block_r)
         return belief_efe_fleet_pallas(b_prev, beliefs, loglik, nb, na,
                                        logc, amb, cost, obs_mask,
-                                       block_r=br, interpret=interpret)
+                                       block_r=block_r or SUBLANES,
+                                       interpret=interpret)
     return belief_efe_fleet_ref(b_prev, beliefs, loglik, nb, na, logc, amb,
                                 cost, obs_mask)
 
@@ -205,7 +193,10 @@ def mega_window(state, est, obs_carry, params,
     ``use_pallas`` the window runs as the Pallas megakernel
     (:mod:`repro.kernels.efe.mega`), which keeps the posterior, factored
     transition cache, preference tables and env carry resident in VMEM for
-    all W ticks.  Inputs/outputs are identical either way:
+    all W ticks.  Chaos (``forced_down``/``speed``), sharded (``row_block``)
+    and graph windows are not ported to the kernel: with ``use_pallas``
+    they raise ``ValueError`` instead of running the oracle.  Inputs/outputs
+    are identical either way:
 
       state:     :class:`repro.core.mega.MegaFleetState`.
       est:       batched env :class:`~repro.envsim.batched.FluidState`.
@@ -221,15 +212,21 @@ def mega_window(state, est, obs_carry, params,
     Returns ``(state, est, obs_carry, ys)`` with ys a per-tick trace tuple
     of (action, weights, raw_obs, unstable, obs_frac, env_window).
     """
-    # The Pallas megakernel's in-VMEM env port predates the fault-injection
-    # schedules and draws restart randomness at the local R (incompatible
-    # with the sharded engine's draw-at-true-R row_block contract), and its
-    # per-cell dataflow has no lane for the graph spillover's cross-cell
-    # segment-sum exchange; chaos, sharded and graph windows fall back to
-    # the XLA oracle (identical semantics, the oracle *is* the CPU
-    # production path).
-    if (use_pallas and forced_down is None and speed is None
-            and row_block is None and graph is None):
+    if use_pallas:
+        # The megakernel's in-VMEM env port predates the fault-injection
+        # schedules, draws restart randomness at the local R (not the
+        # sharded engine's draw-at-true-R row_block contract) and has no
+        # lane for the graph spillover's cross-cell exchange: those windows
+        # need the XLA oracle, which the caller selects with use_pallas=False.
+        unported = [name for name, v in (("forced_down", forced_down),
+                                         ("speed", speed),
+                                         ("row_block (sharded)", row_block),
+                                         ("graph", graph)) if v is not None]
+        if unported:
+            raise ValueError(
+                f"the Pallas megakernel does not implement "
+                f"{', '.join(unported)} windows; run them with "
+                f"use_pallas=False (the XLA mega oracle)")
         from repro.kernels.efe import mega as mega_kernel
         if interpret is None:
             interpret = _auto_interpret()

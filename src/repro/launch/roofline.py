@@ -141,8 +141,6 @@ def analyze(compiled, meta: dict, step: str, n_chips: int,
 
     # XLA's own numbers (scan bodies counted once — kept for reference).
     cost = compiled.cost_analysis()
-    if isinstance(cost, list):       # older jax returns [dict]
-        cost = cost[0]
     xla_flops = float(cost.get("flops", 0.0))
     xla_bytes = float(cost.get("bytes accessed", 0.0))
 

@@ -293,3 +293,33 @@ def test_compare_markdown_and_json():
     assert set(js) == {"steady", "flaky-telemetry"}
     assert set(js["steady"]) == {"uniform", "least_loaded"}
     assert js["flaky-telemetry"]["uniform"]["obs_frac"] < 1.0
+
+
+@pytest.mark.parametrize("env_dir", [None, "/elsewhere/jax-cache"],
+                         ids=["repo-default", "env-var"])
+def test_compile_cache_directory(monkeypatch, env_dir):
+    """The entry points' compile cache follows JAX_COMPILATION_CACHE_DIR
+    when it is set (and sets nothing itself), else the fixed in-checkout
+    ``.jax_cache/``."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from repro import compile_cache
+    was = jax.config.jax_compilation_cache_dir
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    try:
+        got = compile_cache.enable_compile_cache()
+        if env_dir is None:
+            assert got == str(compile_cache.REPO_CACHE_DIR)
+            assert compile_cache.REPO_CACHE_DIR.name == ".jax_cache"
+            assert (compile_cache.REPO_CACHE_DIR.parent
+                    / "pyproject.toml").exists()
+            assert jax.config.jax_compilation_cache_dir == got
+        else:
+            assert got == env_dir
+            assert jax.config.jax_compilation_cache_dir == was
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+        compilation_cache.reset_cache()

@@ -19,7 +19,7 @@ from repro.core import generative
 from repro.core import mega as mega_core
 from repro.core.topology import Topology, default_topology, five_tier_topology
 from repro.envsim import batched
-from repro.kernels.attention.ops import on_tpu
+from repro.kernels.efe.ops import on_tpu
 
 KEY = jax.random.key(0)
 
@@ -445,6 +445,19 @@ def test_mega_pallas_interpret_matches_oracle():
     r1 = run(Experiment(**base))
     r2 = run(Experiment(**base, use_pallas=True))
     _assert_rollouts_match(r1, r2)
+
+
+@pytest.mark.parametrize("kw,case", [
+    (dict(scenario="zone-outage"), "forced_down"),
+    (dict(scenario="ring-spillover"), "graph"),
+    (dict(shard=ShardSpec(devices=1)), "row_block"),
+], ids=["chaos", "graph", "sharded"])
+def test_mega_pallas_refuses_unported_windows(kw, case):
+    """``use_pallas=True`` never quietly runs the XLA oracle: a window the
+    megakernel does not implement raises and names the case."""
+    with pytest.raises(ValueError, match=case):
+        run(Experiment(router="aif", mega=True, use_pallas=True, n_cells=4,
+                       n_windows=10, **kw))
 
 
 @pytest.mark.skipif(not on_tpu(), reason="compiled Pallas megakernel needs "

@@ -12,7 +12,7 @@ from repro.core.topology import (PolicySpec, Topology, default_topology,
                                  five_tier_topology, get_topology)
 from repro.envsim import (SimConfig, batched, discretization_for, scenarios,
                           sim_config_for)
-from repro.kernels.efe.ops import fleet_efe, largest_pow2_divisor
+from repro.kernels.efe.ops import fleet_efe
 
 # The paper's hand-written 20-policy table (§4.1) — the pinned regression
 # target for the K=3 generator.
@@ -117,16 +117,14 @@ def test_efe_kernel_parity_across_topologies(topo, r):
 
 
 def test_block_size_fallback_pow2_divisor():
-    """Odd / prime R must resolve to a valid block size, never 0 (the old
-    ``while r % br: br //= 2`` spun to zero for odd R)."""
-    assert largest_pow2_divisor(7) == 1
-    assert largest_pow2_divisor(12) == 4
-    assert largest_pow2_divisor(256) == 256
+    """Odd / prime R run through the full wrapper: the fleet axis is padded
+    to whole 8-row router blocks (the TPU sublane tiling) and the padded
+    rows are cropped, and a block that is not a multiple of 8 is refused."""
+    topo = default_topology()
+    cfg = generative.AifConfig()
+    s, a = topo.n_states, policies.n_actions(topo)
+    m, nb = topo.n_modalities, topo.max_bins
     for r in (1, 7, 13):   # prime fleet sizes through the full wrapper
-        topo = default_topology()
-        cfg = generative.AifConfig()
-        s, a = topo.n_states, policies.n_actions(topo)
-        m, nb = topo.n_modalities, topo.max_bins
         key = jax.random.key(r)
         a_counts = (jax.random.uniform(key, (r, m, nb, s)) + 0.1
                     ) * spaces.bins_mask(topo)[None, :, :, None]
@@ -140,6 +138,9 @@ def test_block_size_fallback_pow2_divisor():
                           use_pallas=False)
         np.testing.assert_allclose(np.asarray(g_pal), np.asarray(g_ref),
                                    atol=1e-4)
+    with pytest.raises(ValueError, match="multiple of 8"):
+        fleet_efe(a_counts, b_counts, c_log, q, cfg, use_pallas=True,
+                  interpret=True, block_r=4)
 
 
 # -------------------------------------------------- golden bit-compatibility
