@@ -34,6 +34,7 @@ unsharded engine bit-for-bit.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 from typing import Callable
 
@@ -42,6 +43,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from repro import obs
 from repro.api.router import Router, RouterObs
 from repro.core import mega as mega_mod
 from repro.core.fleet import FleetTrace
@@ -103,9 +105,19 @@ def rollout(router: Router,
                    else router.clock_phase(carry))
     if obs_masked is None:
         obs_masked = bool(getattr(env_step, "emits_mask", False))
-    return _rollout_impl(carry, env_state, env_step, n_steps, key,
-                         router=router, obs_masked=obs_masked,
-                         clock_phase=clock_phase)
+    with _dispatch(1):
+        return _rollout_impl(carry, env_state, env_step, n_steps, key,
+                             router=router, obs_masked=obs_masked,
+                             clock_phase=clock_phase)
+
+
+@contextlib.contextmanager
+def _dispatch(launches: int):
+    """The ``repro.run.dispatch`` span around ``launches`` jitted launches
+    (they return once enqueued; the device work is waited for later)."""
+    obs.count("launches", launches)
+    with obs.span("run.dispatch", launches=launches):
+        yield
 
 
 def _row_block_keys(key: jax.Array, row_start: jnp.ndarray, n_true: int,
@@ -550,31 +562,30 @@ def _mega_rollout(router, carry, env_state, env_step: Callable, n_steps: int,
             t_begin = warm
     if obs_masked is None:
         obs_masked = bool(getattr(env_step, "emits_mask", False))
-    cfg = router.cfg
-    r = jax.tree_util.tree_leaves(env_state)[0].shape[0]
-    if state_in is None:
-        # slots are indexed by global tick, so a chunked run must size them
-        # to the *whole* horizon up front (n_total), not this chunk's —
-        # and a promoted run to the warm prefix plus its remaining horizon
-        slot_dtype = (jnp.bfloat16 if router.mega_slot_dtype == "bfloat16"
-                      else jnp.float32)
-        horizon = warm + (n_total if n_total is not None else n_steps)
-        state_in = mega_mod.init_mega_state(
-            cfg, r, horizon, slot_dtype=slot_dtype,
-            from_agent_state=(carry if warm else None))
     if warm and fl.arrival_rate.shape[0] < warm + n_steps:
         raise ValueError(
             f"warm mega promotion indexes the env schedules globally (same "
             f"world): need at least {warm + n_steps} scheduled ticks, got "
             f"{fl.arrival_rate.shape[0]} — build the env_step over the "
             f"full-run schedules")
-    if obs_carry is None:
-        m, k_tiers = router.n_modalities, router.n_tiers
-        obs_carry = (jnp.zeros((r, m), jnp.float32),
-                     jnp.zeros((r, k_tiers), jnp.float32),
-                     jnp.ones((r, k_tiers), jnp.float32),
-                     jnp.zeros((r, k_tiers), jnp.float32),
-                     jnp.ones((r, m), jnp.float32))
+    cfg = router.cfg
+    r = jax.tree_util.tree_leaves(env_state)[0].shape[0]
+    with obs.span("run.init"):
+        if state_in is None:
+            # slots are indexed by global tick, so a chunked run must size
+            # them to the *whole* horizon up front (n_total), not this
+            # chunk's — and a promoted run to the warm prefix plus its
+            # remaining horizon
+            slot_dtype = (jnp.bfloat16
+                          if router.mega_slot_dtype == "bfloat16"
+                          else jnp.float32)
+            horizon = warm + (n_total if n_total is not None else n_steps)
+            state_in = mega_mod.init_mega_state(
+                cfg, r, horizon, slot_dtype=slot_dtype,
+                from_agent_state=(carry if warm else None))
+        if obs_carry is None:
+            obs_carry = _fresh_obs_carry(r, router.n_modalities,
+                                         router.n_tiers)
 
     def launch(state, est, obs, k, tb, n):
         return _mega_impl(
@@ -585,7 +596,9 @@ def _mega_rollout(router, carry, env_state, env_step: Callable, n_steps: int,
             restart_blackout=fl.restart_blackout)
 
     if launch_periods is None:
-        return launch(state_in, env_state, obs_carry, key, t_begin, n_steps)
+        with _dispatch(1):
+            return launch(state_in, env_state, obs_carry, key, t_begin,
+                          n_steps)
     if int(launch_periods) < 1:
         raise ValueError(f"launch_periods must be >= 1, got {launch_periods}")
     # chunked super-launch: same windows, same key chain, same slot indices
@@ -595,17 +608,18 @@ def _mega_rollout(router, carry, env_state, env_step: Callable, n_steps: int,
     # recorded raw-telemetry floats can drift by ulps, since each chunk
     # shape compiles its own XLA program with different fusion.
     chunk = int(launch_periods) * period
-    state, est, obs, k = state_in, env_state, obs_carry, key
+    state, est, obs_c, k = state_in, env_state, obs_carry, key
     traces, c0 = [], 0
-    while c0 < n_steps:
-        n = min(chunk, n_steps - c0)
-        state, est, tr, (obs, k) = launch(state, est, obs, k,
-                                          t_begin + c0, n)
-        traces.append(tr)
-        c0 += n
-    trace = (traces[0] if len(traces) == 1 else jax.tree_util.tree_map(
-        lambda *xs: jnp.concatenate(xs, axis=0), *traces))
-    return state, est, trace, (obs, k)
+    with _dispatch(-(-n_steps // chunk)):
+        while c0 < n_steps:
+            n = min(chunk, n_steps - c0)
+            state, est, tr, (obs_c, k) = launch(state, est, obs_c, k,
+                                                t_begin + c0, n)
+            traces.append(tr)
+            c0 += n
+        trace = (traces[0] if len(traces) == 1 else jax.tree_util.tree_map(
+            lambda *xs: jnp.concatenate(xs, axis=0), *traces))
+    return state, est, trace, (obs_c, k)
 
 
 @functools.partial(jax.jit,
@@ -644,37 +658,35 @@ def _mega_impl(state,
 
     def window(carry, t_start, w_ticks: int, do_slow: bool):
         state, est, obs, k = carry
-        k, (k_env, k_fast, k_slow) = _key_block(k, w_ticks, r)
-        gum = jax.vmap(jax.vmap(
-            lambda kk: jax.random.gumbel(kk, (a_n,))))(k_fast)
-        arr_w = jax.lax.dynamic_slice_in_dim(arrival, t_start, w_ticks)
-        haz_w = jax.lax.dynamic_slice_in_dim(hazard, t_start, w_ticks)
-        ov_w = (None if obs_valid is None
-                else jax.lax.dynamic_slice_in_dim(obs_valid, t_start,
-                                                  w_ticks))
-        fd_w = (None if forced_down is None
-                else jax.lax.dynamic_slice_in_dim(forced_down, t_start,
-                                                  w_ticks))
-        sp_w = (None if speed is None
-                else jax.lax.dynamic_slice_in_dim(speed, t_start, w_ticks))
-        state, est, obs, ys = efe_ops.mega_window(
-            state, est, obs, params, arr_w, haz_w, ov_w, k_env, gum,
-            jnp.asarray(t_start, jnp.int32), forced_down=fd_w, speed=sp_w,
-            graph=graph, **statics)
+        with jax.named_scope("aif.window"):
+            with jax.named_scope("aif.window.draw"):
+                k, (k_env, k_fast, k_slow) = _key_block(k, w_ticks, r)
+                gum = jax.vmap(jax.vmap(
+                    lambda kk: jax.random.gumbel(kk, (a_n,))))(k_fast)
+                arr_w = jax.lax.dynamic_slice_in_dim(arrival, t_start,
+                                                     w_ticks)
+                haz_w = jax.lax.dynamic_slice_in_dim(hazard, t_start,
+                                                     w_ticks)
+                ov_w = (None if obs_valid is None
+                        else jax.lax.dynamic_slice_in_dim(obs_valid, t_start,
+                                                          w_ticks))
+                fd_w = (None if forced_down is None
+                        else jax.lax.dynamic_slice_in_dim(
+                            forced_down, t_start, w_ticks))
+                sp_w = (None if speed is None
+                        else jax.lax.dynamic_slice_in_dim(speed, t_start,
+                                                          w_ticks))
+            state, est, obs, ys = efe_ops.mega_window(
+                state, est, obs, params, arr_w, haz_w, ov_w, k_env, gum,
+                jnp.asarray(t_start, jnp.int32), forced_down=fd_w,
+                speed=sp_w, graph=graph, **statics)
         if do_slow:
             # the boundary tick's per-cell slow keys, as in the per-tick
             # engine's slow_after
             state = mega_mod.mega_slow_step(state, k_slow[-1], cfg)
         # numerical watchdog at window granularity: quarantine-and-reinit
         # diverged cells so the next window starts from priors
-        ev = jnp.zeros((w_ticks, r), jnp.float32)
-        if getattr(cfg, "watchdog", False):
-            bad = mega_mod.mega_watchdog_bad(state)
-            state = jax.lax.cond(
-                jnp.any(bad),
-                lambda s: mega_mod.mega_quarantine(s, bad, cfg),
-                lambda s: s, state)
-            ev = ev.at[-1].set(bad.astype(jnp.float32))
+        state, ev = _mega_watchdog(state, w_ticks, r, cfg)
         return (state, est, obs, k), ys + (ev,)
 
     carry = (state, env_state, obs_carry, key)
@@ -700,6 +712,23 @@ def _mega_impl(state,
                        raw_obs=raw_obs, unstable=unstable,
                        obs_frac=obs_frac, env=win, watchdog=wd)
     return state, est, trace, (obs, k)
+
+
+def _mega_watchdog(state, w_ticks: int, r: int, cfg):
+    """Window-granularity numerical watchdog: quarantine-and-reinit the
+    diverged cells so the next window starts from priors.  Returns the
+    state and the window's (W, R) event trace (the flags on its last
+    tick)."""
+    ev = jnp.zeros((w_ticks, r), jnp.float32)
+    if not getattr(cfg, "watchdog", False):
+        return state, ev
+    with jax.named_scope("aif.watchdog"):
+        bad = mega_mod.mega_watchdog_bad(state)
+        state = jax.lax.cond(
+            jnp.any(bad),
+            lambda s: mega_mod.mega_quarantine(s, bad, cfg),
+            lambda s: s, state)
+        return state, ev.at[-1].set(bad.astype(jnp.float32))
 
 
 # ------------------------------------------------------------- device sharding
@@ -785,18 +814,20 @@ def sharded_rollout(router: Router,
         if n_steps <= 0:
             raise ValueError("mega rollouts need n_steps >= 1")
         fl = env_step.fluid
-        return _sharded_mega_impl(
-            env_state, key, fl.params, fl.arrival_rate, fl.hazard_scale,
-            fl.obs_valid, fl.forced_down, fl.speed, fl.graph, router=router,
-            n_steps=n_steps, obs_masked=obs_masked, spec=shard,
-            n_cells=n_cells, reducer=reducer, dt=fl.dt,
-            scrape_every=fl.scrape_every,
-            restart_blackout=fl.restart_blackout)
+        with _dispatch(1):
+            return _sharded_mega_impl(
+                env_state, key, fl.params, fl.arrival_rate,
+                fl.hazard_scale, fl.obs_valid, fl.forced_down, fl.speed,
+                fl.graph, router=router, n_steps=n_steps,
+                obs_masked=obs_masked, spec=shard, n_cells=n_cells,
+                reducer=reducer, dt=fl.dt, scrape_every=fl.scrape_every,
+                restart_blackout=fl.restart_blackout)
     clock_phase = router.clock_phase(router.init_carry(1))
-    return _sharded_impl(env_state, key, router=router, env_step=env_step,
-                         n_steps=n_steps, obs_masked=obs_masked,
-                         clock_phase=clock_phase, spec=shard,
-                         n_cells=n_cells, reducer=reducer)
+    with _dispatch(1):
+        return _sharded_impl(env_state, key, router=router,
+                             env_step=env_step, n_steps=n_steps,
+                             obs_masked=obs_masked, clock_phase=clock_phase,
+                             spec=shard, n_cells=n_cells, reducer=reducer)
 
 
 @functools.partial(jax.jit,
@@ -899,43 +930,42 @@ def _sharded_mega_impl(env_state,
              graph):
         row0 = jax.lax.axis_index(axis) * r_local
         rows = (row0, n_cells, r_pad)
-        state0 = mega_mod.init_mega_state(cfg, r_local, n_steps,
-                                          slot_dtype=slot_dtype)
-        obs0 = _fresh_obs_carry(r_local, router.n_modalities, router.n_tiers)
+        with jax.named_scope("aif.init"):
+            state0 = mega_mod.init_mega_state(cfg, r_local, n_steps,
+                                              slot_dtype=slot_dtype)
+            obs0 = _fresh_obs_carry(r_local, router.n_modalities,
+                                    router.n_tiers)
         stats0 = reducer.init(r_local, row0)
 
         def window(carry, t_start, w_ticks: int, do_slow: bool):
             state, est, obs, k, stats = carry
-            k, (k_env, k_fast, k_slow) = _key_block(k, w_ticks, r_local,
-                                                    rows)
-            gum = jax.vmap(jax.vmap(
-                lambda kk: jax.random.gumbel(kk, (a_n,))))(k_fast)
-            arr_w = jax.lax.dynamic_slice_in_dim(arrival, t_start, w_ticks)
-            haz_w = jax.lax.dynamic_slice_in_dim(hazard, t_start, w_ticks)
-            ov_w = (None if obs_valid is None
-                    else jax.lax.dynamic_slice_in_dim(obs_valid, t_start,
-                                                      w_ticks))
-            fd_w = (None if forced_down is None
-                    else jax.lax.dynamic_slice_in_dim(forced_down,
-                                                      t_start, w_ticks))
-            sp_w = (None if speed is None
-                    else jax.lax.dynamic_slice_in_dim(speed, t_start,
-                                                      w_ticks))
-            state, est, obs, ys = efe_ops.mega_window(
-                state, est, obs, params, arr_w, haz_w, ov_w, k_env, gum,
-                jnp.asarray(t_start, jnp.int32), forced_down=fd_w,
-                speed=sp_w, row_block=rows, graph=graph, shard_axis=axis,
-                **statics)
+            with jax.named_scope("aif.window"):
+                with jax.named_scope("aif.window.draw"):
+                    k, (k_env, k_fast, k_slow) = _key_block(
+                        k, w_ticks, r_local, rows)
+                    gum = jax.vmap(jax.vmap(
+                        lambda kk: jax.random.gumbel(kk, (a_n,))))(k_fast)
+                    arr_w = jax.lax.dynamic_slice_in_dim(arrival, t_start,
+                                                         w_ticks)
+                    haz_w = jax.lax.dynamic_slice_in_dim(hazard, t_start,
+                                                         w_ticks)
+                    ov_w = (None if obs_valid is None
+                            else jax.lax.dynamic_slice_in_dim(
+                                obs_valid, t_start, w_ticks))
+                    fd_w = (None if forced_down is None
+                            else jax.lax.dynamic_slice_in_dim(
+                                forced_down, t_start, w_ticks))
+                    sp_w = (None if speed is None
+                            else jax.lax.dynamic_slice_in_dim(
+                                speed, t_start, w_ticks))
+                state, est, obs, ys = efe_ops.mega_window(
+                    state, est, obs, params, arr_w, haz_w, ov_w, k_env, gum,
+                    jnp.asarray(t_start, jnp.int32), forced_down=fd_w,
+                    speed=sp_w, row_block=rows, graph=graph,
+                    shard_axis=axis, **statics)
             if do_slow:
                 state = mega_mod.mega_slow_step(state, k_slow[-1], cfg)
-            ev = jnp.zeros((w_ticks, r_local), jnp.float32)
-            if getattr(cfg, "watchdog", False):
-                bad = mega_mod.mega_watchdog_bad(state)
-                state = jax.lax.cond(
-                    jnp.any(bad),
-                    lambda s: mega_mod.mega_quarantine(s, bad, cfg),
-                    lambda s: s, state)
-                ev = ev.at[-1].set(bad.astype(jnp.float32))
+            state, ev = _mega_watchdog(state, w_ticks, r_local, cfg)
             actions, weights, raw_obs, unstable, obs_frac, win = ys
             tr = FleetTrace(actions=actions, routing_weights=weights,
                             raw_obs=raw_obs, unstable=unstable,
@@ -1080,11 +1110,11 @@ def resumable_rollout(router: Router,
     else:
         obs_init = snapshot[:5]
         key = snapshot[5]
-    rc, est, trace, snap = _resumable_impl(
-        carry, env_state, obs_init, jnp.asarray(t_begin, jnp.int32),
-        env_step, n_steps, key, router=router, obs_masked=obs_masked,
-        clock_phase=0)
-    return rc, est, trace, snap
+    with _dispatch(1):
+        return _resumable_impl(
+            carry, env_state, obs_init, jnp.asarray(t_begin, jnp.int32),
+            env_step, n_steps, key, router=router, obs_masked=obs_masked,
+            clock_phase=0)
 
 
 def sharded_resumable_rollout(router: Router,
@@ -1140,12 +1170,14 @@ def sharded_resumable_rollout(router: Router,
     else:
         obs_in, stats_in, chain_key = snapshot
         carry_in = carry
-    rc, est, obs_out, stats_out = _sharded_chunk_impl(
-        env_state, chain_key, carry_in, obs_in, stats_in,
-        jnp.asarray(t_begin, jnp.int32), router=router, env_step=env_step,
-        n_steps=n_steps, obs_masked=obs_masked, spec=shard, n_cells=n_cells,
-        reducer=reducer, fresh=snapshot is None)
-    k_next = _advance_chain_key(chain_key, n_steps)
+    with _dispatch(1):
+        rc, est, obs_out, stats_out = _sharded_chunk_impl(
+            env_state, chain_key, carry_in, obs_in, stats_in,
+            jnp.asarray(t_begin, jnp.int32), router=router,
+            env_step=env_step, n_steps=n_steps, obs_masked=obs_masked,
+            spec=shard, n_cells=n_cells, reducer=reducer,
+            fresh=snapshot is None)
+        k_next = _advance_chain_key(chain_key, n_steps)
     return rc, est, stats_out, (obs_out, stats_out, k_next)
 
 
@@ -1155,7 +1187,8 @@ def sharded_finalize(stats, *, shard, reducer):
     Bit-equal to the reduction :func:`sharded_rollout` applies in-shard at
     the end of an uninterrupted run.
     """
-    return _sharded_finalize_impl(stats, spec=shard, reducer=reducer)
+    with _dispatch(1):
+        return _sharded_finalize_impl(stats, spec=shard, reducer=reducer)
 
 
 @functools.partial(jax.jit, static_argnames=("spec", "reducer"))

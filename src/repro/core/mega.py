@@ -594,26 +594,28 @@ def mega_window(state: MegaFleetState, est, obs_carry, params,
             obs_mask = win.obs_mask
 
     # --- land the window's slot block in one contiguous write per buffer
-    qp_w, qn_w, ob_w, om_w, ac_w, dt_w = (jnp.stack(xs, axis=1)
-                                          for xs in zip(*pushes))
-    sl = state.slots
+    with jax.named_scope("aif.window.land"):
+        qp_w, qn_w, ob_w, om_w, ac_w, dt_w = (jnp.stack(xs, axis=1)
+                                              for xs in zip(*pushes))
+        sl = state.slots
 
-    def put(arr, val):
-        return jax.lax.dynamic_update_slice_in_dim(
-            arr, val.astype(arr.dtype), t0, axis=1)
+        def put(arr, val):
+            return jax.lax.dynamic_update_slice_in_dim(
+                arr, val.astype(arr.dtype), t0, axis=1)
 
-    state = state._replace(slots=sl._replace(
-        q_prev=put(sl.q_prev, qp_w), q_next=put(sl.q_next, qn_w),
-        obs_bins=put(sl.obs_bins, ob_w), obs_mask=put(sl.obs_mask, om_w),
-        action=put(sl.action, ac_w), dt_since_change=put(sl.dt_since_change,
-                                                         dt_w)))
+        state = state._replace(slots=sl._replace(
+            q_prev=put(sl.q_prev, qp_w), q_next=put(sl.q_next, qn_w),
+            obs_bins=put(sl.obs_bins, ob_w),
+            obs_mask=put(sl.obs_mask, om_w), action=put(sl.action, ac_w),
+            dt_since_change=put(sl.dt_since_change, dt_w)))
 
-    trace = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *ys)
+        trace = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *ys)
     return (state, est,
             (raw_obs, tier_util, tier_up, tier_queue, obs_mask), trace)
 
 
 # -------------------------------------------------------------- slow update
+@jax.named_scope("aif.slow_step")
 def mega_slow_step(state: MegaFleetState, k_slow: jax.Array,
                    cfg: generative.AifConfig, *,
                    incremental: bool = True) -> MegaFleetState:

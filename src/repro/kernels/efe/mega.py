@@ -576,6 +576,7 @@ def mega_window_pallas(state, est, obs_carry, params,
 
     outs = pl.pallas_call(
         kernel,
+        name="aif_mega_window",
         grid=(r_pad // br,),
         in_specs=in_specs,
         out_specs=out_specs,
@@ -584,53 +585,54 @@ def mega_window_pallas(state, est, obs_carry, params,
             dimension_semantics=("parallel",), vmem_limit_bytes=vmem),
         interpret=interpret,
     )(*operands)
-    qn_w, tr_act, tr_r, tr_rk, tr_rm, envk_o, envr_o = outs
-    qn_w = qn_w[:, :r]                                           # (W, R, S)
-    tr_act, tr_r = tr_act[:, :r, 0], tr_r[:, :r]
-    tr_rk, tr_rm = tr_rk[:, :, :r], tr_rm[:, :, :r]
-    envk_o, envr_o = envk_o[:, :r], envr_o[:r]
+    with jax.named_scope("aif.window.land"):
+        qn_w, tr_act, tr_r, tr_rk, tr_rm, envk_o, envr_o = outs
+        qn_w = qn_w[:, :r]                                       # (W, R, S)
+        tr_act, tr_r = tr_act[:, :r, 0], tr_r[:, :r]
+        tr_rk, tr_rm = tr_rk[:, :, :r], tr_rm[:, :, :r]
+        envk_o, envr_o = envk_o[:, :r], envr_o[:r]
 
-    # ---- land the window's slot block (slot index == global tick) --------
-    def prepend(first, per_tick):
-        return jnp.concatenate([first[None], per_tick[:-1]], axis=0)
+        # ---- land the window's slot block (slot index == global tick) ----
+        def prepend(first, per_tick):
+            return jnp.concatenate([first[None], per_tick[:-1]], axis=0)
 
-    push_mask = (prepend(obs_mask0, tr_rm[:, 1]) if emits_mask
-                 else jnp.ones((w_ticks,) + obs_mask0.shape, jnp.float32))
-    pushes = dict(
-        q_prev=prepend(state.belief, qn_w), q_next=qn_w,
-        obs_bins=spaces.discretize_observation(tr_rm[:, 2], disc),
-        obs_mask=push_mask,
-        action=prepend(state.prev_action, tr_act),
-        dt_since_change=prepend(state.dt_since_change, tr_r[..., 4]))
-    new_slots = slots._replace(**{
-        name: jax.lax.dynamic_update_slice_in_dim(
-            getattr(slots, name),
-            jnp.swapaxes(val, 0, 1).astype(getattr(slots, name).dtype),
-            t0, axis=1)
-        for name, val in pushes.items()})
+        push_mask = (prepend(obs_mask0, tr_rm[:, 1]) if emits_mask
+                     else jnp.ones((w_ticks,) + obs_mask0.shape, jnp.float32))
+        pushes = dict(
+            q_prev=prepend(state.belief, qn_w), q_next=qn_w,
+            obs_bins=spaces.discretize_observation(tr_rm[:, 2], disc),
+            obs_mask=push_mask,
+            action=prepend(state.prev_action, tr_act),
+            dt_since_change=prepend(state.dt_since_change, tr_r[..., 4]))
+        new_slots = slots._replace(**{
+            name: jax.lax.dynamic_update_slice_in_dim(
+                getattr(slots, name),
+                jnp.swapaxes(val, 0, 1).astype(getattr(slots, name).dtype),
+                t0, axis=1)
+            for name, val in pushes.items()})
 
-    new_state = state._replace(
-        slots=new_slots, belief=qn_w[-1], prev_action=tr_act[-1],
-        dt_since_change=tr_r[-1, :, 4], error_ema=tr_r[-1, :, 5],
-        unstable=tr_r[-1, :, 2] > 0.5, t=state.t + w_ticks)
-    new_est = batched.FluidState(
-        backlog=envk_o[0], down_left=envk_o[1], util_accum=envk_o[2],
-        util_scrape=envk_o[3], prev_tier_rps=envk_o[4],
-        p95_ema=envr_o[:, 0], rps_ema=envr_o[:, 1], err_ema=envr_o[:, 2],
-        held_obs=tr_rm[-1, 0],
-        n_requests=envr_o[:, 3], n_success=envr_o[:, 4],
-        err_timeout=envr_o[:, 5], err_overflow=envr_o[:, 6],
-        err_refused=envr_o[:, 7], err_restart=envr_o[:, 8],
-        tier_requests=envk_o[5], tier_success=envk_o[6],
-        n_restarts=envk_o[7])
-    win = batched.WindowInfo(
-        raw_obs=tr_rm[:, 0], obs_mask=tr_rm[:, 1],
-        tier_utilization=tr_rk[:, 1], tier_up=tr_rk[:, 2],
-        tier_queue=tr_rk[:, 3], tier_latency_s=tr_rk[:, 4],
-        tier_p95_s=tr_rk[:, 5], tier_completed=tr_rk[:, 6],
-        success=tr_r[..., 0], failures=tr_r[..., 1], restarted=tr_rk[:, 7])
-    trace = (tr_act, tr_rk[:, 0], tr_rm[:, 2], tr_r[..., 2] > 0.5,
-             tr_r[..., 3], win)
-    new_carry = (tr_rm[-1, 0], tr_rk[-1, 1], tr_rk[-1, 2], tr_rk[-1, 3],
-                 tr_rm[-1, 1] if emits_mask else obs_mask0)
+        new_state = state._replace(
+            slots=new_slots, belief=qn_w[-1], prev_action=tr_act[-1],
+            dt_since_change=tr_r[-1, :, 4], error_ema=tr_r[-1, :, 5],
+            unstable=tr_r[-1, :, 2] > 0.5, t=state.t + w_ticks)
+        new_est = batched.FluidState(
+            backlog=envk_o[0], down_left=envk_o[1], util_accum=envk_o[2],
+            util_scrape=envk_o[3], prev_tier_rps=envk_o[4],
+            p95_ema=envr_o[:, 0], rps_ema=envr_o[:, 1], err_ema=envr_o[:, 2],
+            held_obs=tr_rm[-1, 0],
+            n_requests=envr_o[:, 3], n_success=envr_o[:, 4],
+            err_timeout=envr_o[:, 5], err_overflow=envr_o[:, 6],
+            err_refused=envr_o[:, 7], err_restart=envr_o[:, 8],
+            tier_requests=envk_o[5], tier_success=envk_o[6],
+            n_restarts=envk_o[7])
+        win = batched.WindowInfo(
+            raw_obs=tr_rm[:, 0], obs_mask=tr_rm[:, 1],
+            tier_utilization=tr_rk[:, 1], tier_up=tr_rk[:, 2],
+            tier_queue=tr_rk[:, 3], tier_latency_s=tr_rk[:, 4],
+            tier_p95_s=tr_rk[:, 5], tier_completed=tr_rk[:, 6],
+            success=tr_r[..., 0], failures=tr_r[..., 1], restarted=tr_rk[:, 7])
+        trace = (tr_act, tr_rk[:, 0], tr_rm[:, 2], tr_r[..., 2] > 0.5,
+                 tr_r[..., 3], win)
+        new_carry = (tr_rm[-1, 0], tr_rk[-1, 1], tr_rk[-1, 2], tr_rk[-1, 3],
+                     tr_rm[-1, 1] if emits_mask else obs_mask0)
     return new_state, new_est, new_carry, trace

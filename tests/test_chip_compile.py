@@ -154,12 +154,12 @@ def test_mega_window_compiles(one_chip, r, t, scenario):
     _assert_fits_one_chip(jax.jit(window).lower(*args).compile())
 
 
-def test_mega_rollout_fits_one_chip(one_chip, monkeypatch):
+@pytest.fixture(scope="module")
+def mega_rollout(one_chip):
     """The whole R=2048 x T=600 mega rollout program with the compiled
-    kernel fits one chip's HBM.  Its inputs keep the device's default
+    kernel, compiled for one chip.  Its inputs keep the device's default
     layouts, as arrays created outside the program do; the engine's backend
-    probe sees the CPU here, so the test selects the compiled kernel."""
-    monkeypatch.setattr(efe_ops, "_auto_interpret", lambda: False)
+    probe sees the CPU here, so the fixture selects the compiled kernel."""
     r, t = 2048, 600
     router, params, env_step = _mega_world(r, t, "paper-burst")
     fl = env_step.fluid
@@ -174,9 +174,25 @@ def test_mega_rollout_fits_one_chip(one_chip, monkeypatch):
                       fl.hazard_scale), one_chip, row_major=False)
     key = _abstract(jax.eval_shape(lambda: jax.random.key(0)), one_chip)
     t0 = _sds((), jnp.int32, one_chip, row_major=False)
-    compiled = engine._mega_impl.lower(
-        *args, None, None, None, None, key, t0, router=router, n_steps=t,
-        obs_masked=bool(env_step.emits_mask), dt=fl.dt,
-        scrape_every=fl.scrape_every,
-        restart_blackout=fl.restart_blackout).compile()
-    _assert_fits_one_chip(compiled)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(efe_ops, "_auto_interpret", lambda: False)
+        return engine._mega_impl.lower(
+            *args, None, None, None, None, key, t0, router=router,
+            n_steps=t, obs_masked=bool(env_step.emits_mask), dt=fl.dt,
+            scrape_every=fl.scrape_every,
+            restart_blackout=fl.restart_blackout).compile()
+
+
+def test_mega_rollout_fits_one_chip(mega_rollout):
+    """The whole mega rollout program fits one chip's HBM."""
+    _assert_fits_one_chip(mega_rollout)
+
+
+def test_mega_rollout_names_its_kernel_and_scopes(mega_rollout):
+    """The compiled program keeps the megakernel's name and the scopes a
+    profiler trace attributes its ops by."""
+    text = mega_rollout.as_text()
+    assert "aif_mega_window" in text
+    for scope in ("aif.window", "aif.window.draw", "aif.window.land",
+                  "aif.slow_step", "aif.watchdog"):
+        assert f"/{scope}/" in text, scope
