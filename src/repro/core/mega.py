@@ -27,14 +27,14 @@ bookkeeping:
   (:func:`mega_window`), the XLA oracle twin of the Pallas megakernel.
 
 **Streaming slow boundaries.**  The boundary step advances the cache
-*incrementally* from the replayed batch (:func:`_advance_cache`): the
-per-column normalizer ``colsum`` gains the batch's O(batch·A·S)
-scatter-free delta (the same per-draw association the per-tick
-:func:`repro.core.learning.update_transition_model` einsum uses), the
-per-slot coefficient rows are re-evaluated elementwise (linear in the
-slot-hit counts), and only the A-derived rows (``logna``/``proj``/
-``projsum``/``qnproj``) are recomputed in full — the A update renormalizes
-whole modality rows, so per-row selection would save nothing there.
+*incrementally* from the replayed batch (:func:`_advance_cache`), folded
+as its slot-hit histogram (:func:`mega_slow_step`): the per-column
+normalizer ``colsum`` gains the batch's delta, one contraction of the slot
+tape weighted by the hit counts; the per-slot coefficient rows are
+re-evaluated elementwise (linear in the slot-hit counts), and only the
+A-derived rows (``logna``/``proj``/``projsum``/``qnproj``) are recomputed
+in full — the A update renormalizes whole modality rows, so per-row
+selection would save nothing there.
 :func:`_refresh_cache` remains as the from-scratch fallback (init,
 quarantine, warm promotion, tests): the slots' ``wcount`` is sufficient
 statistics for it, and the incremental and full forms are mathematically
@@ -118,9 +118,10 @@ class MegaCache(NamedTuple):
                       slot terms grow, so it is read (streamed on EFE
                       ticks), never rewritten.
 
-    Invalidation rule: ``colsum`` advances by the boundary batch's
-    scatter-free delta and the coefficient rows (``coefw``/``coefact``) are
-    re-evaluated elementwise from the bumped hit counts; the A-derived rows
+    Invalidation rule: ``colsum`` advances by the boundary batch's delta
+    (the slot tape weighted by the batch's slot hits) and the coefficient
+    rows (``coefw``/``coefact``) are re-evaluated elementwise from the
+    bumped hit counts; the A-derived rows
     (``proj``/``projsum``/``logna``/``qnproj``) are recomputed in full each
     boundary — every modality row a replayed observation touched is
     renormalized, and the bin-sum denominator couples the rows of a
@@ -216,33 +217,29 @@ def _refresh_cache(a_counts: jnp.ndarray, slots: MegaSlots,
 
 
 def _advance_cache(cache: MegaCache, a_counts: jnp.ndarray,
-                   slots: MegaSlots,
-                   q_prev_b: jnp.ndarray, q_next_b: jnp.ndarray,
-                   action_b: jnp.ndarray, dt_b: jnp.ndarray,
-                   valid: jnp.ndarray,
+                   slots: MegaSlots, hits: jnp.ndarray,
                    cfg: generative.AifConfig) -> MegaCache:
-    """Advance the cache by one boundary's replayed batch.
+    """Advance the cache by one boundary's replayed batch, given as its
+    slot-hit histogram ``hits`` (R, J) (:func:`mega_slow_step`).
 
-    ``colsum`` gains the batch's scatter-free O(batch·A·S) delta — the
-    per-draw association of the per-tick engine's
-    :func:`repro.core.learning.update_transition_model` einsum, so the
-    maintained normalizer tracks the per-tick ``b_counts`` column sums
-    update-for-update.  The per-slot coefficient rows are re-evaluated
-    elementwise from the bumped ``wcount`` (bit-equal to the full refresh:
-    same formula, same inputs), and the A-derived rows are refreshed from
-    the already-updated ``a_counts``.  No (R, A, S, S) tensor is formed.
+    ``colsum`` gains the batch's delta in the full refresh's own form with
+    ``hits`` in the place of ``wcount``: one contraction over the slot tape,
+    ``α_B Σ_j hits_j · settle(Δt_j) · 1[act_j = a] · sumqn_j · q_prev_j``,
+    whose ``sumqn`` is the one computed here for the cache anyway.  The
+    per-slot coefficient rows are re-evaluated elementwise from the bumped
+    ``wcount`` (bit-equal to the full refresh: same formula, same inputs),
+    and the A-derived rows are refreshed from the already-updated
+    ``a_counts``.  No (R, A, S, S) tensor is formed.
     """
     a_n = cfg.n_actions
-    topo = cfg.topology
-    w = learning.settle_weight(dt_b, cfg) * valid                 # (R, n)
-    oh = jax.nn.one_hot(action_b, a_n, dtype=jnp.float32) * w[..., None]
-    sumqn_b = jnp.sum(q_next_b, axis=-1)                          # (R, n)
-    d_col = cfg.alpha_b * _einsum("rna,rns->ras",
-                                     oh * sumqn_b[..., None], q_prev_b)
     qn = slots.q_next.astype(jnp.float32)
     coefw, coefact = slot_coefficients(slots, cfg, a_n)
     sumqn = jnp.sum(qn, axis=-1)
-    proj, projsum, logna = _a_cache(a_counts, topo)
+    with jax.named_scope("aif.slow_step.replay"):
+        _, d_coef = slot_coefficients(slots._replace(wcount=hits), cfg, a_n)
+        d_col = _einsum("rja,rjs->ras", d_coef * sumqn[..., None],
+                        slots.q_prev, preferred_element_type=jnp.float32)
+    proj, projsum, logna = _a_cache(a_counts, cfg.topology)
     qnproj = _einsum("rps,rjs->rjp", proj, qn)
     return MegaCache(colsum=cache.colsum + d_col, proj=proj,
                      projsum=projsum, qnproj=qnproj, sumqn=sumqn,
@@ -624,45 +621,43 @@ def mega_slow_step(state: MegaFleetState, k_slow: jax.Array,
 
     The replayed index draws are the legacy per-router
     ``randint(key, (batch,), 0, max(size, 1))`` bit-for-bit (slot == tick,
-    so the legacy ``idx % capacity`` is the identity here).  The A update is
-    the legacy einsum on the gathered slots; the B side folds the *same
-    gathered batch* into the cached column sums with the per-tick engine's
-    update association (:func:`_advance_cache`) and bumps ``wcount`` — the
-    sufficient statistic that keeps the from-scratch
+    so the legacy ``idx % capacity`` is the identity here).  Every use of
+    the replayed batch is a sum over its draws, which equals a sum over the
+    slots weighted by the slot-hit histogram ``hits[r, j] = #{n : idx[r, n]
+    = j}`` (zero on a router with nothing pushed yet), so the batch is
+    folded in that form by dense contractions over the slot tape and no
+    drawn slot is gathered: the A update is ``α_A Σ_j hits_j · obs_mask_j ·
+    onehot(obs_bins_j) ⊗ q_next_j`` and the B side folds the same histogram
+    into the cached column sums (:func:`_advance_cache`), equal to the
+    gathered batch's sums up to float32 association.  ``wcount`` gains
+    ``hits`` — small integers, so exactly the legacy scatter-add — and
+    stays the sufficient statistic that keeps the from-scratch
     :func:`_refresh_cache` (``incremental=False``, the legacy twin)
     mathematically identical.
     """
     topo = cfg.topology
     slots = state.slots
-    r, j = slots.action.shape
+    j = slots.action.shape[1]
     batch = cfg.replay_batch
     size = jnp.minimum(state.t, j)                               # == t
     idx = jax.vmap(
         lambda k, n: jax.random.randint(k, (batch,), 0,
                                         jnp.maximum(n, 1)))(k_slow, size)
-    valid = ((size > 0).astype(jnp.float32)[:, None]
-             * jnp.ones((1, batch), jnp.float32))                # (R, batch)
+    live = (size > 0).astype(jnp.float32)[:, None]               # (R, 1)
 
-    # exact legacy observation-model update on the gathered slots
-    qp_b = jnp.take_along_axis(slots.q_prev.astype(jnp.float32),
-                               idx[..., None], axis=1)
-    qn_b = jnp.take_along_axis(slots.q_next.astype(jnp.float32),
-                               idx[..., None], axis=1)
-    ob_b = jnp.take_along_axis(slots.obs_bins, idx[..., None], axis=1)
-    om_b = jnp.take_along_axis(slots.obs_mask, idx[..., None], axis=1)
-    act_b = jnp.take_along_axis(slots.action, idx, axis=1)
-    dt_b = jnp.take_along_axis(slots.dt_since_change, idx, axis=1)
-    onehot = spaces.one_hot_observation(ob_b, topo.max_bins)     # (R,n,M,NB)
-    wgt = onehot * valid[..., None, None] * om_b[..., None]
-    a_counts = state.a_counts + cfg.alpha_a * _einsum(
-        "rnmb,rns->rmbs", wgt, qn_b)
+    with jax.named_scope("aif.slow_step.replay"):
+        drawn = idx[..., None] == jnp.arange(j, dtype=idx.dtype)
+        hits = live * jnp.sum(drawn, axis=1, dtype=jnp.float32)  # (R, J)
+        # exact legacy observation-model update, summed over the slots
+        wgt = (spaces.one_hot_observation(slots.obs_bins, topo.max_bins)
+               * (hits[..., None] * slots.obs_mask)[..., None])  # (R,J,M,NB)
+        a_counts = state.a_counts + cfg.alpha_a * _einsum(
+            "rjmb,rjs->rmbs", wgt, slots.q_next,
+            preferred_element_type=jnp.float32)
 
-    # slot-hit counts: the B update's sufficient statistic
-    wcount = slots.wcount.at[jnp.arange(r)[:, None], idx].add(valid)
-    slots = slots._replace(wcount=wcount)
+    slots = slots._replace(wcount=slots.wcount + hits)
     if incremental:
-        cache = _advance_cache(state.cache, a_counts, slots, qp_b, qn_b,
-                               act_b, dt_b, valid, cfg)
+        cache = _advance_cache(state.cache, a_counts, slots, hits, cfg)
     else:
         cache = _refresh_cache(a_counts, slots, cfg,
                                b_base=state.cache.b_base)

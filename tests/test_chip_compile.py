@@ -196,3 +196,16 @@ def test_mega_rollout_names_its_kernel_and_scopes(mega_rollout):
     for scope in ("aif.window", "aif.window.draw", "aif.window.land",
                   "aif.slow_step", "aif.watchdog"):
         assert f"/{scope}/" in text, scope
+
+
+def test_mega_slow_step_folds_replay_without_gathers(mega_rollout):
+    """The slow boundary folds its replayed batch as a slot-hit histogram:
+    no gather op sits under its scope, the histogram and its contractions
+    sit under ``aif.slow_step.replay``, and the program still fits."""
+    lines = mega_rollout.as_text().splitlines()
+    slow = [ln for ln in lines if "/aif.slow_step/" in ln]
+    assert slow
+    gathers = [ln for ln in slow if " gather(" in ln]
+    assert not gathers, gathers[:3]
+    assert any("/aif.slow_step.replay/" in ln for ln in slow)
+    _assert_fits_one_chip(mega_rollout)
