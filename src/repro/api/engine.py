@@ -112,12 +112,38 @@ def rollout(router: Router,
 
 
 @contextlib.contextmanager
-def _dispatch(launches: int):
+def _dispatch(launches: int, tape_bytes: int | None = None):
     """The ``repro.run.dispatch`` span around ``launches`` jitted launches
-    (they return once enqueued; the device work is waited for later)."""
+    (they return once enqueued; the device work is waited for later).
+    Where they run the Pallas megakernel, ``tape_bytes`` is the slot tape
+    its windows read from HBM (:func:`_kernel_tape_bytes`), counted and
+    carried on the span beside ``launches``."""
     obs.count("launches", launches)
-    with obs.span("run.dispatch", launches=launches):
+    args = {"launches": launches}
+    if tape_bytes is not None:
+        obs.count("tape_bytes", tape_bytes)
+        args["tape_bytes"] = tape_bytes
+    with obs.span("run.dispatch", **args):
         yield
+
+
+def _kernel_tape_bytes(router, state, has_obs_valid: bool, t_begin: int,
+                       n_steps: int) -> int | None:
+    """Slot-tape bytes the megakernel reads from HBM over the windows of
+    ticks [t_begin, t_begin + n_steps), from shapes; None off the kernel.
+    Windows start every slow period (chunked launches too), the last one
+    possibly short."""
+    if not router.use_pallas:
+        return None
+    from repro.kernels.efe import mega as mega_kernel
+    r, j = state.slots.q_prev.shape[:2]
+    dtype = state.slots.q_prev.dtype
+    period = max(int(router.period), 1)
+    end = t_begin + n_steps
+    return sum(mega_kernel.tape_bytes(router.cfg, r, j, t0,
+                                      min(period, end - t0), dtype,
+                                      has_obs_valid)
+               for t0 in range(t_begin, end, period))
 
 
 def _row_block_keys(key: jax.Array, row_start: jnp.ndarray, n_true: int,
@@ -595,8 +621,10 @@ def _mega_rollout(router, carry, env_state, env_step: Callable, n_steps: int,
             obs_masked=obs_masked, dt=fl.dt, scrape_every=fl.scrape_every,
             restart_blackout=fl.restart_blackout)
 
+    tape = _kernel_tape_bytes(router, state_in, fl.obs_valid is not None,
+                              t_begin, n_steps)
     if launch_periods is None:
-        with _dispatch(1):
+        with _dispatch(1, tape):
             return launch(state_in, env_state, obs_carry, key, t_begin,
                           n_steps)
     if int(launch_periods) < 1:
@@ -610,7 +638,7 @@ def _mega_rollout(router, carry, env_state, env_step: Callable, n_steps: int,
     chunk = int(launch_periods) * period
     state, est, obs_c, k = state_in, env_state, obs_carry, key
     traces, c0 = [], 0
-    with _dispatch(-(-n_steps // chunk)):
+    with _dispatch(-(-n_steps // chunk), tape):
         while c0 < n_steps:
             n = min(chunk, n_steps - c0)
             state, est, tr, (obs_c, k) = launch(state, est, obs_c, k,

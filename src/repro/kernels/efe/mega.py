@@ -46,17 +46,31 @@ last router (every op is row-local; padded rows are cropped) — per-window
 schedules and traces are (W, ..., R, trailing) with the small axes leading,
 and per-router scalars are (BR, 1) columns.  The state axis keeps its true
 width S (243 for the paper topology; Mosaic masks the partial lane tile),
-so the slot tape is read in place, never padded or copied.  The resident
-tape bounds the horizon: :func:`mega_vmem_bytes` prices a launch, the
-launch asks the compiler for exactly that much scoped VMEM, and
-:func:`mega_window_pallas` refuses one beyond :data:`VMEM_LIMIT` — on a
-TPU v5e an 8-router block compiles up to a J=2230-slot tape.
+so the slot tape is read in place, never padded or copied.
+
+Slot tape and horizon: the tape (``q_prev``, ``q_next``, ``qnproj|sumqn``
+and ``coefact``, J slots each) is folded in :data:`SLOT_CHUNK`-slot chunks.
+:func:`tape_plan` prices a launch from shapes alone: where the 8-router
+block's whole tape fits :data:`VMEM_LIMIT` it is held resident (read once a
+window); where it does not, the launch holds the longest resident prefix
+the priced VMEM leaves room for and streams the rest from HBM, one chunk at
+a time into two VMEM buffers, so the copy of the next chunk overlaps the
+fold of this one.  A streamed chunk is copied at every tick whose fold
+needs it, and only where it holds a filled slot (slot index < t0: the
+later slots carry zero coefficients and add nothing to the fold).  The
+arithmetic and its order within a chunk are the same either way.  The
+launch asks the compiler for exactly the priced scoped VMEM and refuses one
+whose non-tape blocks alone exceed the ceiling; the horizon's one bound is
+the slots' replay capacity (:func:`repro.core.mega.init_mega_state`).
 
 The kernel compiles for a TPU v5e (``tests/test_chip_compile.py`` compiles
 it for a described chip at the paper's widths) and runs there compiled
 (``chip_smoke.py``); on the CPU it runs in interpret mode.
 """
 from __future__ import annotations
+
+import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -77,8 +91,23 @@ _HI = jax.lax.Precision.HIGHEST
 # 128 MiB TensorCore VMEM for Mosaic's own internal scratch).
 VMEM_LIMIT = VMEM_CAPACITY - 16 * 1024 * 1024
 
-# Slots per step of the in-kernel fold over the resident tape.
+# Slots per step of the in-kernel fold over the tape, and per streamed copy.
 SLOT_CHUNK = 128
+
+# The slot tape's operands, in the order the launch passes them.
+TAPE = ("qp", "qn", "qnproj", "coefact")
+# The tape operands each fold reads: the belief prior's and the EFE's.
+_PRIOR_TAPE = ("coefact", "qp", "qn")
+_EFE_TAPE = ("qp", "coefact", "qnproj")
+
+
+class TapePlan(NamedTuple):
+    """How one launch reads its J-slot tape (:func:`tape_plan`)."""
+
+    j_res: int      # leading slots held resident in VMEM (J: the whole tape)
+    j_chunk: int    # slots per fold step and per streamed copy
+    vmem: int       # scoped VMEM the launch asks for, in bytes
+    widths: tuple   # (trailing width, dtype) of each operand, in TAPE order
 
 
 def _batched_dot(a, b, contract_a: int, contract_b: int):
@@ -89,11 +118,107 @@ def _batched_dot(a, b, contract_a: int, contract_b: int):
 
 
 def mega_vmem_bytes(blocks, chunk_bytes: int) -> int:
-    """Scoped VMEM one launch asks for, from its ``(block shape, dtype)``
-    operand/result list: every block double-buffered by the pipeline, plus
+    """Scoped VMEM of a launch's pipelined blocks, from their ``(block
+    shape, dtype)`` list: every block double-buffered by the pipeline, plus
     room for four f32 temporaries the size of one tape chunk."""
     return (2 * sum(block_vmem_bytes(s, d) for s, d in blocks)
             + 4 * chunk_bytes)
+
+
+def tape_widths(cfg, slot_dtype) -> dict:
+    """Trailing width and dtype of each tape operand."""
+    topo = cfg.topology
+    s = topo.n_states
+    return {"qp": (s, jnp.dtype(slot_dtype)), "qn": (s, jnp.dtype(slot_dtype)),
+            "qnproj": (mega_core.n_proj(topo) + 1, jnp.dtype(jnp.float32)),
+            "coefact": (cfg.n_actions, jnp.dtype(jnp.float32))}
+
+
+def _fixed_blocks(cfg, w_ticks: int, has_obs_valid: bool) -> list:
+    """(block shape, dtype) of every pipelined block of a launch but the
+    tape's: the cache, carries, schedules and noise, shared tables and
+    results (all 4-byte types), in :func:`mega_window_pallas`' order."""
+    topo = cfg.topology
+    s, a, k = topo.n_states, cfg.n_actions, topo.n_tiers
+    m, nb, p = topo.n_modalities, topo.max_bins, mega_core.n_proj(topo)
+    w, br = w_ticks, SUBLANES
+    shapes = [(br, a, s), (br, p, s), (br, p), (br, m, nb, s), (br, s),
+              (br, 1), (br, 2), (3, br, m), (br, k), (8, br, k), (br, 9),
+              (16, br, k), (w, br, 1), (w, br, k), (w, 2, br, k), (w, br, a),
+              (k, s), (2, m * nb), (1, a), (a, k)]
+    if has_obs_valid:
+        shapes.append((w, br, m))
+    shapes += [(w, br, s), (w, br, 1), (w, br, 6), (w, 8, br, k),
+               (w, 3, br, m), (8, br, k), (br, 9)]
+    return [(sh, jnp.float32) for sh in shapes]
+
+
+@functools.lru_cache(maxsize=64)
+def tape_plan(cfg, j: int, w_ticks: int, slot_dtype, has_obs_valid: bool,
+              *, slot_chunk: int = SLOT_CHUNK,
+              vmem_limit: int = VMEM_LIMIT) -> TapePlan:
+    """Price a launch from shapes and choose how it reads the tape.
+
+    The whole tape stays resident where it fits ``vmem_limit``.  Else the
+    resident prefix is the most whole chunks the VMEM left after the other
+    blocks and the two streaming buffers of each tape operand can hold
+    (possibly none), and the rest is streamed.  Raises ``ValueError`` where
+    the other blocks and the buffers alone exceed ``vmem_limit``.
+    """
+    br = SUBLANES
+    jc = min(j, slot_chunk)
+    widths = tape_widths(cfg, slot_dtype)
+    fixed = mega_vmem_bytes(_fixed_blocks(cfg, w_ticks, has_obs_valid),
+                            block_vmem_bytes((br, jc, cfg.topology.n_states),
+                                             jnp.float32))
+
+    def resident(n):           # the pipeline double-buffers each block
+        return 2 * sum(block_vmem_bytes((br, n, wd), dt)
+                       for wd, dt in widths.values())
+
+    if fixed + resident(j) <= vmem_limit:
+        return TapePlan(j, jc, fixed + resident(j), tuple(widths.values()))
+    buffers = sum(block_vmem_bytes((2, br, jc, wd), dt)
+                  for wd, dt in widths.values())
+    room = vmem_limit - fixed - buffers
+    if room < 0:
+        raise ValueError(
+            f"megakernel launch needs {(fixed + buffers) / 2**20:.1f} MiB of "
+            f"VMEM for its non-tape blocks and tape buffers alone (limit "
+            f"{vmem_limit / 2**20:.1f} MiB)")
+    j_res = min(room // resident(jc), (j - 1) // jc) * jc
+    return TapePlan(j_res, jc, fixed + buffers + resident(j_res),
+                    tuple(widths.values()))
+
+
+def _live_slots(plan: TapePlan, j: int, t0: int) -> int:
+    """Slots of the streamed part a window at ``t0`` copies per fold: the
+    whole chunks, and the short last one, that hold a filled slot."""
+    if t0 <= plan.j_res:
+        return 0
+    n_full, tail = divmod(j - plan.j_res, plan.j_chunk)
+    live = min(-(-(t0 - plan.j_res) // plan.j_chunk), n_full) * plan.j_chunk
+    if tail and t0 > j - tail:
+        live += tail
+    return live
+
+
+def tape_bytes(cfg, r: int, j: int, t0: int, w_ticks: int, slot_dtype,
+               has_obs_valid: bool, **plan_kw) -> int:
+    """Bytes of slot tape one launch at window start ``t0`` reads from HBM,
+    counted from shapes as the kernel reads them: the resident prefix once
+    per router block, each live streamed chunk of the prior's operands at
+    every tick and of the EFE's at every selecting tick.  True widths and
+    padded rows: the lane padding the copies also move does not count."""
+    plan = tape_plan(cfg, j, w_ticks, slot_dtype, has_obs_valid, **plan_kw)
+    per_slot = {n: wd * dt.itemsize for n, (wd, dt) in zip(TAPE, plan.widths)}
+    dwell = max(int(cfg.action_dwell_s / cfg.fast_period_s), 1)
+    selecting = len(range(0, w_ticks, dwell))
+    live = _live_slots(plan, j, t0)
+    streamed = live * (w_ticks * sum(per_slot[n] for n in _PRIOR_TAPE)
+                       + selecting * sum(per_slot[n] for n in _EFE_TAPE))
+    return pad_rows(r, SUBLANES) * (plan.j_res * sum(per_slot.values())
+                                    + streamed)
 
 
 def mega_window_pallas(state, est, obs_carry, params,
@@ -103,7 +228,9 @@ def mega_window_pallas(state, est, obs_carry, params,
                        t0: jnp.ndarray, *,
                        cfg, disc, util_edges, util_period: int, dt: float,
                        scrape_every: int, restart_blackout: bool,
-                       emits_mask: bool, interpret: bool):
+                       emits_mask: bool, interpret: bool,
+                       slot_chunk: int = SLOT_CHUNK,
+                       vmem_limit: int = VMEM_LIMIT):
     """Pallas dispatch of one whole window; signature/result match
     :func:`repro.core.mega.mega_window`.
 
@@ -111,6 +238,8 @@ def mega_window_pallas(state, est, obs_carry, params,
     there) so the selecting/held tick structure is compiled statically.
     ``interpret`` is deliberately required, as for the per-tick kernels —
     only the :mod:`..ops` wrapper auto-detects the backend.
+    ``slot_chunk`` and ``vmem_limit`` set the fold's chunk and the VMEM
+    ceiling :func:`tape_plan` prices the launch against.
     """
     topo = cfg.topology
     slots, cache = state.slots, state.cache
@@ -123,10 +252,23 @@ def mega_window_pallas(state, est, obs_carry, params,
     dwell = max(int(cfg.action_dwell_s / cfg.fast_period_s), 1)
     br = SUBLANES
     r_pad = pad_rows(r, br)
-    # the slot tape is folded in J_c-slot chunks (code size independent of
-    # the horizon), the last one possibly short
-    j_chunk = min(j, SLOT_CHUNK)
     slot_dtype = slots.q_prev.dtype
+    # the slot tape is folded in J_c-slot chunks (code size independent of
+    # the horizon), the last one possibly short: the first j_res slots
+    # from resident VMEM blocks, the rest streamed from HBM
+    plan = tape_plan(cfg, j, w_ticks, slot_dtype, obs_valid is not None,
+                     slot_chunk=slot_chunk, vmem_limit=vmem_limit)
+    j_res, j_chunk = plan.j_res, plan.j_chunk
+    streamed = j_res < j
+    n_stream, tail_s = divmod(j - j_res, j_chunk)
+    widths = {n: wd for n, (wd, _) in zip(TAPE, plan.widths)}
+    # compiled, a copy moves whole (8, 128) tiles of the tape's HBM layout:
+    # every lane of the padded width, and the short last chunk's rows up to
+    # a whole sublane tile (both inside the padded allocation); the
+    # interpreter copies the exact window
+    lanes = {n: wd if interpret else -(-wd // 128) * 128
+             for n, wd in widths.items()}
+    tail_rows = tail_s if interpret else -(-tail_s // 8) * 8
 
     # ---- static closure constants (inlined into the kernel) ---------------
     edges_list = [np.asarray(e, np.float32) for e in disc.modality_edges()]
@@ -151,15 +293,20 @@ def mega_window_pallas(state, est, obs_carry, params,
     masked_obs = emits_mask or obs_valid is not None or restart_blackout
 
     # ---- kernel ----------------------------------------------------------
-    def kernel(t0_ref, qp_ref, qn_ref, colsum_ref, proj_ref, projsum_ref,
-               qnproj_ref, coefact_ref, logna_ref, belief_ref, pa_ref,
-               scal_ref, obsm_ref, tutil_ref, envk_ref, envr_ref, pstack_ref,
-               arr_ref, haz_ref, unif_ref, gum_ref,
-               sftbl_ref, logc_ref, cost_ref, ptab_ref, *rest):
-        if obs_valid is not None:
-            ov_ref = rest[0]
-            rest = rest[1:]
-        qn_out, tr_act, tr_r, tr_rk, tr_rm, envk_out, envr_out = rest
+    def kernel(t0_ref, *refs):
+        it = iter(refs)
+        res = {n: next(it) for n in TAPE} if j_res else None
+        (colsum_ref, proj_ref, projsum_ref, logna_ref, belief_ref, pa_ref,
+         scal_ref, obsm_ref, tutil_ref, envk_ref, envr_ref, pstack_ref,
+         arr_ref, haz_ref, unif_ref, gum_ref, sftbl_ref, logc_ref, cost_ref,
+         ptab_ref) = (next(it) for _ in range(20))
+        ov_ref = next(it) if obs_valid is not None else None
+        hbm = {n: next(it) for n in TAPE} if streamed else None
+        qn_out, tr_act, tr_r, tr_rk, tr_rm, envk_out, envr_out = (
+            next(it) for _ in range(7))
+        if streamed:
+            bufs = {n: next(it) for n in TAPE}
+            sems = next(it)
 
         t0_v = t0_ref[0, 0]
         colsum = colsum_ref[...]                                 # (BR,A,S)
@@ -189,18 +336,85 @@ def mega_window_pallas(state, est, obs_carry, params,
                 b = b + (col >= e).astype(jnp.int32)
             return b
 
-        def over_slots(body, init):
-            """Fold ``body(slot_slice, acc)`` over the tape in J_c chunks."""
-            n_full, tail = divmod(j, j_chunk)
-            if n_full == 1:
-                acc = body(pl.ds(0, j_chunk), init)
-            else:
-                acc = jax.lax.fori_loop(
-                    0, n_full, lambda c, a: body(pl.ds(
-                        pl.multiple_of(c * j_chunk, j_chunk), j_chunk), a),
-                    init)
-            if tail:
-                acc = body(pl.ds(n_full * j_chunk, tail), acc)
+        if streamed:
+            # streamed chunks that hold a filled slot (slot < t0); the later
+            # ones carry zero coefficients and would add nothing
+            n_live = jnp.clip(jax.lax.div(t0_v - j_res + j_chunk - 1, j_chunk),
+                              0, n_stream)
+            tail_live = t0_v > j - tail_s
+            row0 = pl.multiple_of(pl.program_id(0) * br, br)
+            # a traced zero: the padded copies reach past the logical shape,
+            # which only the static bound check would refuse
+            zero = 0 if interpret else t0_v * 0
+            lane0 = zero if interpret else pl.multiple_of(zero, 128)
+
+        def copies(names, start, rows, b):
+            """Copies of the tape rows [start, start + rows) of ``names``
+            into buffer ``b``."""
+            return [pltpu.make_async_copy(
+                hbm[n].at[pl.ds(row0, br), pl.ds(start, rows),
+                          pl.ds(lane0, lanes[n])],
+                bufs[n].at[b, :, pl.ds(0, rows), :],
+                sems.at[TAPE.index(n), b]) for n in names]
+
+        def chunk_start(c):
+            return pl.multiple_of(j_res + c * j_chunk, j_chunk)
+
+        def over_slots(body, init, names):
+            """Fold ``body(tape, acc)`` over the tape in J_c chunks, where
+            ``tape(name)`` loads the chunk of one tape operand: the resident
+            prefix from its VMEM blocks, then the live streamed chunks,
+            copied two buffers deep (the copy of chunk c+1 overlaps the fold
+            of chunk c, and the first copy the resident fold)."""
+            if streamed:
+                @pl.when(n_live > 0)
+                def _():
+                    for cp in copies(names, chunk_start(0), j_chunk, 0):
+                        cp.start()
+            acc = init
+            if j_res:
+                def resident(js):
+                    return lambda n: res[n][:, js, :]
+                n_full, tail = divmod(j_res, j_chunk)
+                if n_full == 1:
+                    acc = body(resident(pl.ds(0, j_chunk)), acc)
+                else:
+                    acc = jax.lax.fori_loop(
+                        0, n_full, lambda c, a: body(resident(pl.ds(
+                            pl.multiple_of(c * j_chunk, j_chunk), j_chunk)),
+                            a), acc)
+                if tail:
+                    acc = body(resident(pl.ds(n_full * j_chunk, tail)), acc)
+            if not streamed:
+                return acc
+
+            def buffered(b, size):
+                return lambda n: bufs[n][b, :, pl.ds(0, size), :widths[n]]
+
+            def step(c, acc):
+                b = c % 2
+
+                @pl.when(c + 1 < n_live)
+                def _():
+                    for cp in copies(names, chunk_start(c + 1), j_chunk,
+                                     1 - b):
+                        cp.start()
+                for cp in copies(names, chunk_start(c), j_chunk, b):
+                    cp.wait()
+                return body(buffered(b, j_chunk), acc)
+
+            acc = jax.lax.fori_loop(0, n_live, step, acc)
+            if tail_s:
+                def tail_chunk(acc):
+                    start = (j - tail_s if interpret
+                             else pl.multiple_of(j - tail_s + zero, 8))
+                    cps = copies(names, start, tail_rows, 0)
+                    for cp in cps:
+                        cp.start()
+                    for cp in cps:
+                        cp.wait()
+                    return body(buffered(0, tail_s), acc)
+                acc = jax.lax.cond(tail_live, tail_chunk, lambda a: a, acc)
             return acc
 
         def tick(w, c, select: bool):
@@ -249,15 +463,16 @@ def mega_window_pallas(state, est, obs_carry, params,
             csum = jnp.sum(oh_pa[:, :, None] * colsum, axis=1)   # (BR, S)
             qt = belief / csum
 
-            def prior_slots(js, acc):
-                cw = jnp.sum(coefact_ref[:, js, :] * oh_pa[:, None, :],
+            def prior_slots(tape, acc):
+                cw = jnp.sum(tape("coefact") * oh_pa[:, None, :],
                              axis=-1, keepdims=True)             # (BR,Jc,1)
-                qp = qp_ref[:, js, :].astype(jnp.float32)
+                qp = tape("qp").astype(jnp.float32)
                 pend_p = cw * _batched_dot(qp, qt[:, None, :], 2, 2)
                 return acc + jnp.sum(
-                    pend_p * qn_ref[:, js, :].astype(jnp.float32), axis=1)
+                    pend_p * tape("qn").astype(jnp.float32), axis=1)
 
-            slot = over_slots(prior_slots, jnp.zeros_like(belief))
+            slot = over_slots(prior_slots, jnp.zeros_like(belief),
+                              _PRIOR_TAPE)
             num = u_c * rowsum(qt) + d_c * qt + slot
             prior = num / jnp.maximum(rowsum(num), 1e-30)
             logp = loglik + jnp.log(jnp.maximum(prior, 1e-30))
@@ -272,14 +487,14 @@ def mega_window_pallas(state, est, obs_carry, params,
                 qa = q_next[:, None, :] / colsum                 # (BR, A, S)
                 sqa = jnp.sum(qa, axis=-1)                       # (BR, A)
 
-                def efe_slots(js, acc):
-                    qp = qp_ref[:, js, :].astype(jnp.float32)
-                    pend = coefact_ref[:, js, :] * _batched_dot(qp, qa, 2, 2)
-                    return acc + _batched_dot(pend, qnproj_ref[:, js, :],
-                                              1, 1)
+                def efe_slots(tape, acc):
+                    qp = tape("qp").astype(jnp.float32)
+                    pend = tape("coefact") * _batched_dot(qp, qa, 2, 2)
+                    return acc + _batched_dot(pend, tape("qnproj"), 1, 1)
                 # slot terms of the (P) projections and, last, of Σ_t ŝ
                 o_slot = over_slots(
-                    efe_slots, jnp.zeros((br, a_n, p_n + 1), jnp.float32))
+                    efe_slots, jnp.zeros((br, a_n, p_n + 1), jnp.float32),
+                    _EFE_TAPE)
                 o_num = (u_c * sqa[:, :, None]
                          * projsum_ref[...][:, None, :]
                          + d_c * _batched_dot(qa, proj_ref[...], 2, 2)
@@ -511,16 +726,16 @@ def mega_window_pallas(state, est, obs_carry, params,
     raw_obs0, tier_util0, tier_up0, tier_queue0, obs_mask0 = obs_carry
     obsm = jnp.stack([raw_obs0, obs_mask0, est.held_obs])        # (3, R, M)
 
-    # (operand, cell axis, block shape); the block spans the cell axis in
-    # router blocks and every other axis whole
+    # the tape, in TAPE order, cell axis first
+    tape = [pad_cells(x, r_pad) for x in (
+        slots.q_prev, slots.q_next,
+        jnp.concatenate([cache.qnproj, cache.sumqn[..., None]], axis=-1),
+        cache.coefact)]
+    # (operand, cell axis); the block spans the cell axis in router blocks
+    # and every other axis whole
     cells = [
-        (slots.q_prev, 0), (slots.q_next, 0),
-        (cache.colsum, 0), (cache.proj, 0),
-        (cache.projsum, 0),
-        (jnp.concatenate([cache.qnproj, cache.sumqn[..., None]], axis=-1),
-         0),
-        (cache.coefact, 0), (cache.logna, 0),
-        (state.belief, 0), (state.prev_action[:, None], 0),
+        (cache.colsum, 0), (cache.proj, 0), (cache.projsum, 0),
+        (cache.logna, 0), (state.belief, 0), (state.prev_action[:, None], 0),
         (jnp.stack([state.dt_since_change, state.error_ema], axis=-1), 0),
         (obsm, 1), (tier_util0, 0), (envk, 1), (envr, 0), (pstack, 1),
         (arrival[..., None], 1), (hazard, 1), (uniforms, 2), (gumbel, 1),
@@ -542,12 +757,21 @@ def mega_window_pallas(state, est, obs_carry, params,
     cell_ops = [pad_cells(x, r_pad, axis) for x, axis in cells]
     cell_specs = [cell_spec(x.shape, axis)
                   for x, (_, axis) in zip(cell_ops, cells)]
-    in_specs = ([pl.BlockSpec(memory_space=pltpu.SMEM)]
+    # the resident prefix: the first j_res slots of each router block
+    res_specs = [pl.BlockSpec((br, j_res) + x.shape[2:], lambda i: (i, 0, 0))
+                 for x in tape] if j_res else []
+    hbm_specs = [pl.BlockSpec(memory_space=pl.ANY)] * 4 if streamed else []
+    in_specs = ([pl.BlockSpec(memory_space=pltpu.SMEM)] + res_specs
                 + cell_specs[:n_cells] + [full_spec(c.shape) for c in shared]
-                + cell_specs[n_cells:])
+                + cell_specs[n_cells:] + hbm_specs)
     operands = ([jnp.asarray(t0, jnp.int32).reshape(1, 1)]
+                + (tape if j_res else [])
                 + cell_ops[:n_cells] + [jnp.asarray(c) for c in shared]
-                + cell_ops[n_cells:])
+                + cell_ops[n_cells:] + (tape if streamed else []))
+    scratch = ([pltpu.VMEM((2, br, j_chunk, lanes[n]), dt)
+                for n, (_, dt) in zip(TAPE, plan.widths)]
+               + [pltpu.SemaphoreType.DMA((len(TAPE), 2))]
+               if streamed else [])
 
     out_cells = [
         ((w_ticks, r_pad, s), jnp.float32, 1),           # posteriors
@@ -561,18 +785,6 @@ def mega_window_pallas(state, est, obs_carry, params,
     out_shapes = [jax.ShapeDtypeStruct(sh, dt_) for sh, dt_, _ in out_cells]
     out_specs = [cell_spec(sh, ax) for sh, _, ax in out_cells]
 
-    blocks = ([(sp.block_shape, x.dtype)
-               for sp, x in zip(in_specs[1:], operands[1:])]
-              + [(sp.block_shape, sh.dtype)
-                 for sp, sh in zip(out_specs, out_shapes)])
-    vmem = mega_vmem_bytes(
-        blocks, block_vmem_bytes((br, j_chunk, s), jnp.float32))
-    if vmem > VMEM_LIMIT:
-        raise ValueError(
-            f"megakernel launch needs {vmem / 2**20:.0f} MiB of VMEM for a "
-            f"{j}-slot resident tape (limit {VMEM_LIMIT / 2**20:.0f} MiB): "
-            f"the horizon is too long for the resident-tape kernel — run it "
-            f"with use_pallas=False")
 
     outs = pl.pallas_call(
         kernel,
@@ -581,8 +793,9 @@ def mega_window_pallas(state, est, obs_carry, params,
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shapes,
+        scratch_shapes=scratch,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel",), vmem_limit_bytes=vmem),
+            dimension_semantics=("parallel",), vmem_limit_bytes=plan.vmem),
         interpret=interpret,
     )(*operands)
     with jax.named_scope("aif.window.land"):

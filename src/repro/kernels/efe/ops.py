@@ -193,7 +193,9 @@ def mega_window(state, est, obs_carry, params,
     ``use_pallas`` the window runs as the Pallas megakernel
     (:mod:`repro.kernels.efe.mega`), which keeps the posterior, factored
     transition cache, preference tables and env carry resident in VMEM for
-    all W ticks.  Chaos (``forced_down``/``speed``), sharded (``row_block``)
+    all W ticks, and the slot tape too as far as VMEM holds it: the rest of
+    the tape streams from HBM, so only the slots' replay capacity bounds
+    the horizon.  Chaos (``forced_down``/``speed``), sharded (``row_block``)
     and graph windows are not ported to the kernel: with ``use_pallas``
     they raise ``ValueError`` instead of running the oracle.  Inputs/outputs
     are identical either way:

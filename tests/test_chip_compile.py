@@ -126,9 +126,11 @@ def _mega_world(r, t, scenario):
 
 @pytest.mark.parametrize("r,t,scenario", [
     (64, 120, "paper-burst"), (64, 120, "flaky-telemetry"),
-    (2048, 600, "paper-burst")])
+    (2048, 600, "paper-burst"), (8, 3600, "paper-burst"),
+    (256, 3600, "paper-burst")])
 def test_mega_window_compiles(one_chip, r, t, scenario):
-    """One whole-window megakernel launch at horizon T (a T-slot tape)."""
+    """One whole-window megakernel launch at horizon T (a T-slot tape; at
+    T=3600 past what VMEM holds, so a resident prefix and a streamed rest)."""
     router, params, env_step = _mega_world(r, t, scenario)
     cfg, fl = router.cfg, env_step.fluid
     w, m, k = router.period, router.n_modalities, router.n_tiers
@@ -154,13 +156,11 @@ def test_mega_window_compiles(one_chip, r, t, scenario):
     _assert_fits_one_chip(jax.jit(window).lower(*args).compile())
 
 
-@pytest.fixture(scope="module")
-def mega_rollout(one_chip):
-    """The whole R=2048 x T=600 mega rollout program with the compiled
-    kernel, compiled for one chip.  Its inputs keep the device's default
-    layouts, as arrays created outside the program do; the engine's backend
-    probe sees the CPU here, so the fixture selects the compiled kernel."""
-    r, t = 2048, 600
+def _compile_mega_rollout(one_chip, r, t):
+    """The whole R x T mega rollout program with the compiled kernel,
+    compiled for one chip.  Its inputs keep the device's default layouts,
+    as arrays created outside the program do; the engine's backend probe
+    sees the CPU here, so the compiled kernel is selected by hand."""
     router, params, env_step = _mega_world(r, t, "paper-burst")
     fl = env_step.fluid
     m, k = router.n_modalities, router.n_tiers
@@ -183,9 +183,21 @@ def mega_rollout(one_chip):
             restart_blackout=fl.restart_blackout).compile()
 
 
+@pytest.fixture(scope="module")
+def mega_rollout(one_chip):
+    """The R=2048 x T=600 mega rollout program, compiled for one chip."""
+    return _compile_mega_rollout(one_chip, 2048, 600)
+
+
 def test_mega_rollout_fits_one_chip(mega_rollout):
     """The whole mega rollout program fits one chip's HBM."""
     _assert_fits_one_chip(mega_rollout)
+
+
+def test_hour_long_mega_rollout_fits_one_chip(one_chip):
+    """The R=256 x T=3600 rollout (one hour of 1 s windows, a tape past what
+    VMEM holds) compiles with the kernel and fits one chip's HBM."""
+    _assert_fits_one_chip(_compile_mega_rollout(one_chip, 256, 3600))
 
 
 def test_mega_rollout_names_its_kernel_and_scopes(mega_rollout):

@@ -20,6 +20,8 @@ from repro.core import generative
 from repro.core import mega as mega_core
 from repro.core.topology import Topology, default_topology, five_tier_topology
 from repro.envsim import batched
+from repro.kernels.efe import mega as mega_kernel
+from repro.kernels.efe.efe import block_vmem_bytes
 from repro.kernels.efe.ops import on_tpu
 
 KEY = jax.random.key(0)
@@ -541,6 +543,191 @@ def test_reducer_update_window_matches_sequential():
 
 
 # ---------------------------------------------------------- Pallas megakernel
+def _router_world(r, t, scenario="paper-burst"):
+    topo = default_topology()
+    scfg, params, env_step = experiment_mod._build_world(
+        topo, scenario, r, t, 1.0, 0)
+    return experiment_mod._make_aif(topo, scfg, False, True, True), env_step
+
+
+def _vmem_with(cfg, t, slot_dtype, chunk, n_resident):
+    """The VMEM ceiling at which a launch over a ``t``-slot tape holds
+    ``n_resident`` whole chunks resident and streams the rest: the other
+    blocks, the two buffers of each tape operand and the resident blocks,
+    all priced by hand."""
+    s = cfg.topology.n_states
+    widths = mega_kernel.tape_widths(cfg, slot_dtype).values()
+    fixed = mega_kernel.mega_vmem_bytes(
+        mega_kernel._fixed_blocks(cfg, 10, False),
+        block_vmem_bytes((8, chunk, s), jnp.float32))
+    buffers = sum(block_vmem_bytes((2, 8, chunk, w), d) for w, d in widths)
+    per_chunk = 2 * sum(block_vmem_bytes((8, chunk, w), d) for w, d in widths)
+    return fixed + buffers + n_resident * per_chunk
+
+
+@pytest.mark.parametrize("slot_dtype,n_resident", [
+    ("float32", 0), ("float32", 1), ("bfloat16", 0), ("bfloat16", 2)],
+    ids=["f32-streamed", "f32-prefix", "bf16-streamed", "bf16-prefix"])
+def test_mega_pallas_streamed_tape_matches_oracle(slot_dtype, n_resident):
+    """The kernel with its tape streamed from HBM (every chunk, or past a
+    resident prefix), in interpret mode: against the XLA oracle, bit-equal
+    actions and <=1e-4 everywhere; against the kernel holding the whole
+    tape resident in the same chunks, bit-equal throughout (same
+    arithmetic, same order; a skipped unfilled chunk would add zeros).  A
+    60-slot tape in 16-slot chunks ends in a 12-slot chunk, which the last
+    window (t0=50) reads."""
+    r, t, chunk = 3, 60, 16
+    router, _ = _router_world(r, t)
+    limit = _vmem_with(router.cfg, t, slot_dtype, chunk, n_resident)
+    plan = mega_kernel.tape_plan(router.cfg, t, 10, jnp.dtype(slot_dtype),
+                                 False, slot_chunk=chunk, vmem_limit=limit)
+    assert (plan.j_res, plan.j_chunk) == (n_resident * chunk, chunk)
+    base = dict(router="aif", fused=True, mega=True, n_cells=r,
+                n_windows=t, mega_slot_dtype=slot_dtype)
+
+    def kernel_run(**kernel_kw):
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(mega_kernel, "mega_window_pallas", functools.partial(
+                mega_kernel.mega_window_pallas, slot_chunk=chunk,
+                **kernel_kw))
+            jax.clear_caches()
+            try:
+                return run(Experiment(**base, use_pallas=True))
+            finally:
+                jax.clear_caches()
+    streamed = kernel_run(vmem_limit=limit)
+    _assert_rollouts_match(run(Experiment(**base)), streamed)
+    resident = kernel_run()
+    for name, a, b in (("final_carry", resident.final_carry,
+                        streamed.final_carry),
+                       ("trace", resident.trace, streamed.trace)):
+        for x, y in zip(jax.tree_util.tree_leaves(a),
+                        jax.tree_util.tree_leaves(b)):
+            np.testing.assert_array_equal(np.asarray(x), np.asarray(y),
+                                          err_msg=name)
+
+
+@pytest.mark.parametrize("t,n_resident", [(40, None), (60, 0), (60, 2)],
+                         ids=["resident", "streamed", "prefix"])
+def test_tape_plan_prices_every_block_of_the_launch(monkeypatch, t,
+                                                    n_resident):
+    """The VMEM a launch asks for is what its pipelined blocks (each
+    double-buffered), its scratch buffers and four chunk temporaries take,
+    read off the ``pallas_call`` it makes."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    r, chunk = 3, 16
+    router, env_step = _router_world(r, t)
+    cfg, fl = router.cfg, env_step.fluid
+    limit = (mega_kernel.VMEM_LIMIT if n_resident is None
+             else _vmem_with(cfg, t, jnp.float32, chunk, n_resident))
+    seen = {}
+    real = pl.pallas_call
+
+    def spy(kernel, **kw):
+        call = real(kernel, **kw)
+
+        def go(*operands):
+            seen.update(kw, operands=operands)
+            return call(*operands)
+        return go
+    monkeypatch.setattr(pl, "pallas_call", spy)
+    w, m, k = router.period, router.n_modalities, router.n_tiers
+    f32 = jnp.float32
+    sds = jax.ShapeDtypeStruct
+    args = (jax.eval_shape(lambda: mega_core.init_mega_state(cfg, r, t)),
+            jax.eval_shape(lambda: batched.init_fluid_state(fl.params)),
+            tuple(sds(x, f32) for x in ((r, m), (r, k), (r, k), (r, k),
+                                         (r, m))),
+            fl.params, sds((w, r), f32), sds((w, r, k), f32), None,
+            jax.eval_shape(lambda: jax.random.split(KEY, w)),
+            sds((w, r, cfg.n_actions), f32), sds((), jnp.int32))
+    jax.eval_shape(functools.partial(
+        mega_kernel.mega_window_pallas, cfg=cfg, disc=router.resolved_disc,
+        util_edges=router.resolved_util_edges,
+        util_period=router.util_period, dt=fl.dt,
+        scrape_every=fl.scrape_every, restart_blackout=False,
+        emits_mask=False, interpret=True, slot_chunk=chunk,
+        vmem_limit=limit), *args)
+    plan = mega_kernel.tape_plan(cfg, t, w, jnp.dtype(f32), False,
+                                 slot_chunk=chunk, vmem_limit=limit)
+    pipelined = [(sp.block_shape, x.dtype)
+                 for sp, x in zip(seen["in_specs"], seen["operands"])
+                 if sp.block_shape is not None]
+    pipelined += [(sp.block_shape, o.dtype)
+                  for sp, o in zip(seen["out_specs"], seen["out_shape"])]
+    scratch = sum(block_vmem_bytes(sc.shape, sc.dtype)
+                  for sc in seen["scratch_shapes"]
+                  if sc.memory_space == pltpu.VMEM)
+    want = (2 * sum(block_vmem_bytes(sh, d) for sh, d in pipelined)
+            + 4 * block_vmem_bytes((8, chunk, cfg.topology.n_states), f32)
+            + scratch)
+    assert plan.vmem == want
+    assert seen["compiler_params"].vmem_limit_bytes == want <= limit
+    assert plan.j_res == (t if n_resident is None else n_resident * chunk)
+    assert bool(scratch) == (plan.j_res < t)
+
+
+def test_tape_plan_refuses_only_what_cannot_fit():
+    """A long tape no longer bounds the launch (only the replay capacity
+    bounds the horizon); a ceiling below the other blocks and the tape
+    buffers alone is refused."""
+    router, _ = _router_world(8, 10)
+    cfg = router.cfg
+    plan = mega_kernel.tape_plan(cfg, 5000, 10, jnp.dtype(jnp.float32), False)
+    assert 0 < plan.j_res < 5000 and plan.vmem <= mega_kernel.VMEM_LIMIT
+    assert plan.j_res % plan.j_chunk == 0
+    floor = _vmem_with(cfg, 5000, jnp.float32, mega_kernel.SLOT_CHUNK, 0)
+    assert mega_kernel.tape_plan(cfg, 5000, 10, jnp.dtype(jnp.float32), False,
+                                 vmem_limit=floor).j_res == 0
+    with pytest.raises(ValueError, match="non-tape blocks"):
+        mega_kernel.tape_plan(cfg, 5000, 10, jnp.dtype(jnp.float32), False,
+                              vmem_limit=floor - 1)
+
+
+def test_tape_bytes_matches_hand_count():
+    """A 60-slot float32 tape, 16 slots resident and 16-slot chunks: the
+    streamed part [16, 60) is two whole chunks and a 12-slot one.
+
+    Bytes a slot: q_prev, q_next 243·4 = 972 each, qnproj|sumqn 17·4 = 68,
+    coefact 20·4 = 80: 2,092 in all; the prior reads coefact, q_prev, q_next
+    (2,024), the EFE q_prev, coefact, qnproj (1,120).  A window reads the
+    16 resident slots once (33,472) and each live streamed slot at its 10
+    ticks and 2 selecting ticks (10·2,024 + 2·1,120 = 22,480).  Live slots
+    by t0: 0, 10 -> 0; 20, 30 -> 16 (one chunk); 40 -> 32; 50 -> 32 + 12
+    (the short chunk [48, 60) holds slot 49).  Per row 6·33,472 +
+    (16 + 16 + 32 + 44)·22,480 = 2,628,672; eight padded rows."""
+    router, _ = _router_world(3, 60)
+    cfg = router.cfg
+    limit = _vmem_with(cfg, 60, jnp.float32, 16, 1)
+    got = sum(mega_kernel.tape_bytes(cfg, 3, 60, t0, 10, jnp.float32, False,
+                                     slot_chunk=16, vmem_limit=limit)
+              for t0 in range(0, 60, 10))
+    assert got == 8 * 2_628_672
+
+
+def test_dispatch_counts_the_tape_bytes(monkeypatch):
+    """``tape_bytes`` on the ``run.dispatch`` span and in the counter: a
+    resident 12-slot tape read once per window, two windows, eight padded
+    rows of 2,092 bytes a slot; none off the kernel."""
+    from repro import obs
+    seen = []
+    real = obs.span
+
+    def spy(name, **args):
+        if name == "run.dispatch":
+            seen.append(args)
+        return real(name, **args)
+    monkeypatch.setattr(obs, "span", spy)
+    for use_pallas in (True, False):
+        before = obs.counters().get("tape_bytes", 0)
+        run(Experiment(router="aif", mega=True, use_pallas=use_pallas,
+                       n_cells=3, n_windows=12))
+        got = obs.counters().get("tape_bytes", 0) - before
+        assert got == (2 * 8 * 12 * 2_092 if use_pallas else 0)
+    assert [a.get("tape_bytes") for a in seen] == [2 * 8 * 12 * 2_092, None]
+
+
 def test_mega_pallas_interpret_matches_oracle():
     """Interpret-mode Pallas megakernel vs the XLA oracle twin: bit-equal
     actions, <=1e-4 everywhere (CI smoke for the kernel body)."""
