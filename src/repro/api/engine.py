@@ -112,27 +112,28 @@ def rollout(router: Router,
 
 
 @contextlib.contextmanager
-def _dispatch(launches: int, tape_bytes: int | None = None):
+def _dispatch(launches: int, kernel: dict | None = None):
     """The ``repro.run.dispatch`` span around ``launches`` jitted launches
     (they return once enqueued; the device work is waited for later).
-    Where they run the Pallas megakernel, ``tape_bytes`` is the slot tape
-    its windows read from HBM (:func:`_kernel_tape_bytes`), counted and
-    carried on the span beside ``launches``."""
+    Where they run the Pallas megakernel, ``kernel`` holds its counts
+    (:func:`_kernel_counts`), each counted and carried on the span beside
+    ``launches``."""
     obs.count("launches", launches)
     args = {"launches": launches}
-    if tape_bytes is not None:
-        obs.count("tape_bytes", tape_bytes)
-        args["tape_bytes"] = tape_bytes
+    for name, n in (kernel or {}).items():
+        obs.count(name, n)
+        args[name] = n
     with obs.span("run.dispatch", **args):
         yield
 
 
-def _kernel_tape_bytes(router, state, has_obs_valid: bool, t_begin: int,
-                       n_steps: int) -> int | None:
-    """Slot-tape bytes the megakernel reads from HBM over the windows of
-    ticks [t_begin, t_begin + n_steps), from shapes; None off the kernel.
-    Windows start every slow period (chunked launches too), the last one
-    possibly short."""
+def _kernel_counts(router, state, has_obs_valid: bool, t_begin: int,
+                   n_steps: int) -> dict | None:
+    """What the megakernel's windows over ticks [t_begin, t_begin +
+    n_steps) do, from shapes: ``tape_bytes``, the slot tape they read from
+    HBM, and ``folded_slots``, the slot rows their prior folds cover; None
+    off the kernel.  Windows start every slow period (chunked launches
+    too), the last one possibly short."""
     if not router.use_pallas:
         return None
     from repro.kernels.efe import mega as mega_kernel
@@ -140,10 +141,12 @@ def _kernel_tape_bytes(router, state, has_obs_valid: bool, t_begin: int,
     dtype = state.slots.q_prev.dtype
     period = max(int(router.period), 1)
     end = t_begin + n_steps
-    return sum(mega_kernel.tape_bytes(router.cfg, r, j, t0,
-                                      min(period, end - t0), dtype,
-                                      has_obs_valid)
-               for t0 in range(t_begin, end, period))
+    windows = [(t0, min(period, end - t0))
+               for t0 in range(t_begin, end, period)]
+    return {name: sum(count(router.cfg, r, j, t0, w, dtype, has_obs_valid)
+                      for t0, w in windows)
+            for name, count in (("tape_bytes", mega_kernel.tape_bytes),
+                                ("folded_slots", mega_kernel.folded_slots))}
 
 
 def _row_block_keys(key: jax.Array, row_start: jnp.ndarray, n_true: int,
@@ -621,10 +624,10 @@ def _mega_rollout(router, carry, env_state, env_step: Callable, n_steps: int,
             obs_masked=obs_masked, dt=fl.dt, scrape_every=fl.scrape_every,
             restart_blackout=fl.restart_blackout)
 
-    tape = _kernel_tape_bytes(router, state_in, fl.obs_valid is not None,
-                              t_begin, n_steps)
+    kernel = _kernel_counts(router, state_in, fl.obs_valid is not None,
+                            t_begin, n_steps)
     if launch_periods is None:
-        with _dispatch(1, tape):
+        with _dispatch(1, kernel):
             return launch(state_in, env_state, obs_carry, key, t_begin,
                           n_steps)
     if int(launch_periods) < 1:
@@ -638,7 +641,7 @@ def _mega_rollout(router, carry, env_state, env_step: Callable, n_steps: int,
     chunk = int(launch_periods) * period
     state, est, obs_c, k = state_in, env_state, obs_carry, key
     traces, c0 = [], 0
-    with _dispatch(-(-n_steps // chunk), tape):
+    with _dispatch(-(-n_steps // chunk), kernel):
         while c0 < n_steps:
             n = min(chunk, n_steps - c0)
             state, est, tr, (obs_c, k) = launch(state, est, obs_c, k,

@@ -55,10 +55,11 @@ block's whole tape fits :data:`VMEM_LIMIT` it is held resident (read once a
 window); where it does not, the launch holds the longest resident prefix
 the priced VMEM leaves room for and streams the rest from HBM, one chunk at
 a time into two VMEM buffers, so the copy of the next chunk overlaps the
-fold of this one.  A streamed chunk is copied at every tick whose fold
-needs it, and only where it holds a filled slot (slot index < t0: the
-later slots carry zero coefficients and add nothing to the fold).  The
-arithmetic and its order within a chunk are the same either way.  The
+fold of this one.  Resident or streamed, the fold reads only the chunks
+that hold a filled slot (slot index < t0, :func:`live_chunks`: the later
+slots carry zero coefficients and add nothing to it), and a streamed
+chunk is copied at every tick whose fold needs it.  The arithmetic and its
+order within a chunk are the same either way.  The
 launch asks the compiler for exactly the priced scoped VMEM and refuses one
 whose non-tape blocks alone exceed the ceiling; the horizon's one bound is
 the slots' replay capacity (:func:`repro.core.mega.init_mega_state`).
@@ -191,14 +192,27 @@ def tape_plan(cfg, j: int, w_ticks: int, slot_dtype, has_obs_valid: bool,
                     tuple(widths.values()))
 
 
-def _live_slots(plan: TapePlan, j: int, t0: int) -> int:
-    """Slots of the streamed part a window at ``t0`` copies per fold: the
-    whole chunks, and the short last one, that hold a filled slot."""
-    if t0 <= plan.j_res:
-        return 0
-    n_full, tail = divmod(j - plan.j_res, plan.j_chunk)
-    live = min(-(-(t0 - plan.j_res) // plan.j_chunk), n_full) * plan.j_chunk
-    if tail and t0 > j - tail:
+def live_chunks(t0, start: int, stop: int, j_chunk: int):
+    """The chunks of tape slots ``[start, stop)``, folded ``j_chunk`` at a
+    time with the last one possibly short, that hold a filled slot (slot
+    index < ``t0``, a traced int32): how many of the whole chunks, from the
+    first, and whether the short one does.  The later slots carry zero
+    coefficients until the next slow boundary re-weighs them, so a fold
+    that skips them adds the same.  :func:`_live_slots` is the host's
+    count of the same rule."""
+    n_full, tail = divmod(stop - start, j_chunk)
+    whole = jnp.clip(jax.lax.div(t0 - start + j_chunk - 1, j_chunk), 0,
+                     n_full)
+    return whole, (t0 > stop - tail) if tail else False
+
+
+def _live_slots(t0: int, start: int, stop: int, j_chunk: int) -> int:
+    """Slots of the tape part ``[start, stop)`` a fold at window start
+    ``t0`` reads: the whole chunks, and the short last one, that hold a
+    filled slot (:func:`live_chunks`, on the host)."""
+    n_full, tail = divmod(stop - start, j_chunk)
+    live = min(max(-(-(t0 - start) // j_chunk), 0), n_full) * j_chunk
+    if tail and t0 > stop - tail:
         live += tail
     return live
 
@@ -214,11 +228,23 @@ def tape_bytes(cfg, r: int, j: int, t0: int, w_ticks: int, slot_dtype,
     per_slot = {n: wd * dt.itemsize for n, (wd, dt) in zip(TAPE, plan.widths)}
     dwell = max(int(cfg.action_dwell_s / cfg.fast_period_s), 1)
     selecting = len(range(0, w_ticks, dwell))
-    live = _live_slots(plan, j, t0)
+    live = _live_slots(t0, plan.j_res, j, plan.j_chunk)
     streamed = live * (w_ticks * sum(per_slot[n] for n in _PRIOR_TAPE)
                        + selecting * sum(per_slot[n] for n in _EFE_TAPE))
     return pad_rows(r, SUBLANES) * (plan.j_res * sum(per_slot.values())
                                     + streamed)
+
+
+def folded_slots(cfg, r: int, j: int, t0: int, w_ticks: int, slot_dtype,
+                 has_obs_valid: bool, **plan_kw) -> int:
+    """Slot rows the belief prior's fold of one launch at window start
+    ``t0`` covers, counted from shapes: the live slots of the resident
+    prefix and of the streamed rest, at every tick, for every padded
+    row."""
+    plan = tape_plan(cfg, j, w_ticks, slot_dtype, has_obs_valid, **plan_kw)
+    live = (_live_slots(t0, 0, plan.j_res, plan.j_chunk)
+            + _live_slots(t0, plan.j_res, j, plan.j_chunk))
+    return pad_rows(r, SUBLANES) * w_ticks * live
 
 
 def mega_window_pallas(state, est, obs_carry, params,
@@ -260,7 +286,7 @@ def mega_window_pallas(state, est, obs_carry, params,
                      slot_chunk=slot_chunk, vmem_limit=vmem_limit)
     j_res, j_chunk = plan.j_res, plan.j_chunk
     streamed = j_res < j
-    n_stream, tail_s = divmod(j - j_res, j_chunk)
+    tail_s = (j - j_res) % j_chunk
     widths = {n: wd for n, (wd, _) in zip(TAPE, plan.widths)}
     # compiled, a copy moves whole (8, 128) tiles of the tape's HBM layout:
     # every lane of the padded width, and the short last chunk's rows up to
@@ -336,12 +362,12 @@ def mega_window_pallas(state, est, obs_carry, params,
                 b = b + (col >= e).astype(jnp.int32)
             return b
 
+        # the chunks that hold a filled slot (slot < t0), of the resident
+        # prefix and of the streamed rest: a fold reads no other
+        if j_res:
+            n_res, res_tail_live = live_chunks(t0_v, 0, j_res, j_chunk)
         if streamed:
-            # streamed chunks that hold a filled slot (slot < t0); the later
-            # ones carry zero coefficients and would add nothing
-            n_live = jnp.clip(jax.lax.div(t0_v - j_res + j_chunk - 1, j_chunk),
-                              0, n_stream)
-            tail_live = t0_v > j - tail_s
+            n_live, tail_live = live_chunks(t0_v, j_res, j, j_chunk)
             row0 = pl.multiple_of(pl.program_id(0) * br, br)
             # a traced zero: the padded copies reach past the logical shape,
             # which only the static bound check would refuse
@@ -360,12 +386,15 @@ def mega_window_pallas(state, est, obs_carry, params,
         def chunk_start(c):
             return pl.multiple_of(j_res + c * j_chunk, j_chunk)
 
+        def when(live, fold, acc):
+            return jax.lax.cond(live, fold, lambda a: a, acc)
+
         def over_slots(body, init, names):
-            """Fold ``body(tape, acc)`` over the tape in J_c chunks, where
-            ``tape(name)`` loads the chunk of one tape operand: the resident
-            prefix from its VMEM blocks, then the live streamed chunks,
-            copied two buffers deep (the copy of chunk c+1 overlaps the fold
-            of chunk c, and the first copy the resident fold)."""
+            """Fold ``body(tape, acc)`` over the live chunks of the tape,
+            where ``tape(name)`` loads the chunk of one tape operand: those
+            of the resident prefix from its VMEM blocks, then the streamed
+            ones, copied two buffers deep (the copy of chunk c+1 overlaps
+            the fold of chunk c, and the first copy the resident fold)."""
             if streamed:
                 @pl.when(n_live > 0)
                 def _():
@@ -373,18 +402,20 @@ def mega_window_pallas(state, est, obs_carry, params,
                         cp.start()
             acc = init
             if j_res:
-                def resident(js):
-                    return lambda n: res[n][:, js, :]
+                def resident(start, size):
+                    return lambda n: res[n][:, pl.ds(start, size), :]
                 n_full, tail = divmod(j_res, j_chunk)
                 if n_full == 1:
-                    acc = body(resident(pl.ds(0, j_chunk)), acc)
+                    acc = when(n_res > 0,
+                               lambda a: body(resident(0, j_chunk), a), acc)
                 else:
                     acc = jax.lax.fori_loop(
-                        0, n_full, lambda c, a: body(resident(pl.ds(
-                            pl.multiple_of(c * j_chunk, j_chunk), j_chunk)),
+                        0, n_res, lambda c, a: body(resident(
+                            pl.multiple_of(c * j_chunk, j_chunk), j_chunk),
                             a), acc)
                 if tail:
-                    acc = body(resident(pl.ds(n_full * j_chunk, tail)), acc)
+                    acc = when(res_tail_live, lambda a: body(
+                        resident(n_full * j_chunk, tail), a), acc)
             if not streamed:
                 return acc
 
@@ -414,7 +445,7 @@ def mega_window_pallas(state, est, obs_carry, params,
                     for cp in cps:
                         cp.wait()
                     return body(buffered(0, tail_s), acc)
-                acc = jax.lax.cond(tail_live, tail_chunk, lambda a: a, acc)
+                acc = when(tail_live, tail_chunk, acc)
             return acc
 
         def tick(w, c, select: bool):

@@ -7,8 +7,9 @@ profiler trace of ``repro.api.run`` lines each host step up with the device
 work it waited for.  Outside a trace a span costs one inactive annotation.
 
 Counters are process-wide host integers: ``runs``, ``launches``,
-``tape_bytes`` (slot tape the megakernel's launches read from HBM, counted
-from shapes), ``cell_windows``, ``watchdog_events``, and, from a
+``tape_bytes`` (slot tape the megakernel's launches read from HBM) and
+``folded_slots`` (slot rows their prior folds cover), both counted from
+shapes, ``cell_windows``, ``watchdog_events``, and, from a
 :mod:`jax.monitoring` listener, ``traces`` (a jit cache miss that traced a
 function) and ``compiles`` (a backend compile or persistent-cache load).
 None of them waits for the device.  :func:`counters` returns a snapshot.
@@ -28,7 +29,7 @@ _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 #: counters whose change over a call the call's ``repro.run.counts`` marker
 #: carries
 CALL_COUNTERS = ("traces", "compiles", "launches", "tape_bytes",
-                 "cell_windows", "watchdog_events")
+                 "folded_slots", "cell_windows", "watchdog_events")
 
 _lock = threading.Lock()
 _counts: collections.Counter = collections.Counter()

@@ -565,6 +565,27 @@ def _vmem_with(cfg, t, slot_dtype, chunk, n_resident):
     return fixed + buffers + n_resident * per_chunk
 
 
+def _kernel_run(experiment, chunk, **kernel_kw):
+    """``experiment`` through the interpret-mode kernel folding its tape in
+    ``chunk``-slot chunks (``kernel_kw``: more keywords of the launch)."""
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(mega_kernel, "mega_window_pallas", functools.partial(
+            mega_kernel.mega_window_pallas, slot_chunk=chunk, **kernel_kw))
+        jax.clear_caches()
+        try:
+            return run(experiment)
+        finally:
+            jax.clear_caches()
+
+
+def _assert_bit_equal(r1, r2):
+    for name in ("final_carry", "trace"):
+        for x, y in zip(jax.tree_util.tree_leaves(getattr(r1, name)),
+                        jax.tree_util.tree_leaves(getattr(r2, name))):
+            np.testing.assert_array_equal(np.asarray(x), np.asarray(y),
+                                          err_msg=name)
+
+
 @pytest.mark.parametrize("slot_dtype,n_resident", [
     ("float32", 0), ("float32", 1), ("bfloat16", 0), ("bfloat16", 2)],
     ids=["f32-streamed", "f32-prefix", "bf16-streamed", "bf16-prefix"])
@@ -584,27 +605,65 @@ def test_mega_pallas_streamed_tape_matches_oracle(slot_dtype, n_resident):
     assert (plan.j_res, plan.j_chunk) == (n_resident * chunk, chunk)
     base = dict(router="aif", fused=True, mega=True, n_cells=r,
                 n_windows=t, mega_slot_dtype=slot_dtype)
-
-    def kernel_run(**kernel_kw):
-        with pytest.MonkeyPatch.context() as mp:
-            mp.setattr(mega_kernel, "mega_window_pallas", functools.partial(
-                mega_kernel.mega_window_pallas, slot_chunk=chunk,
-                **kernel_kw))
-            jax.clear_caches()
-            try:
-                return run(Experiment(**base, use_pallas=True))
-            finally:
-                jax.clear_caches()
-    streamed = kernel_run(vmem_limit=limit)
+    kernel = Experiment(**base, use_pallas=True)
+    streamed = _kernel_run(kernel, chunk, vmem_limit=limit)
     _assert_rollouts_match(run(Experiment(**base)), streamed)
-    resident = kernel_run()
-    for name, a, b in (("final_carry", resident.final_carry,
-                        streamed.final_carry),
-                       ("trace", resident.trace, streamed.trace)):
-        for x, y in zip(jax.tree_util.tree_leaves(a),
-                        jax.tree_util.tree_leaves(b)):
-            np.testing.assert_array_equal(np.asarray(x), np.asarray(y),
-                                          err_msg=name)
+    _assert_bit_equal(_kernel_run(kernel, chunk), streamed)
+
+
+@pytest.fixture(scope="module")
+def oracle_60():
+    """The XLA oracle's run of three cells over 60 windows."""
+    return run(Experiment(router="aif", fused=True, mega=True, n_cells=3,
+                          n_windows=60))
+
+
+@pytest.mark.parametrize("chunk", [16, 20, 40],
+                         ids=["tail", "on-boundaries", "one-whole-chunk"])
+def test_mega_pallas_resident_fold_skips_unfilled_chunks(oracle_60, chunk):
+    """The kernel holding a 60-slot tape resident folds only the chunks
+    that hold a filled slot (slot < t0), in interpret mode: bit-equal to
+    the kernel streaming the whole tape in the same chunks, which follows
+    the same rule, and bit-equal actions and <=1e-4 everywhere against the
+    XLA oracle, which folds every slot.  Windows start at t0 = 0 (nothing
+    folded), 10, ..., 50.  16-slot chunks: 3 whole and a 12-slot tail
+    [48, 60), t0=10 inside the first chunk and t0=50 reaching the tail;
+    20-slot chunks: 3 whole, t0=20 and 40 on chunk boundaries; 40-slot
+    chunks: one whole chunk (its own path in the kernel), skipped at t0=0,
+    and a 20-slot tail."""
+    r, t = 3, 60
+    router, _ = _router_world(r, t)
+    limit = _vmem_with(router.cfg, t, jnp.float32, chunk, 0)
+    assert (mega_kernel.tape_plan(router.cfg, t, 10, jnp.dtype(jnp.float32),
+                                  False, slot_chunk=chunk).j_res == t)
+    assert (mega_kernel.tape_plan(router.cfg, t, 10, jnp.dtype(jnp.float32),
+                                  False, slot_chunk=chunk,
+                                  vmem_limit=limit).j_res == 0)
+    kernel = Experiment(router="aif", fused=True, mega=True, n_cells=r,
+                        n_windows=t, use_pallas=True)
+    resident = _kernel_run(kernel, chunk)
+    _assert_rollouts_match(oracle_60, resident)
+    _assert_bit_equal(resident, _kernel_run(kernel, chunk, vmem_limit=limit))
+
+
+@pytest.mark.parametrize("j,j_res", [(600, 600), (3600, 2048)],
+                         ids=["r2048", "t3600"])
+def test_live_chunks_agree_with_the_hosts_live_slots(j, j_res):
+    """The kernel's live-chunk rule and the host's count of live slots
+    agree at every t0 of both cells' tapes, for the resident prefix and
+    the streamed rest."""
+    router, _ = _router_world(8, 10)
+    jc = mega_kernel.SLOT_CHUNK
+    assert mega_kernel.tape_plan(router.cfg, j, 10, jnp.dtype(jnp.float32),
+                                 False).j_res == j_res
+    t0 = np.arange(j + 1)
+    for start, stop in ((0, j_res), (j_res, j)):
+        whole, tail_live = mega_kernel.live_chunks(
+            jnp.asarray(t0, jnp.int32), start, stop, jc)
+        got = (np.asarray(whole) * jc
+               + np.where(tail_live, (stop - start) % jc, 0))
+        want = [mega_kernel._live_slots(int(t), start, stop, jc) for t in t0]
+        np.testing.assert_array_equal(got, want)
 
 
 @pytest.mark.parametrize("t,n_resident", [(40, None), (60, 0), (60, 2)],
@@ -706,10 +765,30 @@ def test_tape_bytes_matches_hand_count():
     assert got == 8 * 2_628_672
 
 
-def test_dispatch_counts_the_tape_bytes(monkeypatch):
-    """``tape_bytes`` on the ``run.dispatch`` span and in the counter: a
-    resident 12-slot tape read once per window, two windows, eight padded
-    rows of 2,092 bytes a slot; none off the kernel."""
+def test_folded_slots_matches_hand_count():
+    """The 60-slot float32 tape in 16-slot chunks, held whole (3 chunks and
+    a 12-slot tail) or behind a 16-slot resident prefix: the same slots are
+    live either way.  Live slots by t0: 0 -> 0; 10 -> 16; 20, 30 -> 32;
+    40 -> 48; 50 -> 60 (the tail [48, 60) holds slot 49).  (With the
+    prefix: 16 resident from t0=10 on, and the streamed 0, 0, 16, 16, 32,
+    44 of :func:`test_tape_bytes_matches_hand_count`.)  188 slots, each
+    folded at 10 ticks for eight padded rows."""
+    router, _ = _router_world(3, 60)
+    cfg = router.cfg
+    for limit in (mega_kernel.VMEM_LIMIT, _vmem_with(cfg, 60, jnp.float32,
+                                                     16, 1)):
+        got = sum(mega_kernel.folded_slots(cfg, 3, 60, t0, 10, jnp.float32,
+                                           False, slot_chunk=16,
+                                           vmem_limit=limit)
+                  for t0 in range(0, 60, 10))
+        assert got == 8 * 10 * 188
+
+
+@pytest.fixture(scope="module")
+def dispatched():
+    """A kernel run and an oracle run of a resident 12-slot tape (two
+    windows: 10 ticks from t0=0, 2 from t0=10): each counter's change over
+    each run, and the arguments of every ``run.dispatch`` span."""
     from repro import obs
     seen = []
     real = obs.span
@@ -718,14 +797,35 @@ def test_dispatch_counts_the_tape_bytes(monkeypatch):
         if name == "run.dispatch":
             seen.append(args)
         return real(name, **args)
-    monkeypatch.setattr(obs, "span", spy)
-    for use_pallas in (True, False):
-        before = obs.counters().get("tape_bytes", 0)
-        run(Experiment(router="aif", mega=True, use_pallas=use_pallas,
-                       n_cells=3, n_windows=12))
-        got = obs.counters().get("tape_bytes", 0) - before
-        assert got == (2 * 8 * 12 * 2_092 if use_pallas else 0)
+    deltas = []
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(obs, "span", spy)
+        for use_pallas in (True, False):
+            before = obs.counters()
+            run(Experiment(router="aif", mega=True, use_pallas=use_pallas,
+                           n_cells=3, n_windows=12))
+            after = obs.counters()
+            deltas.append({k: after[k] - before.get(k, 0) for k in after})
+    return deltas, seen
+
+
+def test_dispatch_counts_the_tape_bytes(dispatched):
+    """``tape_bytes`` on the ``run.dispatch`` span and in the counter: a
+    resident 12-slot tape read once per window, two windows, eight padded
+    rows of 2,092 bytes a slot; none off the kernel."""
+    deltas, seen = dispatched
+    assert [d.get("tape_bytes", 0) for d in deltas] == [2 * 8 * 12 * 2_092, 0]
     assert [a.get("tape_bytes") for a in seen] == [2 * 8 * 12 * 2_092, None]
+
+
+def test_dispatch_counts_the_folded_slots(dispatched):
+    """``folded_slots`` on the ``run.dispatch`` span and in the counter:
+    the window at t0=0 folds nothing, the one at t0=10 the tape's one
+    12-slot chunk at its 2 ticks, for eight padded rows; none off the
+    kernel."""
+    deltas, seen = dispatched
+    assert [d.get("folded_slots", 0) for d in deltas] == [12 * 2 * 8, 0]
+    assert [a.get("folded_slots") for a in seen] == [12 * 2 * 8, None]
 
 
 def test_mega_pallas_interpret_matches_oracle():
