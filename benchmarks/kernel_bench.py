@@ -16,12 +16,9 @@ import time
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
-from repro.core import AifConfig, generative, policies, spaces
 from repro.compile_cache import enable_compile_cache
 from repro.kernels.attention.ref import decode_ref, mha_ref
-from repro.kernels.efe.ops import fleet_efe
 from repro.kernels.ssd.ref import ssd_ref
 
 
@@ -33,25 +30,6 @@ def _time(fn, *args, iters=5):
         out = fn(*args)
     jax.block_until_ready(out)
     return (time.perf_counter() - t0) / iters * 1e6   # us
-
-
-def bench_efe(quick: bool = False) -> tuple[str, float, str]:
-    cfg = AifConfig()
-    topo = cfg.topology
-    r = 8 if quick else 64
-    key = jax.random.key(0)
-    S, A = topo.n_states, policies.n_actions(topo)
-    M, NB = topo.n_modalities, topo.max_bins
-    a_counts = (jax.random.uniform(key, (r, M, NB, S)) + 0.1) * \
-        spaces.bins_mask(topo)[None, :, :, None]
-    b_counts = jax.random.uniform(jax.random.fold_in(key, 1),
-                                  (r, A, S, S)) + 0.01
-    c_log = jnp.tile(generative.nominal_c_log(cfg)[None], (r, 1, 1))
-    q = jax.random.dirichlet(jax.random.fold_in(key, 2), jnp.ones(S), (r,))
-    f = jax.jit(lambda *xs: fleet_efe(*xs, cfg, use_pallas=False))
-    us = _time(f, a_counts, b_counts, c_log, q)
-    flops = 2 * r * A * S * S          # dominant batched matvec
-    return (f"efe_fleet_r{r}", us, f"{flops/us/1e3:.1f}GFLOPs")
 
 
 def bench_attention(quick: bool = False) -> list[tuple[str, float, str]]:
@@ -93,7 +71,7 @@ def bench_ssd(quick: bool = False) -> tuple[str, float, str]:
 
 
 def run(quick: bool = False) -> list[tuple[str, float, str]]:
-    rows = [bench_efe(quick)] + bench_attention(quick) + [bench_ssd(quick)]
+    rows = bench_attention(quick) + [bench_ssd(quick)]
     for name, us, derived in rows:
         print(f"{name},{us:.1f},{derived}")
     return rows

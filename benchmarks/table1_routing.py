@@ -83,13 +83,13 @@ def run_event(duration_s: float, n_runs: int, out_json: str | None = None,
 
 def run_batched(routers: tuple[str, ...], scenario_names: tuple[str, ...],
                 n_cells: int, n_windows: int, seed: int = 0,
-                fused: bool = True, out_json: str | None = None) -> dict:
+                out_json: str | None = None) -> dict:
     """The comparison grid on the batched engine via :mod:`repro.api`."""
     from repro import api
     t0 = time.time()
     comp = api.compare(api.table1_grid(
         routers=routers, scenario_names=scenario_names, n_cells=n_cells,
-        n_windows=n_windows, seed=seed, fused=fused))
+        n_windows=n_windows, seed=seed))
     wall = time.time() - t0
     print(comp.markdown())
     cells = len(comp.results) * n_cells * n_windows

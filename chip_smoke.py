@@ -52,7 +52,7 @@ from repro.envsim import batched  # noqa: E402
 #: such cells among 2048 and refuse a kernel that routes differently.
 SUCCESS_PP = 0.5
 P95_REL = 0.02
-#: Sharded vs unsharded (``tests/test_mega.py``'s multi-device bounds).
+#: Sharded vs unsharded (``tests/test_mega_launch.py``'s multi-device bounds).
 SHARD_ATOL = 1e-4
 #: Largest per-device peak over the smallest, for an even R=8192 split.
 PEAK_SPREAD = 1.2
@@ -94,7 +94,8 @@ def precision_probe() -> None:
 
 def trace_deviation(r_ref, r_new) -> dict:
     """Per-field max |difference| of two rollouts' traces, plus the count of
-    differing actions (``tests/test_mega.py``'s rollout-parity checks)."""
+    differing actions (``tests/test_mega_parity.py``'s rollout-parity
+    checks)."""
     out = {"actions_differing": int(np.sum(
         np.asarray(r_ref.trace.actions) != np.asarray(r_new.trace.actions)))}
     pairs = [(n, getattr(r_ref.trace, n), getattr(r_new.trace, n))

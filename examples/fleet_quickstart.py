@@ -55,8 +55,8 @@ def main():
           "the capacity-aware router on P95 while paying the exploration "
           "price in success rate under instability (paper §5.2).  Scale "
           "n_cells/n_windows, swap the scenario ('cascade', "
-          "'hetero-diurnal', ...), or pass fused=True to the Experiment to "
-          "route EFE through the fused fleet kernel.")
+          "'hetero-diurnal', ...), or pass mega=True to the Experiment to "
+          "run the whole-window engine.")
 
 
 if __name__ == "__main__":

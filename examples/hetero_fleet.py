@@ -7,8 +7,7 @@ side by side on the same diurnal load shape:
 
 * 4 cells of the paper's 3-tier testbed (|S| = 243, 20 policies),
 * 3 cells of the 5-tier cloud/regional/metro/far-edge/device continuum
-  (|S| = 128 via binary levels, 37 generated policies), with the fused EFE
-  kernel (interpret mode off-TPU) exercising the shape-generic kernel path.
+  (|S| = 128 via binary levels, 37 generated policies).
 
 (For pre-grouped shards sharing one call, see
 ``repro.core.fleet.hetero_fleet_rollout``.)
@@ -34,14 +33,12 @@ def main():
         api.Experiment(router="aif", topology="paper-3tier", n_cells=4,
                        n_windows=t, scenario="diurnal"),
         api.Experiment(router="aif", topology="continuum-5tier", n_cells=3,
-                       n_windows=t, scenario="diurnal",
-                       fused=True, use_pallas=True),
+                       n_windows=t, scenario="diurnal"),
     ]
     print(f"heterogeneous fleet, {t} control windows per shard:")
     for e in shards:
         topo = e.resolve_topology()
-        print(f"  {e.topology}: {topo.describe()}, {e.n_cells} cells"
-              + (" [fused EFE kernel]" if e.fused else ""))
+        print(f"  {e.topology}: {topo.describe()}, {e.n_cells} cells")
 
     t0 = time.time()
     results = [api.run(e) for e in shards]

@@ -2,8 +2,8 @@
 
 This is the paper's router as a fleet policy: the spec wraps everything the
 old 13-argument ``fleet_rollout`` signature hand-assembled (agent config,
-observation discretization, utilization-scrape edges/cadence, fused/Pallas
-EFE execution path) into one hashable object the engine treats as a static
+observation discretization, utilization-scrape edges/cadence, engine
+path) into one hashable object the engine treats as a static
 jit argument.  The step/light/slow hooks are *exactly* the agent-side body
 of the pre-refactor ``fleet_rollout`` tick (same ops, same order, same PRNG
 consumption), so the AIF path through :func:`repro.api.engine.rollout` is
@@ -33,16 +33,16 @@ class AifRouter(Router):
         rows must match the topology's modalities.
       util_edges: raw-utilization level edges (None = the topology's).
       util_period: windows between utilization scrapes.
-      fused: run belief update + EFE through the fused fleet kernel.
-      use_pallas: with ``fused``, dispatch the Pallas TPU kernel rather
-        than the XLA oracle.
       mega: run the whole-window megakernel engine path — the transition
         model stays in factored (slot) form, the whole rollout fuses into
         one super-launch (periods scanned inside; chunk with the engine's
         ``launch_periods``) and the rollout carry becomes a
         :class:`repro.core.mega.MegaFleetState` (densify with
-        :func:`repro.core.mega.to_agent_state`).  With ``use_pallas`` the
-        window dispatches the Pallas megakernel instead of its XLA oracle.
+        :func:`repro.core.mega.to_agent_state`).  Without it the router
+        runs the per-tick reference engine (:mod:`repro.core.fleet`).
+      use_pallas: with ``mega``, each window dispatches the Pallas
+        megakernel instead of its XLA oracle.  It has no meaning on the
+        per-tick engine, so setting it without ``mega`` raises.
       mega_slot_dtype: storage dtype of the (R, J, S) transition slots on
         the mega path — "float32" (default) or "bfloat16" (halves slot
         memory traffic; accumulation stays float32, drift is bounded by
@@ -54,7 +54,6 @@ class AifRouter(Router):
     disc: spaces.DiscretizationConfig | None = None
     util_edges: tuple[float, ...] | None = None
     util_period: int = 10
-    fused: bool = False
     use_pallas: bool = False
     mega: bool = False
     mega_slot_dtype: str = "float32"
@@ -94,8 +93,13 @@ class AifRouter(Router):
             if self.cfg.novelty_weight != 0.0:
                 raise ValueError(
                     "mega=True does not implement the beyond-paper novelty "
-                    "bonus (novelty_weight != 0) — the fused kernels drop "
-                    "it; run the unfused per-tick path instead")
+                    "bonus (novelty_weight != 0) — the factored window "
+                    "drops it; run the per-tick path (mega=False) instead")
+        elif self.use_pallas:
+            raise ValueError(
+                "use_pallas=True selects the Pallas megakernel, which only "
+                "the mega engine runs; set mega=True as well (the per-tick "
+                "engine has no kernel to dispatch)")
         if self.mega_slot_dtype not in ("float32", "bfloat16"):
             raise ValueError(
                 f"mega_slot_dtype must be 'float32' or 'bfloat16', got "
@@ -187,7 +191,7 @@ class AifRouter(Router):
         obs_bins, util_bins, util_valid, raw_err = self._observe(obs)
         carry, info = fleet_mod.fleet_fast_step(
             carry, obs_bins, raw_err, keys, self.cfg, util_bins, util_valid,
-            obs_mask, fused=self.fused, use_pallas=self.use_pallas)
+            obs_mask)
         return carry, info.routing_weights, TickInfo(action=info.action,
                                                      unstable=info.unstable,
                                                      watchdog=wd)
@@ -199,7 +203,7 @@ class AifRouter(Router):
         obs_bins, util_bins, util_valid, raw_err = self._observe(obs)
         carry, info = fleet_mod.fleet_light_step(
             carry, obs_bins, raw_err, self.cfg, util_bins, util_valid,
-            obs_mask, fused=self.fused)
+            obs_mask)
         return carry, info.routing_weights, TickInfo(action=info.action,
                                                      unstable=info.unstable,
                                                      watchdog=wd)

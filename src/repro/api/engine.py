@@ -176,7 +176,8 @@ def _key_block(key: jax.Array, n: int, r: int, rows: tuple | None = None):
     chain into one block per slow period takes the key derivation off the
     tick's critical path; the split *tree* is unchanged, so the produced
     keys (and therefore the rollout) are bit-identical to the per-tick
-    chain (pinned by ``tests/test_mega.py::test_key_block_replays_chain``).
+    chain (pinned by
+    ``tests/test_mega_parity.py::test_key_block_replays_chain``).
 
     Returns (advanced chain key, (k_env (n,), k_fast (n, R), k_slow (n, R))).
     """

@@ -54,8 +54,7 @@ _EPS = 1e-9
 
 
 # ------------------------------------------------------------ router registry
-def _make_aif(topo: Topology, scfg: SimConfig, fused: bool,
-              use_pallas: bool, mega: bool,
+def _make_aif(topo: Topology, scfg: SimConfig, use_pallas: bool, mega: bool,
               mega_slot_dtype: str = "float32",
               graph: graph_mod.FleetGraph | None = None) -> AifRouter:
     disc = discretization_for(scfg)
@@ -66,8 +65,7 @@ def _make_aif(topo: Topology, scfg: SimConfig, fused: bool,
         disc = dataclasses.replace(
             disc, edges=disc.modality_edges() + (graph_mod.NEIGHBOR_EDGES,))
     return AifRouter(cfg=generative.AifConfig(topology=topo),
-                     disc=disc,
-                     fused=fused, use_pallas=use_pallas, mega=mega,
+                     disc=disc, use_pallas=use_pallas, mega=mega,
                      mega_slot_dtype=mega_slot_dtype)
 
 
@@ -81,8 +79,8 @@ def _capacity_weights(scfg: SimConfig) -> tuple[float, ...]:
     return tuple(w) + (round(1.0 - sum(w), 2),)
 
 
-#: Router registry: name -> (topology, sim config, fused, use_pallas, mega,
-#: ...) -> Router.  The baseline builders ignore the trailing AIF execution
+#: Router registry: name -> (topology, sim config, use_pallas, mega, ...)
+#: -> Router.  The baseline builders ignore the trailing AIF execution
 #: options (``*_``) so the registry call shape can grow without touching
 #: them.  ``capacity`` derives its weights from the sim config's tier CPU
 #: limits — the prior knowledge AIF learns online.
@@ -260,7 +258,9 @@ class Experiment:
       n_cells / n_windows: fleet size R and horizon T.
       seed: drives the scenario schedules and the rollout PRNG.
       window_s: control-window length in seconds.
-      fused / use_pallas: AIF execution path (ignored for baselines).
+      use_pallas: with ``mega``, run each window as the Pallas megakernel
+        instead of its XLA oracle (ignored for baselines; raises for AIF
+        without ``mega``).
       mega: run AIF on the whole-window megakernel engine path (the
         multi-period super-launch: one jit spans the run, factored
         transition cache, streaming slow boundaries — see
@@ -320,7 +320,6 @@ class Experiment:
     n_windows: int = 300
     seed: int = 0
     window_s: float = 1.0
-    fused: bool = False
     use_pallas: bool = False
     mega: bool = False
     mega_slot_dtype: str = "float32"
@@ -350,11 +349,11 @@ class Experiment:
                        graph: graph_mod.FleetGraph | None = None
                        ) -> router_mod.Router:
         if isinstance(self.router, router_mod.Router):
-            if self.fused or self.use_pallas or self.mega:
+            if self.use_pallas or self.mega:
                 raise ValueError(
-                    "fused/use_pallas/mega only apply to registry-built "
-                    "routers; set them on the Router instance itself (e.g. "
-                    "AifRouter(fused=True)) — silently ignoring them would "
+                    "use_pallas/mega only apply to registry-built routers; "
+                    "set them on the Router instance itself (e.g. "
+                    "AifRouter(mega=True)) — silently ignoring them would "
                     "misreport which execution path ran")
             return _graphify_router(self.router, graph)
         try:
@@ -363,12 +362,12 @@ class Experiment:
             raise KeyError(f"unknown router {self.router!r}; "
                            f"available: {sorted(ROUTERS)}") from None
         if self.router == "aif":
-            return _make_aif(self.resolve_topology(), scfg, self.fused,
+            return _make_aif(self.resolve_topology(), scfg,
                              self.use_pallas, self.mega,
                              self.mega_slot_dtype, graph=graph)
         return _graphify_router(
-            make(self.resolve_topology(), scfg, self.fused,
-                 self.use_pallas, self.mega), graph)
+            make(self.resolve_topology(), scfg, self.use_pallas,
+                 self.mega), graph)
 
     @property
     def name(self) -> str:
@@ -1046,7 +1045,7 @@ def table1_grid(routers: Sequence[str] = TABLE1_ROUTERS,
     """The paper's comparison grid: router zoo × clean + degraded telemetry.
 
     ``overrides`` forward to every :class:`Experiment` (n_cells, n_windows,
-    seed, topology, fused, ...).
+    seed, topology, mega, ...).
     """
     return [Experiment(router=r, scenario=s, **overrides)
             for s in scenario_names for r in routers]

@@ -113,9 +113,9 @@ def pre_action(state: AgentState,
     """Everything in a fast step *before* action selection.
 
     Adaptive preferences (paper §4.2) → Bayesian belief update (Eq. 2) →
-    replay-buffer push.  Split out so fleet mode can evaluate the EFE term
-    with the fused fleet kernel between this and :func:`apply_action` while
-    sharing one copy of the control-step logic.
+    replay-buffer push.  Split out so fleet mode's held ticks can skip the
+    EFE term between this and :func:`apply_action` while sharing one copy of
+    the control-step logic.
 
     ``obs_mask`` ((M,) float 0/1) flags which modalities delivered fresh
     telemetry this tick: masked modalities contribute zero evidence to the

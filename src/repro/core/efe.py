@@ -15,9 +15,11 @@ observation distribution per modality, and scores it:
   Cost(a)      = λ · (log K − H(w_a))              — regularizer against
                                                      extreme routing policies
 
-This module is the pure-jnp oracle; :mod:`repro.kernels.efe` provides the
-fused Pallas TPU kernel for fleet-scale batches of routers and
-``assert_allclose``-matches these functions for every topology.
+This module is the per-tick reference.  The whole-window engine
+(:mod:`repro.core.mega`, and its Pallas megakernel in
+:mod:`repro.kernels.efe.mega`) computes the same terms in factored form for
+fleet-scale batches of routers; the tests compare it against the per-tick
+engine built on these functions, for every topology.
 """
 from __future__ import annotations
 

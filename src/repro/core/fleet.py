@@ -3,21 +3,13 @@
 The paper runs one router at 1 Hz on a CPU.  At datacenter scale each *service
 cell* (model family × pod slice × region) gets its own router; all of them
 share the same control cadence.  Because the agent is purely functional we
-get the fleet for free with ``jax.vmap``, and the batched step is a dense
-(R, A, S, S) einsum workload that shards over a mesh axis with pjit and maps
-onto the MXU via the fused Pallas EFE kernel (:mod:`repro.kernels.efe`).
+get the fleet for free with ``jax.vmap``: one control tick here is the
+``jax.vmap`` of the single-agent :func:`repro.core.agent.fast_step`.  This
+per-tick engine is the reference semantics; the production path is the
+whole-window mega engine (:mod:`repro.core.mega`, with its Pallas megakernel
+in :mod:`repro.kernels.efe.mega`), which the tests compare against it.
 
-Two execution paths for one control tick:
-
-* ``fused=False`` — ``jax.vmap`` of the single-agent
-  :func:`repro.core.agent.fast_step` (reference semantics),
-* ``fused=True`` — the same math with the belief update *and* the EFE
-  evaluation fused into one (R, A, S, S) launch
-  (:func:`repro.kernels.efe.ops.fleet_belief_efe`) instead of R independent
-  einsums (``use_pallas=True`` selects the Pallas TPU kernel, else the XLA
-  oracle).
-
-Both paths read the quasi-static :class:`~repro.core.generative.ModelCache`
+The tick reads the quasi-static :class:`~repro.core.generative.ModelCache`
 (normalized A/B + per-state ambiguity) that
 :func:`repro.core.agent.slow_step` refreshes once per slow period — the
 paper's 1 s / 10 s timescale separation (§4.4) means nothing else about the
@@ -49,8 +41,7 @@ import jax.numpy as jnp
 from repro.core import agent as agent_mod
 from repro.core import belief as belief_mod
 from repro.core import efe as efe_mod
-from repro.core import generative, learning, policies, preferences, spaces
-from repro.kernels.efe import ops as efe_ops
+from repro.core import generative, policies, spaces
 
 
 def init_fleet_state(cfg: generative.AifConfig,
@@ -62,103 +53,6 @@ def init_fleet_state(cfg: generative.AifConfig,
 
 
 # ------------------------------------------------------------------ one tick
-def _fused_evidence(state: agent_mod.AgentState,
-                    obs_bins: jnp.ndarray,
-                    raw_error_rate: jnp.ndarray,
-                    cfg: generative.AifConfig,
-                    util_bins, util_valid,
-                    obs_mask: jnp.ndarray | None = None):
-    """Per-tick evidence shared by the fused selecting and held steps:
-    adaptive preferences (paper §4.2 — the only per-tick model change) and
-    the observation log-likelihood gathered from the cached normalized A.
-    ``obs_mask`` ((R, M)) zeroes the evidence of masked modalities before
-    the sum, so everything downstream (the fused kernel's VMEM-carried
-    posterior included) sees only valid telemetry.
-
-    Returns (model-with-updated-c_log, error_ema, unstable, loglik).
-    """
-    topo = cfg.topology
-    error_ema = agent_mod.masked_error_ema(state.error_ema, raw_error_rate,
-                                           cfg, obs_mask)
-    c_log, unstable = preferences.adapt_preferences(error_ema, cfg)
-    model = state.model._replace(c_log=c_log)
-
-    loglik = belief_mod.log_likelihood_from_normalized(state.cache.na,
-                                                       obs_bins, obs_mask)
-    if util_bins is not None:
-        util_ll = jax.vmap(
-            lambda u: belief_mod.util_log_likelihood(u, topo))(util_bins)
-        loglik = loglik + jnp.where(util_valid, util_ll, 0.0)
-    return model, error_ema, unstable, loglik
-
-
-def _effective_amb(cache: generative.ModelCache,
-                   obs_mask: jnp.ndarray | None) -> jnp.ndarray:
-    """Per-state ambiguity under the tick's mask (cached amb when unmasked)."""
-    if obs_mask is None:
-        return cache.amb
-    return generative.masked_ambiguity(cache.amb_m, obs_mask)
-
-
-def _fused_fast_step(state: agent_mod.AgentState,
-                     obs_bins: jnp.ndarray,
-                     raw_error_rate: jnp.ndarray,
-                     keys: jax.Array,
-                     cfg: generative.AifConfig,
-                     util_bins: jnp.ndarray | None,
-                     util_valid,
-                     obs_mask: jnp.ndarray | None,
-                     use_pallas: bool):
-    """:func:`repro.core.agent.fast_step` with belief update *and* EFE fused
-    into one fleet-kernel launch (:func:`repro.kernels.efe.ops.fleet_belief_efe`)
-    reading the quasi-static model cache.  The control-step logic is shared
-    with the single-agent path (``apply_action``); only the
-    inference/selection sandwich differs.  The returned ``StepInfo.efe``
-    carries the fused G and action probabilities; the risk/ambiguity
-    diagnostics are not split out by the fused kernel and read zero.
-    """
-    topo = cfg.topology
-    cache = state.cache
-    model, error_ema, unstable, loglik = _fused_evidence(
-        state, obs_bins, raw_error_rate, cfg, util_bins, util_valid, obs_mask)
-
-    # Fused Eq. 2 → Eq. 1: posterior + G in one launch, belief stays on-chip.
-    logc = generative.masked_log_c(model.c_log, topo)
-    g, q_next = efe_ops.fleet_belief_efe(
-        cache.nb, cache.na, logc, _effective_amb(cache, obs_mask),
-        state.belief, state.prev_action, loglik, cfg, obs_mask=obs_mask,
-        use_pallas=use_pallas)                             # (R, A), (R, S)
-
-    probs = jax.nn.softmax(-cfg.beta * g, axis=-1)
-    sampled = jax.vmap(
-        lambda k, p: jax.random.categorical(
-            k, jnp.log(jnp.maximum(p, 1e-30))))(keys, probs)
-
-    replay = jax.vmap(learning.push_transition)(
-        state.replay, state.belief, q_next, obs_bins, state.prev_action,
-        state.dt_since_change, obs_mask)
-
-    # apply_action is elementwise over the router axis — call it unbatched
-    new_state, action = agent_mod.apply_action(
-        state, model, q_next, replay, error_ema, unstable, sampled, cfg)
-
-    zeros = jnp.zeros_like(g)
-    cost = cfg.cost_weight * policies.policy_concentration_cost(topo)
-    info = agent_mod.StepInfo(
-        action=action,
-        routing_weights=policies.routing_weights(action, topo),
-        efe=efe_mod.EfeBreakdown(
-            g=g, risk=zeros, ambiguity=zeros,
-            cost=jnp.broadcast_to(cost, g.shape), action_probs=probs),
-        belief_entropy=jax.vmap(belief_mod.belief_entropy)(q_next),
-        unstable=unstable,
-        obs_bins=obs_bins,
-        obs_mask=(agent_mod.all_valid_mask(obs_bins)
-                  if obs_mask is None else obs_mask),
-    )
-    return new_state, info
-
-
 def fleet_fast_step(state: agent_mod.AgentState,
                     obs_bins: jnp.ndarray,
                     raw_error_rate: jnp.ndarray,
@@ -166,19 +60,13 @@ def fleet_fast_step(state: agent_mod.AgentState,
                     cfg: generative.AifConfig,
                     util_bins: jnp.ndarray | None = None,
                     util_valid=False,
-                    obs_mask: jnp.ndarray | None = None,
-                    *,
-                    fused: bool = False,
-                    use_pallas: bool = False):
+                    obs_mask: jnp.ndarray | None = None):
     """One fast step (belief → EFE → action) for the fleet; no slow learning.
 
     ``keys`` are the per-router *fast* keys (one categorical draw each);
     ``obs_mask`` is the (R, M) telemetry-validity mask for this tick (None =
     every modality fresh — the exact pre-mask program).
     """
-    if fused:
-        return _fused_fast_step(state, obs_bins, raw_error_rate, keys, cfg,
-                                util_bins, util_valid, obs_mask, use_pallas)
     # None arguments are empty pytrees — vmap maps only the array leaves.
     return jax.vmap(
         lambda s, o, e, k, u, m: agent_mod.fast_step(s, o, e, k, cfg, u,
@@ -210,38 +98,13 @@ def _light_step_single(state: agent_mod.AgentState,
     return new_state, (action, q_next, unstable)
 
 
-def _fused_light_step(state: agent_mod.AgentState,
-                      obs_bins: jnp.ndarray,
-                      raw_error_rate: jnp.ndarray,
-                      cfg: generative.AifConfig,
-                      util_bins, util_valid, obs_mask):
-    """Fleet-batched held tick for the fused path (no kernel launch): the
-    cached-model belief update alone, via the same posterior math as the
-    fused kernel's oracle twin
-    (:func:`repro.kernels.efe.ref.belief_posterior_ref`)."""
-    model, error_ema, unstable, loglik = _fused_evidence(
-        state, obs_bins, raw_error_rate, cfg, util_bins, util_valid, obs_mask)
-    q_next = efe_ops.fleet_belief_posterior(
-        state.cache.nb, state.belief, state.prev_action, loglik)
-
-    replay = jax.vmap(learning.push_transition)(
-        state.replay, state.belief, q_next, obs_bins, state.prev_action,
-        state.dt_since_change, obs_mask)
-    new_state, action = agent_mod.apply_action(
-        state, model, q_next, replay, error_ema, unstable,
-        state.prev_action, cfg)
-    return new_state, (action, q_next, unstable)
-
-
 def fleet_light_step(state: agent_mod.AgentState,
                      obs_bins: jnp.ndarray,
                      raw_error_rate: jnp.ndarray,
                      cfg: generative.AifConfig,
                      util_bins: jnp.ndarray | None = None,
                      util_valid=False,
-                     obs_mask: jnp.ndarray | None = None,
-                     *,
-                     fused: bool = False):
+                     obs_mask: jnp.ndarray | None = None):
     """Fleet fast step for a tick whose clock is off the action-dwell cadence
     (``t % dwell != 0`` for every router): the sampled action would be
     discarded, so the EFE evaluation — the dominant per-tick cost, streaming
@@ -250,15 +113,10 @@ def fleet_light_step(state: agent_mod.AgentState,
     ``StepInfo.efe`` diagnostics read zero (the closed-loop rollout does not
     trace them).
     """
-    if fused:
-        new_state, (action, q_next, unstable) = _fused_light_step(
-            state, obs_bins, raw_error_rate, cfg, util_bins, util_valid,
-            obs_mask)
-    else:
-        new_state, (action, q_next, unstable) = jax.vmap(
-            lambda s, o, e, u, m: _light_step_single(s, o, e, cfg, u,
-                                                     util_valid, m)
-        )(state, obs_bins, raw_error_rate, util_bins, obs_mask)
+    new_state, (action, q_next, unstable) = jax.vmap(
+        lambda s, o, e, u, m: _light_step_single(s, o, e, cfg, u,
+                                                 util_valid, m)
+    )(state, obs_bins, raw_error_rate, util_bins, obs_mask)
     info = agent_mod.StepInfo(
         action=action,
         routing_weights=policies.routing_weights(action, cfg.topology),
@@ -308,8 +166,7 @@ def fleet_slow_step(state: agent_mod.AgentState, keys: jax.Array,
     return state._replace(model=new_model, cache=new_cache)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("cfg", "fused", "use_pallas"),
+@functools.partial(jax.jit, static_argnames=("cfg",),
                    donate_argnames=("state",))
 def fleet_tick(state: agent_mod.AgentState,
                obs_bins: jnp.ndarray,
@@ -318,10 +175,7 @@ def fleet_tick(state: agent_mod.AgentState,
                cfg: generative.AifConfig,
                util_bins: jnp.ndarray | None = None,
                util_valid=False,
-               obs_mask: jnp.ndarray | None = None,
-               *,
-               fused: bool = False,
-               use_pallas: bool = False):
+               obs_mask: jnp.ndarray | None = None):
     """One control tick for the whole fleet (fast step + gated slow step).
 
     ``state`` is donated: the caller's buffers are consumed and must not be
@@ -341,21 +195,7 @@ def fleet_tick(state: agent_mod.AgentState,
       util_valid: scalar gate for util_bins (True on scrape ticks; traced ok).
       obs_mask: optional (R, M) float 0/1 telemetry-validity mask for this
         tick's observations (None = all modalities fresh).
-      fused: route belief update + EFE through the fused fleet kernel
-        (:func:`repro.kernels.efe.ops.fleet_belief_efe`) instead of vmapping
-        the per-router einsums.
-      use_pallas: with ``fused=True``, dispatch the Pallas TPU kernel rather
-        than the XLA oracle.
     """
-    if fused:
-        ks = jax.vmap(jax.random.split)(keys)              # (R, 2) keys
-        k_fast, k_slow = ks[:, 0], ks[:, 1]
-        state, info = fleet_fast_step(state, obs_bins, raw_error_rate,
-                                      k_fast, cfg, util_bins, util_valid,
-                                      obs_mask,
-                                      fused=True, use_pallas=use_pallas)
-        return fleet_slow_step(state, k_slow, cfg), info
-
     return jax.vmap(
         lambda s, o, e, k, u, m: agent_mod.tick(s, o, e, k, cfg, u,
                                                 util_valid, m)
@@ -379,7 +219,7 @@ def fleet_watchdog_bad(state: agent_mod.AgentState) -> jnp.ndarray:
     when the error EMA driving the adaptive-preference switch is
     non-finite.  Deliberately cheap — O(R·M·bins·S) reads, no (R, A, S, S)
     traffic — so the check can run on *every* tick's incoming carry without
-    denting clean-path throughput (pinned by the perf-regression gate).
+    denting clean-path throughput.
     """
     r = state.belief.shape[0]
 
@@ -465,20 +305,18 @@ def fleet_rollout(agent_state: agent_mod.AgentState,
                   util_edges: tuple[float, ...] | None = None,
                   util_period: int = 10,
                   *,
-                  fused: bool = False,
-                  use_pallas: bool = False,
                   obs_masked: bool | None = None,
                   t0: int | None = None):
     """Deprecated AIF-only entry point — use :mod:`repro.api` instead.
 
     The closed-loop engine now lives in :func:`repro.api.engine.rollout`
     behind the Router protocol; this shim keeps the old hand-assembled
-    cfg/disc/util_edges/fused/use_pallas signature working by packing it
+    cfg/disc/util_edges signature working by packing it
     into a :class:`repro.api.aif.AifRouter` spec and delegating (same
     program bit-for-bit — the golden rollout test pins it).  Prefer::
 
         from repro import api
-        router = api.AifRouter(cfg=cfg, disc=disc, fused=fused)
+        router = api.AifRouter(cfg=cfg, disc=disc)
         api.rollout(router, agent_state, env_state, env_step, n_steps, key)
 
     or the declarative :func:`repro.api.run` / :class:`repro.api.Experiment`
@@ -495,8 +333,7 @@ def fleet_rollout(agent_state: agent_mod.AgentState,
     router = AifRouter(cfg=cfg, disc=disc,
                        util_edges=(None if util_edges is None
                                    else tuple(util_edges)),
-                       util_period=util_period,
-                       fused=fused, use_pallas=use_pallas)
+                       util_period=util_period)
     return rollout(router, agent_state, env_state, env_step, n_steps, key,
                    obs_masked=obs_masked, t0=t0)
 
@@ -520,17 +357,13 @@ class FleetGroup(NamedTuple):
     agent_state: agent_mod.AgentState    # batched, leading dim R_g
     env_state: Any
     env_step: Callable
-    # Per-shard EFE execution path (a 5-tier shard can run the fused kernel
-    # while a 3-tier shard stays on the vmapped reference).
-    fused: bool = False
-    use_pallas: bool = False
     # Per-shard observation discretization (None = paper defaults); shards
     # serving different offered loads need different bin edges.
     disc: spaces.DiscretizationConfig | None = None
 
 
 #: Engine options hetero_fleet_rollout forwards to every group's rollout
-#: (per-group options — disc, fused, use_pallas — live on the FleetGroup).
+#: (the per-group option, disc, lives on the FleetGroup).
 _HETERO_ROLLOUT_KWARGS = frozenset(
     {"util_edges", "util_period", "obs_masked", "t0"})
 
@@ -541,7 +374,7 @@ def hetero_fleet_rollout(groups, n_steps: int, key: jax.Array,
 
     Args:
       groups: sequence of :class:`FleetGroup` (cells pre-grouped by
-        topology; each carries its own EFE execution path).  Each group's
+        topology).  Each group's
         ``agent_state`` / ``env_state`` are donated to its rollout.
       n_steps: shared number of control windows.
       key: PRNG key; folded per group so groups stay independent.
@@ -560,7 +393,7 @@ def hetero_fleet_rollout(groups, n_steps: int, key: jax.Array,
             f"hetero_fleet_rollout got unknown engine option(s) "
             f"{sorted(unknown)}; shared options are "
             f"{sorted(_HETERO_ROLLOUT_KWARGS)} and per-group options "
-            f"(disc, fused, use_pallas) belong on the FleetGroup")
+            f"(disc) belongs on the FleetGroup")
     names = [g.name for g in groups]
     if len(set(names)) != len(names):
         raise ValueError(f"duplicate FleetGroup names: {names}")
@@ -574,8 +407,7 @@ def hetero_fleet_rollout(groups, n_steps: int, key: jax.Array,
             cfg=g.cfg, disc=g.disc,
             util_edges=(tuple(kwargs["util_edges"])
                         if kwargs.get("util_edges") is not None else None),
-            util_period=kwargs.get("util_period", 10),
-            fused=g.fused, use_pallas=g.use_pallas)
+            util_period=kwargs.get("util_period", 10))
         out[g.name] = rollout(
             router, g.agent_state, g.env_state, g.env_step, n_steps,
             jax.random.fold_in(key, i), **rollout_kwargs)

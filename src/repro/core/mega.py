@@ -41,9 +41,9 @@ statistics for it, and the incremental and full forms are mathematically
 identical (the cache is linear in the hit counts), differing only in
 floating-point association.
 
-Semantics match the legacy fused path term-for-term (same guard constants,
-same op order); only floating-point reassociation differs, pinned by the
-rollout-parity tests at 1e-4 (actions bit-equal).
+Semantics match the per-tick engine (:mod:`repro.core.fleet`) term for
+term (same guard constants); only floating-point reassociation differs,
+pinned by the rollout-parity tests at 1e-4 (actions bit-equal).
 """
 from __future__ import annotations
 

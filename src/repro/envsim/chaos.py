@@ -245,6 +245,6 @@ CHAOS_INFO: dict[str, ChaosInfo] = {
     "long-outage": ChaosInfo(base="paper-burst", fault_frac=(0.3, 0.7)),
 }
 
-# register the presets alongside the base scenarios (idempotent) so CLI
-# surfaces (fleet_bench --scenario, Experiment(scenario=...)) see them
+# register the presets alongside the base scenarios (idempotent) so the
+# declarative surface (Experiment(scenario=...)) sees them
 scenarios.SCENARIOS.update(CHAOS_PRESETS)

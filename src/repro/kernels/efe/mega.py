@@ -11,8 +11,10 @@ is one read of the quasi-static operands and one write of the window's
 posteriors and trace per window instead of per tick.
 
 The XLA oracle twin is :func:`repro.core.mega.mega_window` (same op order,
-same guard constants); rollout-level parity is pinned at 1e-4 by
-``tests/test_mega.py``.  Known intentional deviations, both inside that
+same guard constants); parity is pinned at 1e-4 window by window by
+``tests/test_mega_window_k{2,3,5}.py`` and rollout by rollout by
+``tests/test_mega_kernel.py``, ``test_mega_tape.py`` and
+``test_mega_launch.py``.  Known intentional deviations, both inside that
 tolerance:
 
 * the env's completion-weighted P95 replaces the oracle's
@@ -71,6 +73,7 @@ it for a described chip at the paper's widths) and runs there compiled
 from __future__ import annotations
 
 import functools
+import math
 from typing import NamedTuple
 
 import jax
@@ -82,11 +85,16 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core import policies, preferences, spaces
 from repro.core import mega as mega_core
 from repro.envsim import batched
-from repro.kernels.efe.efe import (SUBLANES, VMEM_CAPACITY, block_vmem_bytes,
-                                   pad_cells, pad_rows)
 
 _EPS = 1e-9             # envsim.batched._EPS (restated: kernels stay leaf)
 _HI = jax.lax.Precision.HIGHEST
+
+_LANES = 128         # TPU lane width (VMEM tiles pad the minor dim to it)
+SUBLANES = 8         # TPU sublane count: router blocks are multiples of this
+
+#: Per-core VMEM of a TPU v5e (and v6e) TensorCore; the scoped limit a
+#: kernel may request is capped below it.
+VMEM_CAPACITY = 128 * 1024 * 1024
 
 # Scoped-VMEM ceiling requested from the compiler (headroom below the
 # 128 MiB TensorCore VMEM for Mosaic's own internal scratch).
@@ -100,6 +108,36 @@ TAPE = ("qp", "qn", "qnproj", "coefact")
 # The tape operands each fold reads: the belief prior's and the EFE's.
 _PRIOR_TAPE = ("coefact", "qp", "qn")
 _EFE_TAPE = ("qp", "coefact", "qnproj")
+
+
+def pad_rows(r: int, block_r: int = SUBLANES) -> int:
+    """R rounded up to a whole number of router blocks."""
+    return -(-r // block_r) * block_r
+
+
+def pad_cells(x: jnp.ndarray, r_pad: int, axis: int = 0) -> jnp.ndarray:
+    """Pad the cell axis to ``r_pad`` rows by repeating the last router.
+
+    Padded rows are finite copies of a real router (every op in the kernel
+    is row-local), and the wrapper crops them from the results.
+    """
+    extra = r_pad - x.shape[axis]
+    if extra == 0:
+        return x
+    widths = [(0, 0)] * x.ndim
+    widths[axis] = (0, extra)
+    return jnp.pad(x, widths, mode="edge")
+
+
+def block_vmem_bytes(shape, dtype) -> int:
+    """VMEM footprint of one block: the two minor dims padded to the
+    (sublane, lane) tile of ``dtype``."""
+    itemsize = jnp.dtype(dtype).itemsize
+    sub = SUBLANES * 4 // itemsize
+    shape = (1,) * max(0, 2 - len(shape)) + tuple(shape)
+    *lead, s2, s1 = shape
+    return (math.prod(lead) * (-(-s2 // sub) * sub)
+            * (-(-s1 // _LANES) * _LANES) * itemsize)
 
 
 class TapePlan(NamedTuple):
@@ -262,8 +300,8 @@ def mega_window_pallas(state, est, obs_carry, params,
 
     ``t0`` must sit on a dwell boundary (the engine only launches windows
     there) so the selecting/held tick structure is compiled statically.
-    ``interpret`` is deliberately required, as for the per-tick kernels —
-    only the :mod:`..ops` wrapper auto-detects the backend.
+    ``interpret`` is deliberately required — only the :mod:`..ops` wrapper
+    auto-detects the backend.
     ``slot_chunk`` and ``vmem_limit`` set the fold's chunk and the VMEM
     ceiling :func:`tape_plan` prices the launch against.
     """

@@ -1,97 +1,12 @@
-"""Pure-jnp oracle for the fleet EFE kernel.
-
-Inputs are *normalized* distributions (the kernel fuses the inference-time
-hot path, not the pseudo-count normalization, which runs on the slow loop):
-
-  b_norm: (R, A, S, S) — p(s'|s,a) per router, column-stochastic over s'.
-  q:      (R, S)       — current beliefs.
-  a_norm: (R, M, NB, S) — p(o_m=b | s) per router (padded bins are zero).
-  logc:   (R, M, NB)   — log σ(C) preference distributions (padded ~-inf).
-  amb:    (R, S)       — Σ_m H[A_m(·|s)] per state (precomputed on the slow
-                          loop; changes only when A changes).
-  cost:   (A,)         — policy concentration regularizer.
-
-Output: G (R, A) — expected free energy per router × action:
-  ŝ_a = B_a q;  ô = A ŝ_a;  risk = Σ ô·(log ô − logC);  G = risk + ŝ_a·amb + cost.
-
-Partial observability: every oracle takes an optional ``obs_mask`` ((R, M)
-float 0/1) matching the mask-aware Pallas kernels — masked modalities drop
-out of the risk reduction (the ``amb`` operand is then expected to be the
-mask-effective ambiguity and the fused ``loglik`` to be mask-zeroed, both
-prepared by :mod:`repro.kernels.efe.ops`).  ``obs_mask=None`` is the exact
-unmasked program.
-"""
+"""Oracle of the whole-window megakernel (:mod:`repro.kernels.efe.mega`)."""
 from __future__ import annotations
-
-import jax.numpy as jnp
-
-
-def efe_fleet_ref(b_norm: jnp.ndarray, q: jnp.ndarray, a_norm: jnp.ndarray,
-                  logc: jnp.ndarray, amb: jnp.ndarray,
-                  cost: jnp.ndarray,
-                  obs_mask: jnp.ndarray | None = None) -> jnp.ndarray:
-    s_pred = jnp.einsum("rats,rs->rat", b_norm, q)
-    s_pred = s_pred / jnp.maximum(jnp.sum(s_pred, -1, keepdims=True), 1e-30)
-    o_pred = jnp.einsum("rmbs,ras->ramb", a_norm, s_pred)
-    terms = jnp.where(o_pred > 1e-20,
-                      o_pred * (jnp.log(jnp.maximum(o_pred, 1e-30))
-                                - logc[:, None]), 0.0)
-    if obs_mask is not None:
-        terms = terms * obs_mask[:, None, :, None]
-    risk = jnp.sum(terms, axis=(2, 3))
-    ambiguity = jnp.einsum("ras,rs->ra", s_pred, amb)
-    return risk + ambiguity + cost[None, :]
-
-
-def belief_posterior_ref(b_prev: jnp.ndarray, q_prev: jnp.ndarray,
-                         loglik: jnp.ndarray) -> jnp.ndarray:
-    """Batched Bayesian belief update (paper Eq. 2), the belief half of the
-    fused tick.  The single source of the posterior math off-TPU: both the
-    fused selecting tick (via :func:`belief_efe_fleet_ref`) and the held-tick
-    fast path (:func:`repro.core.fleet.fleet_light_step`) call this, so the
-    rollout's dwell-blocking bit-identity invariant cannot drift.
-
-      b_prev: (R, S, S) — p(s'|s, a_prev) per router (the previously applied
-              action's transition row, pre-gathered from the cached B).
-      q_prev: (R, S)    — belief *before* the tick.
-      loglik: (R, S)    — log p(o_t|s) summed over modalities (+ any gated
-              utilization-scrape evidence), computed from the cached
-              normalized A outside the kernel (cheap gathers).
-    """
-    prior = jnp.einsum("rts,rs->rt", b_prev, q_prev)
-    prior = prior / jnp.maximum(jnp.sum(prior, -1, keepdims=True), 1e-30)
-    logp = loglik + jnp.log(jnp.maximum(prior, 1e-30))
-    logp = logp - jnp.max(logp, axis=-1, keepdims=True)
-    q = jnp.exp(logp)
-    return q / jnp.maximum(jnp.sum(q, -1, keepdims=True), 1e-30)
-
-
-def belief_efe_fleet_ref(b_prev: jnp.ndarray, q_prev: jnp.ndarray,
-                         loglik: jnp.ndarray, b_norm: jnp.ndarray,
-                         a_norm: jnp.ndarray, logc: jnp.ndarray,
-                         amb: jnp.ndarray, cost: jnp.ndarray,
-                         obs_mask: jnp.ndarray | None = None
-                         ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Fused belief update → EFE, one tick (paper Eq. 2 then Eq. 1).
-
-    See :func:`belief_posterior_ref` for the belief-half input semantics;
-    under partial observability ``loglik`` arrives with masked modalities
-    already zeroed (uniform evidence) and ``obs_mask`` additionally drops
-    them from the risk term — the oracle twin of the masked Pallas kernel.
-
-    Returns (G (R, A), q (R, S)) — the posterior never round-trips through a
-    separate belief pass; on TPU the Pallas twin keeps it in VMEM.
-    """
-    q = belief_posterior_ref(b_prev, q_prev, loglik)
-    return efe_fleet_ref(b_norm, q, a_norm, logc, amb, cost, obs_mask), q
 
 
 def mega_window_ref(*args, **kwargs):
     """XLA oracle twin of the whole-window megakernel.
 
     Thin alias of :func:`repro.core.mega.mega_window` so the kernel package
-    exposes the oracle next to the Pallas entry point, mirroring the
-    ``efe_fleet_pallas`` / ``efe_fleet_ref`` pairing.  Imported lazily to
+    exposes the oracle next to the Pallas entry point.  Imported lazily to
     keep this module free of core-package imports at import time.
     """
     from repro.core import mega as mega_core

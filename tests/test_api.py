@@ -242,8 +242,8 @@ def test_hetero_fleet_rollout_rejects_unknown_kwargs():
     inside the per-group loop."""
     with pytest.raises(TypeError, match="use_palas"):
         fleet.hetero_fleet_rollout([], 5, jax.random.key(0), use_palas=True)
-    with pytest.raises(TypeError, match="fused"):
-        fleet.hetero_fleet_rollout([], 5, jax.random.key(0), fused=True)
+    with pytest.raises(TypeError, match="disc"):
+        fleet.hetero_fleet_rollout([], 5, jax.random.key(0), disc=None)
 
 
 def test_fleet_rollout_shim_warns_and_points_at_api():

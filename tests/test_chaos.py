@@ -237,7 +237,7 @@ def test_resume_bit_identical_per_tick():
 
 def test_resume_bit_identical_mega():
     params, env_step = _world("zone-outage")
-    router = api.AifRouter(cfg=generative.AifConfig(), fused=True, mega=True)
+    router = api.AifRouter(cfg=generative.AifConfig(), mega=True)
     key = jax.random.key(42)
 
     c_u, e_u, _ = engine.rollout(

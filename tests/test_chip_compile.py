@@ -18,12 +18,10 @@ from jax.sharding import SingleDeviceSharding
 from repro.api import engine
 from repro.api import experiment as experiment_mod
 from repro.core import mega as mega_core
-from repro.core import policies
-from repro.core.topology import default_topology
+from repro.core.topology import get_topology
 from repro.envsim import batched
 from repro.kernels.efe import mega as mega_kernel
 from repro.kernels.efe import ops as efe_ops
-from repro.kernels.efe.efe import belief_efe_fleet_pallas, efe_fleet_pallas
 
 HBM_BYTES = 16e9          # one TPU v5e chip
 
@@ -76,62 +74,33 @@ def _assert_fits_one_chip(compiled):
     assert used < HBM_BYTES, f"{used / 1e9:.2f} GB does not fit one chip"
 
 
-def _fleet_shapes(r, masked):
-    topo_ = default_topology()
-    s, a = topo_.n_states, policies.n_actions(topo_)
-    m, nb = topo_.n_modalities, topo_.max_bins
-    f32 = jnp.float32
-    assert (s, a) == (243, 20)
-    shapes = dict(b_norm=((r, a, s, s), f32), q=((r, s), f32),
-                  a_norm=((r, m, nb, s), f32), logc=((r, m, nb), f32),
-                  amb=((r, s), f32), cost=((a,), f32),
-                  obs_mask=((r, m), f32) if masked else None)
-    return s, shapes
-
-
-@pytest.mark.parametrize("masked", [False, True], ids=["clean", "masked"])
-@pytest.mark.parametrize("r", [64, 2048])
-def test_efe_fleet_kernel_compiles(one_chip, r, masked):
-    _, sh = _fleet_shapes(r, masked)
-    args = {k: None if v is None else _sds(*v, one_chip)
-            for k, v in sh.items()}
-    compiled = efe_fleet_pallas.lower(
-        args["b_norm"], args["q"], args["a_norm"], args["logc"],
-        args["amb"], args["cost"], args["obs_mask"],
-        interpret=False).compile()
-    _assert_fits_one_chip(compiled)
-
-
-@pytest.mark.parametrize("masked", [False, True], ids=["clean", "masked"])
-@pytest.mark.parametrize("r", [64, 2048])
-def test_belief_efe_kernel_compiles(one_chip, r, masked):
-    s, sh = _fleet_shapes(r, masked)
-    args = {k: None if v is None else _sds(*v, one_chip)
-            for k, v in sh.items()}
-    b_prev = _sds((r, s, s), jnp.float32, one_chip)
-    compiled = belief_efe_fleet_pallas.lower(
-        b_prev, args["q"], args["q"], args["b_norm"], args["a_norm"],
-        args["logc"], args["amb"], args["cost"], args["obs_mask"],
-        interpret=False).compile()
-    _assert_fits_one_chip(compiled)
-
-
-def _mega_world(r, t, scenario):
-    topo_ = default_topology()
+def _mega_world(r, t, scenario, topology="paper-3tier"):
+    topo_ = get_topology(topology)
     scfg, params, env_step = experiment_mod._build_world(
         topo_, scenario, r, t, 1.0, 0)
-    router = experiment_mod._make_aif(topo_, scfg, False, True, True)
+    router = experiment_mod._make_aif(topo_, scfg, True, True)
     return router, params, env_step
 
 
-@pytest.mark.parametrize("r,t,scenario", [
-    (64, 120, "paper-burst"), (64, 120, "flaky-telemetry"),
-    (2048, 600, "paper-burst"), (8, 3600, "paper-burst"),
-    (256, 3600, "paper-burst")])
-def test_mega_window_compiles(one_chip, r, t, scenario):
+_WINDOWS = [
+    (64, 120, "paper-burst", "paper-3tier"),
+    (64, 120, "flaky-telemetry", "paper-3tier"),
+    (2048, 600, "paper-burst", "paper-3tier"),
+    (2048, 600, "flaky-telemetry", "paper-3tier"),
+    (8, 3600, "paper-burst", "paper-3tier"),
+    (256, 3600, "paper-burst", "paper-3tier"),
+    (64, 120, "paper-burst", "continuum-5tier")]
+
+
+@pytest.mark.parametrize(
+    "r,t,scenario,topology", _WINDOWS,
+    ids=[f"{r}-{t}-{s}" + ("" if k == "paper-3tier" else f"-{k}")
+         for r, t, s, k in _WINDOWS])
+def test_mega_window_compiles(one_chip, r, t, scenario, topology):
     """One whole-window megakernel launch at horizon T (a T-slot tape; at
-    T=3600 past what VMEM holds, so a resident prefix and a streamed rest)."""
-    router, params, env_step = _mega_world(r, t, scenario)
+    T=3600 past what VMEM holds, so a resident prefix and a streamed rest),
+    with clean and masked telemetry, at K=3 and at the K=5 continuum."""
+    router, params, env_step = _mega_world(r, t, scenario, topology)
     cfg, fl = router.cfg, env_step.fluid
     w, m, k = router.period, router.n_modalities, router.n_tiers
     f32 = jnp.float32

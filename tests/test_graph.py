@@ -223,7 +223,7 @@ def test_sharded_single_device_graph_bit_identity():
 def test_mega_engine_matches_per_tick_with_graph():
     """The mega path (XLA-oracle fallback under a graph) reproduces the
     per-tick engine's actions and final accounting on a graphed world."""
-    base = dict(router="aif", fused=True, scenario="ring-spillover",
+    base = dict(router="aif", scenario="ring-spillover",
                 n_cells=6, n_windows=25)
     r1 = api.run(api.Experiment(**base))
     r2 = api.run(api.Experiment(**base, mega=True))

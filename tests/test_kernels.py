@@ -5,45 +5,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import efe as core_efe
-from repro.core import generative, policies, spaces
 from repro.kernels.attention.flash import flash_decode, flash_prefill
 from repro.kernels.attention.ref import decode_ref, mha_ref
-from repro.kernels.efe.ops import fleet_efe
 from repro.kernels.ssd.ref import ssd_ref
 from repro.kernels.ssd.ssd import ssd_pallas
 
 KEY = jax.random.key(0)
-
-
-# ----------------------------------------------------------------- EFE
-@pytest.mark.parametrize("r", [4, 16])
-def test_efe_kernel_matches_ref_and_core(r):
-    cfg = generative.AifConfig()
-    topo = cfg.topology
-    ks = jax.random.split(KEY, 3)
-    S, A = topo.n_states, policies.n_actions(topo)
-    M, NB = topo.n_modalities, topo.max_bins
-    a_counts = (jax.random.uniform(ks[0], (r, M, NB, S), minval=0.1,
-                                   maxval=2.0)
-                * spaces.bins_mask(topo)[None, :, :, None])
-    b_counts = jax.random.uniform(ks[1], (r, A, S, S), minval=0.01,
-                                  maxval=1.0)
-    c_log = jnp.tile(generative.nominal_c_log(cfg)[None], (r, 1, 1))
-    q = jax.random.dirichlet(ks[2], jnp.ones(S), (r,))
-
-    g_pal = fleet_efe(a_counts, b_counts, c_log, q, cfg, use_pallas=True,
-                      interpret=True)
-    g_ref = fleet_efe(a_counts, b_counts, c_log, q, cfg, use_pallas=False)
-    np.testing.assert_allclose(np.asarray(g_pal), np.asarray(g_ref),
-                               atol=1e-4)
-    model = generative.GenerativeModel(a_counts=a_counts[0],
-                                       b_counts=b_counts[0],
-                                       c_log=c_log[0],
-                                       d_prior=jnp.ones(S) / S)
-    bd = core_efe.expected_free_energy(model, q[0], cfg)
-    np.testing.assert_allclose(np.asarray(g_ref[0]), np.asarray(bd.g),
-                               atol=1e-4)
 
 
 # --------------------------------------------------------- flash attention

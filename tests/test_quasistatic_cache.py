@@ -1,18 +1,17 @@
-"""Quasi-static model cache + fused belief→EFE tick coverage.
+"""Quasi-static model cache coverage.
 
-Pins the PR's performance-architecture invariants:
+Pins the performance-architecture invariants:
 
 * the normalized-model cache is exactly what :func:`derive_cache` yields
   from the pseudo-counts at every point in a rollout (slow-tick refresh),
 * ``predict_prior`` slices the action row before normalizing (bit-identical
   to normalizing the full (A, S, S) stack),
-* the fused belief→EFE Pallas kernel matches its XLA oracle twin for every
-  topology, including odd fleet sizes,
-* full-rollout trace parity between the fused+cached path and the vmapped
+* full-rollout trace parity between the Pallas megakernel and the per-tick
   reference on ``paper-3tier`` and ``continuum-5tier`` (slow-boundary and
   remainder ticks included, odd R),
-* the slow learning step executes exactly once per slow period inside
-  ``fleet_rollout`` (runtime call-count trace, not a trace-time proxy),
+* the slow learning step executes exactly once per slow period, on the
+  per-tick and the mega engine (runtime call-count trace, not a trace-time
+  proxy),
 * held (non-dwell) ticks evolve state identically with and without the EFE
   evaluation (the invariant behind the rollout's dwell blocking),
 * state buffers are donated through ``fleet_rollout``.
@@ -22,13 +21,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro import core
+from repro import api, core
 from repro.core import belief as belief_mod
 from repro.core import fleet, generative, policies, spaces
+from repro.core import mega as mega_core
 from repro.core.topology import default_topology, five_tier_topology
 from repro.envsim import (SimConfig, batched, discretization_for, scenarios,
                           sim_config_for)
-from repro.kernels.efe import ops as efe_ops
 
 
 def _fleet_world(topo, r, t, seed=0):
@@ -86,119 +85,75 @@ def test_predict_prior_slices_before_normalizing():
         np.testing.assert_array_equal(np.asarray(got), np.asarray(full))
 
 
-# ------------------------------------------------- fused belief→EFE kernel
-@pytest.mark.parametrize("topo", [default_topology(), five_tier_topology()],
-                         ids=["k3", "k5"])
-@pytest.mark.parametrize("r", [3, 4])   # odd fleet size on purpose
-def test_belief_efe_kernel_matches_oracle_twin(topo, r):
-    """Pallas(interpret) fused belief update + EFE vs the XLA oracle, and the
-    oracle posterior vs the cached single-agent update_belief."""
-    cfg = generative.AifConfig(topology=topo)
-    s = topo.n_states
-    m, nbins = topo.n_modalities, topo.max_bins
-    ks = jax.random.split(jax.random.key(r), 5)
-    a_counts = (jax.random.uniform(ks[0], (r, m, nbins, s), minval=0.1,
-                                   maxval=2.0)
-                * spaces.bins_mask(topo)[None, :, :, None])
-    b_counts = jax.random.uniform(ks[1], (r, policies.n_actions(topo), s, s),
-                                  minval=0.01, maxval=1.0)
-    q = jax.random.dirichlet(ks[2], jnp.ones(s), (r,))
-    obs = jax.random.randint(ks[3], (r, m), 0, 2)
-    prev = jax.random.randint(ks[4], (r,), 0, policies.n_actions(topo))
-
-    model = generative.GenerativeModel(
-        a_counts=a_counts[0], b_counts=b_counts[0],
-        c_log=generative.nominal_c_log(cfg), d_prior=jnp.ones(s) / s)
-    caches = [generative.derive_cache(
-        generative.GenerativeModel(a_counts=a_counts[i], b_counts=b_counts[i],
-                                   c_log=model.c_log, d_prior=model.d_prior),
-        topo) for i in range(r)]
-    nb = jnp.stack([c.nb for c in caches])
-    na = jnp.stack([c.na for c in caches])
-    amb = jnp.stack([c.amb for c in caches])
-    logc = jnp.tile(generative.masked_log_c(model.c_log, topo)[None],
-                    (r, 1, 1))
-    loglik = belief_mod.log_likelihood_from_normalized(na, obs)
-
-    g_ref, q_ref = efe_ops.fleet_belief_efe(nb, na, logc, amb, q, prev,
-                                            loglik, cfg, use_pallas=False)
-    g_pal, q_pal = efe_ops.fleet_belief_efe(nb, na, logc, amb, q, prev,
-                                            loglik, cfg, use_pallas=True,
-                                            interpret=True)
-    np.testing.assert_allclose(np.asarray(g_pal), np.asarray(g_ref),
-                               atol=1e-4)
-    np.testing.assert_allclose(np.asarray(q_pal), np.asarray(q_ref),
-                               atol=1e-5)
-    # oracle posterior == the cached single-agent belief update
-    for i in range(r):
-        q_single = belief_mod.update_belief(model, q[i], prev[i], obs[i],
-                                            topo, cache=caches[i])
-        np.testing.assert_allclose(np.asarray(q_ref[i]),
-                                   np.asarray(q_single), atol=1e-6)
-
-
 # ------------------------------------------------------- rollout trace parity
 @pytest.mark.parametrize("topo", [default_topology(), five_tier_topology()],
                          ids=["paper-3tier", "continuum-5tier"])
-def test_fused_rollout_trace_parity(topo):
-    """Fused+cached vs vmapped-reference full-rollout parity: identical
-    action/weight traces, beliefs within 1e-5.  T=23 crosses the slow
-    boundaries at t=10, 20 and leaves a 3-tick remainder (one dwell block +
-    held ticks); R=3 exercises the odd-fleet kernel fallback."""
+def test_megakernel_rollout_trace_parity(topo):
+    """Pallas megakernel (interpret mode) vs per-tick reference full-rollout
+    parity: identical action/weight traces, beliefs within 1e-5.  T=23
+    crosses the slow boundaries at t=10, 20 and leaves a 3-tick remainder
+    (one dwell block + held ticks); R=3 pads the kernel's 8-router block."""
     r, t = 3, 23
     cfg, params, env_step, disc = _fleet_world(topo, r, t)
-    out = {}
-    for name, kw in (("vmap", {}), ("fused", dict(fused=True))):
-        ast, est, trace = _rollout(cfg, params, env_step, disc, r, t, **kw)
-        out[name] = (ast, est, trace)
-    tr_v, tr_f = out["vmap"][2], out["fused"][2]
+    ref = _rollout(cfg, params, env_step, disc, r, t)
+    st, est, tr_k = api.rollout(
+        api.AifRouter(cfg=cfg, disc=disc, mega=True, use_pallas=True), None,
+        batched.init_fluid_state(params), env_step, t, jax.random.key(11))
+    dense = mega_core.to_agent_state(st, cfg)
+    tr_v = ref[2]
     np.testing.assert_array_equal(np.asarray(tr_v.actions),
-                                  np.asarray(tr_f.actions))
+                                  np.asarray(tr_k.actions))
     np.testing.assert_array_equal(np.asarray(tr_v.routing_weights),
-                                  np.asarray(tr_f.routing_weights))
+                                  np.asarray(tr_k.routing_weights))
     np.testing.assert_array_equal(np.asarray(tr_v.unstable),
-                                  np.asarray(tr_f.unstable))
-    np.testing.assert_allclose(np.asarray(out["vmap"][0].belief),
-                               np.asarray(out["fused"][0].belief),
-                               atol=1e-5)
-    np.testing.assert_allclose(np.asarray(out["vmap"][0].model.b_counts),
-                               np.asarray(out["fused"][0].model.b_counts),
+                                  np.asarray(tr_k.unstable))
+    np.testing.assert_allclose(np.asarray(ref[0].belief),
+                               np.asarray(dense.belief), atol=1e-5)
+    np.testing.assert_allclose(np.asarray(ref[0].model.b_counts),
+                               np.asarray(dense.model.b_counts),
                                rtol=1e-4, atol=1e-6)
-    np.testing.assert_allclose(np.asarray(out["vmap"][1].n_success),
-                               np.asarray(out["fused"][1].n_success),
-                               rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(ref[1].n_success),
+                               np.asarray(est.n_success), rtol=1e-5)
 
 
 # ------------------------------------------------- slow-step execution count
-@pytest.mark.parametrize("fused", [False, True], ids=["vmap", "fused"])
-def test_slow_step_executes_once_per_period(fused, monkeypatch):
+@pytest.mark.parametrize("mega", [False, True], ids=["vmap", "mega"])
+def test_slow_step_executes_once_per_period(mega, monkeypatch):
     """Runtime call-count trace: the rollout's slow learning path must fire
     n_steps // period times (once per slow period), not once per tick."""
     calls = []
-    orig = fleet._slow_learn
+    mod, name = (mega_core, "mega_slow_step") if mega else (fleet,
+                                                              "_slow_learn")
+    orig = getattr(mod, name)
 
-    def counting(state, keys, cfg):
+    def counting(*args, **kw):
         jax.debug.callback(lambda: calls.append(1))
-        return orig(state, keys, cfg)
+        return orig(*args, **kw)
 
-    monkeypatch.setattr(fleet, "_slow_learn", counting)
+    monkeypatch.setattr(mod, name, counting)
     topo = default_topology()
     r, t = 2, 25                           # 2 slow periods + 5-tick remainder
     cfg, params, env_step, disc = _fleet_world(topo, r, t)
-    ast, _, _ = _rollout(cfg, params, env_step, disc, r, t, fused=fused)
+    if mega:
+        ast, _, _ = api.rollout(
+            api.AifRouter(cfg=cfg, disc=disc, mega=True), None,
+            batched.init_fluid_state(params), env_step, t,
+            jax.random.key(11))
+        a_counts = ast.a_counts
+    else:
+        ast, _, _ = _rollout(cfg, params, env_step, disc, r, t)
+        a_counts = ast.model.a_counts
     jax.block_until_ready(ast)
     jax.effects_barrier()
     period = int(cfg.slow_period_s / cfg.fast_period_s)
     assert len(calls) == t // period == 2
     # ...and learning really happened on those boundaries
     init = fleet.init_fleet_state(cfg, r)
-    assert float(jnp.sum(ast.model.a_counts)) > float(
-        jnp.sum(init.model.a_counts))
+    assert float(jnp.sum(a_counts)) > float(jnp.sum(init.model.a_counts))
 
 
 # --------------------------------------------------- held-tick equivalence
-@pytest.mark.parametrize("fused", [False, True], ids=["vmap", "fused"])
-def test_light_step_matches_fast_step_on_held_ticks(fused):
+def test_light_step_matches_fast_step_on_held_ticks():
     """On a tick with t % dwell != 0 the sampled action is discarded, so
     skipping the EFE evaluation (fleet_light_step) must evolve the state
     exactly like the full fast step — the invariant behind the rollout's
@@ -212,14 +167,12 @@ def test_light_step_matches_fast_step_on_held_ticks(fused):
     # advance off the dwell cadence (t=0 -> 2 ticks -> t=2, 2 % 5 != 0)
     for step in range(2):
         keys = jax.random.split(jax.random.key(step), n)
-        state, _ = fleet.fleet_tick(state, obs, errs, keys, cfg, fused=fused)
+        state, _ = fleet.fleet_tick(state, obs, errs, keys, cfg)
     assert int(state.t[0]) % int(cfg.action_dwell_s) != 0
 
     keys = jax.random.split(jax.random.key(99), n)
-    s_full, info_full = fleet.fleet_fast_step(state, obs, errs, keys, cfg,
-                                              fused=fused)
-    s_light, info_light = fleet.fleet_light_step(state, obs, errs, cfg,
-                                                 fused=fused)
+    s_full, info_full = fleet.fleet_fast_step(state, obs, errs, keys, cfg)
+    s_light, info_light = fleet.fleet_light_step(state, obs, errs, cfg)
     np.testing.assert_array_equal(np.asarray(info_full.action),
                                   np.asarray(info_light.action))
     for leaf_f, leaf_l in zip(jax.tree_util.tree_leaves(s_full),

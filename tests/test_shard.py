@@ -136,8 +136,7 @@ def test_four_device_parity(router, scenario):
     """Reduced metrics are invariant to the device count (±1e-5): the same
     experiment on a 1-way and a 4-way mesh, plus the unsharded reference
     for everything the final env state determines."""
-    kw = dict(router=router, scenario=scenario, n_cells=R, n_windows=T,
-              fused=(router == "aif"))
+    kw = dict(router=router, scenario=scenario, n_cells=R, n_windows=T)
     r0 = api.run(api.Experiment(**kw))
     r1 = api.run(api.Experiment(**kw, shard=api.ShardSpec(devices=1)))
     r4 = api.run(api.Experiment(**kw, shard=api.ShardSpec(devices=4)))

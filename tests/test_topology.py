@@ -1,18 +1,16 @@
-"""Topology-parameterized core: generated policy sets, cross-K kernel parity,
-golden bit-compatibility of the default (paper) topology, K=5 end-to-end."""
+"""Topology-parameterized core: generated policy sets, golden rollout pins of
+the default (paper) topology, K=5 end-to-end."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro import api, core
-from repro.core import efe as core_efe
-from repro.core import fleet, generative, policies, spaces
+from repro.core import fleet, generative, policies
 from repro.core.topology import (PolicySpec, Topology, default_topology,
                                  five_tier_topology, get_topology)
 from repro.envsim import (SimConfig, batched, discretization_for, scenarios,
                           sim_config_for)
-from repro.kernels.efe.ops import fleet_efe
 
 # The paper's hand-written 20-policy table (§4.1) — the pinned regression
 # target for the K=3 generator.
@@ -82,67 +80,6 @@ def test_topology_registry_and_validation():
         Topology(tier_classes=("server",))   # length mismatch
 
 
-# ----------------------------------------------------- cross-K kernel parity
-@pytest.mark.parametrize("topo", [_topo_k2(), default_topology(),
-                                  five_tier_topology()],
-                         ids=["k2", "k3", "k5"])
-@pytest.mark.parametrize("r", [3, 5])   # odd fleet sizes on purpose
-def test_efe_kernel_parity_across_topologies(topo, r):
-    """Pallas(interpret) vs jnp oracle vs single-agent core EFE, any K."""
-    cfg = generative.AifConfig(topology=topo)
-    s, a = topo.n_states, policies.n_actions(topo)
-    m, nb = topo.n_modalities, topo.max_bins
-    ks = jax.random.split(jax.random.key(topo.n_tiers), 3)
-    a_counts = (jax.random.uniform(ks[0], (r, m, nb, s), minval=0.1,
-                                   maxval=2.0)
-                * spaces.bins_mask(topo)[None, :, :, None])
-    b_counts = jax.random.uniform(ks[1], (r, a, s, s), minval=0.01,
-                                  maxval=1.0)
-    c_log = jnp.tile(generative.nominal_c_log(cfg)[None], (r, 1, 1))
-    q = jax.random.dirichlet(ks[2], jnp.ones(s), (r,))
-
-    g_pal = fleet_efe(a_counts, b_counts, c_log, q, cfg, use_pallas=True,
-                      interpret=True)
-    g_ref = fleet_efe(a_counts, b_counts, c_log, q, cfg, use_pallas=False)
-    assert g_pal.shape == (r, a)
-    np.testing.assert_allclose(np.asarray(g_pal), np.asarray(g_ref),
-                               atol=1e-4)
-    model = generative.GenerativeModel(a_counts=a_counts[0],
-                                       b_counts=b_counts[0],
-                                       c_log=c_log[0],
-                                       d_prior=jnp.ones(s) / s)
-    bd = core_efe.expected_free_energy(model, q[0], cfg)
-    np.testing.assert_allclose(np.asarray(g_ref[0]), np.asarray(bd.g),
-                               atol=1e-4)
-
-
-def test_block_size_fallback_pow2_divisor():
-    """Odd / prime R run through the full wrapper: the fleet axis is padded
-    to whole 8-row router blocks (the TPU sublane tiling) and the padded
-    rows are cropped, and a block that is not a multiple of 8 is refused."""
-    topo = default_topology()
-    cfg = generative.AifConfig()
-    s, a = topo.n_states, policies.n_actions(topo)
-    m, nb = topo.n_modalities, topo.max_bins
-    for r in (1, 7, 13):   # prime fleet sizes through the full wrapper
-        key = jax.random.key(r)
-        a_counts = (jax.random.uniform(key, (r, m, nb, s)) + 0.1
-                    ) * spaces.bins_mask(topo)[None, :, :, None]
-        b_counts = jax.random.uniform(jax.random.fold_in(key, 1),
-                                      (r, a, s, s)) + 0.01
-        c_log = jnp.tile(generative.nominal_c_log(cfg)[None], (r, 1, 1))
-        q = jnp.ones((r, s)) / s
-        g_pal = fleet_efe(a_counts, b_counts, c_log, q, cfg,
-                          use_pallas=True, interpret=True, block_r=8)
-        g_ref = fleet_efe(a_counts, b_counts, c_log, q, cfg,
-                          use_pallas=False)
-        np.testing.assert_allclose(np.asarray(g_pal), np.asarray(g_ref),
-                                   atol=1e-4)
-    with pytest.raises(ValueError, match="multiple of 8"):
-        fleet_efe(a_counts, b_counts, c_log, q, cfg, use_pallas=True,
-                  interpret=True, block_r=4)
-
-
 # -------------------------------------------------- golden bit-compatibility
 def _dwell_blocks(blocks):
     """Expand one action triple per 5-tick dwell block to the (T, R) trace."""
@@ -204,8 +141,7 @@ def test_golden_fleet_rollout_paper_burst(threefry):
 
 # ----------------------------------------------------------- K=5 end-to-end
 def test_five_tier_fleet_rollout_end_to_end():
-    """K=5 topology through fleet_rollout + batched env + fused EFE kernel
-    (interpret mode), odd fleet size; fused matches the vmapped path."""
+    """K=5 topology through fleet_rollout + batched env, odd fleet size."""
     topo = five_tier_topology()
     cfg = core.AifConfig(topology=topo)
     scfg = sim_config_for(topo)
@@ -218,21 +154,14 @@ def test_five_tier_fleet_rollout_end_to_end():
                                      jnp.asarray(sc.hazard_scale))
     disc = discretization_for(scfg)   # rps edges rescaled to the K=5 load
     assert disc.rps_edges[0] < scfg.rps < disc.rps_edges[1]
-    outs = {}
-    for name, kw in (("vmap", {}),
-                     ("fused", dict(fused=True, use_pallas=True))):
-        ast, est, trace = fleet.fleet_rollout(
-            fleet.init_fleet_state(cfg, r), batched.init_fluid_state(params),
-            env_step, t, jax.random.key(7), cfg, disc=disc, **kw)
-        assert trace.routing_weights.shape == (t, r, 5)
-        acts = np.asarray(trace.actions)
-        assert acts.min() >= 0 and acts.max() < policies.n_actions(topo)
-        res = batched.summarize(est, trace.env)
-        assert np.all(res.n_requests > 0)
-        outs[name] = (acts, np.asarray(est.n_success))
-    # the fused fleet-kernel path is the same math as the vmapped reference
-    np.testing.assert_array_equal(outs["vmap"][0], outs["fused"][0])
-    np.testing.assert_allclose(outs["vmap"][1], outs["fused"][1], rtol=1e-4)
+    ast, est, trace = fleet.fleet_rollout(
+        fleet.init_fleet_state(cfg, r), batched.init_fluid_state(params),
+        env_step, t, jax.random.key(7), cfg, disc=disc)
+    assert trace.routing_weights.shape == (t, r, 5)
+    acts = np.asarray(trace.actions)
+    assert acts.min() >= 0 and acts.max() < policies.n_actions(topo)
+    res = batched.summarize(est, trace.env)
+    assert np.all(res.n_requests > 0)
 
 
 def test_hetero_fleet_rollout_static_sharding():

@@ -1,10 +1,7 @@
 """Unreliable-telemetry closed loop: masked partial observability end-to-end.
 
-Pins the PR's invariants:
+Pins the invariants:
 
-* masked Pallas kernels match their XLA oracle twins ≤ 1e-4 for K∈{2,3,5}
-  topologies and odd fleet sizes, in both separate-EFE and fused
-  (belief→EFE) modes,
 * an all-ones mask schedule is equal to the unmasked rollout (and the
   unmasked rollout itself is pinned bit-exactly by the golden test in
   test_topology.py),
@@ -14,126 +11,22 @@ Pins the PR's invariants:
   ``restart_blackout``,
 * under the ``flaky-telemetry`` preset (≥30% modality dropout) the closed
   loop stays finite — no NaN/collapsed-belief ticks — and degrades
-  gracefully vs the clean run.
+  gracefully vs the clean run,
+
+on the per-tick reference engine and on the mega engine.  (The masked
+megakernel is compared with its oracle in ``tests/test_mega_window_k*.py``.)
 """
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro import core
+from repro import api, core
 from repro.core import agent as agent_mod
 from repro.core import belief as belief_mod
-from repro.core import fleet, generative, learning, policies, spaces
-from repro.core.topology import Topology, default_topology, five_tier_topology
+from repro.core import fleet, generative, learning, spaces
+from repro.core.topology import default_topology
 from repro.envsim import SimConfig, batched, scenarios
-from repro.kernels.efe import ops as efe_ops
-
-
-def _topo_k2() -> Topology:
-    return Topology(tier_names=("edge", "cloud"),
-                    tier_classes=("edge-light", "server"))
-
-
-def _random_fleet_model(topo, r, seed):
-    """Random batched counts + derived cache tensors for kernel parity."""
-    cfg = generative.AifConfig(topology=topo)
-    s, a = topo.n_states, policies.n_actions(topo)
-    m, nb = topo.n_modalities, topo.max_bins
-    ks = jax.random.split(jax.random.key(seed), 6)
-    a_counts = (jax.random.uniform(ks[0], (r, m, nb, s), minval=0.1,
-                                   maxval=2.0)
-                * spaces.bins_mask(topo)[None, :, :, None])
-    b_counts = jax.random.uniform(ks[1], (r, a, s, s), minval=0.01,
-                                  maxval=1.0)
-    c_log = jnp.tile(generative.nominal_c_log(cfg)[None], (r, 1, 1))
-    q = jax.random.dirichlet(ks[2], jnp.ones(s), (r,))
-    obs = jax.random.randint(ks[3], (r, m), 0, 2)
-    prev = jax.random.randint(ks[4], (r,), 0, a)
-    # random but non-degenerate mask: at least ~half the entries valid
-    mask = (jax.random.uniform(ks[5], (r, m)) > 0.4).astype(jnp.float32)
-    return cfg, a_counts, b_counts, c_log, q, obs, prev, mask
-
-
-# ------------------------------------------------- masked kernel parity
-@pytest.mark.parametrize("topo", [_topo_k2(), default_topology(),
-                                  five_tier_topology()],
-                         ids=["k2", "k3", "k5"])
-@pytest.mark.parametrize("r", [3, 5])   # odd fleet sizes on purpose
-def test_masked_efe_kernel_parity(topo, r):
-    """Separate mode: masked Pallas(interpret) vs masked XLA oracle vs the
-    mask-aware single-agent core EFE."""
-    cfg, a_counts, b_counts, c_log, q, _, _, mask = _random_fleet_model(
-        topo, r, seed=topo.n_tiers)
-    g_pal = efe_ops.fleet_efe(a_counts, b_counts, c_log, q, cfg,
-                              obs_mask=mask, use_pallas=True, interpret=True)
-    g_ref = efe_ops.fleet_efe(a_counts, b_counts, c_log, q, cfg,
-                              obs_mask=mask, use_pallas=False)
-    assert g_pal.shape == (r, policies.n_actions(topo))
-    assert np.all(np.isfinite(np.asarray(g_pal)))
-    np.testing.assert_allclose(np.asarray(g_pal), np.asarray(g_ref),
-                               atol=1e-4)
-    # the mask changes G (a fully-masked fleet would see only Cost)
-    g_unmasked = efe_ops.fleet_efe(a_counts, b_counts, c_log, q, cfg,
-                                   use_pallas=False)
-    assert not np.allclose(np.asarray(g_ref), np.asarray(g_unmasked))
-    # single-agent mask-aware oracle agrees
-    model = generative.GenerativeModel(a_counts=a_counts[0],
-                                       b_counts=b_counts[0],
-                                       c_log=c_log[0],
-                                       d_prior=jnp.ones(topo.n_states)
-                                       / topo.n_states)
-    bd = core.expected_free_energy(model, q[0], cfg, obs_mask=mask[0])
-    np.testing.assert_allclose(np.asarray(g_ref[0]), np.asarray(bd.g),
-                               atol=1e-4)
-
-
-@pytest.mark.parametrize("topo", [_topo_k2(), default_topology(),
-                                  five_tier_topology()],
-                         ids=["k2", "k3", "k5"])
-@pytest.mark.parametrize("r", [3, 4])   # odd fleet size on purpose
-def test_masked_fused_kernel_parity(topo, r):
-    """Fused mode: masked belief→EFE Pallas(interpret) vs the oracle twin,
-    and the posterior vs the mask-aware single-agent update_belief."""
-    cfg, a_counts, b_counts, c_log, q, obs, prev, mask = _random_fleet_model(
-        topo, r, seed=10 + topo.n_tiers)
-    caches = [generative.derive_cache(
-        generative.GenerativeModel(a_counts=a_counts[i], b_counts=b_counts[i],
-                                   c_log=c_log[i],
-                                   d_prior=jnp.ones(topo.n_states)
-                                   / topo.n_states),
-        topo) for i in range(r)]
-    nb = jnp.stack([c.nb for c in caches])
-    na = jnp.stack([c.na for c in caches])
-    amb_m = jnp.stack([c.amb_m for c in caches])
-    logc = jnp.stack([generative.masked_log_c(c_log[i], topo)
-                      for i in range(r)])
-    # mask enters the evidence (loglik) and the effective ambiguity
-    loglik = belief_mod.log_likelihood_from_normalized(na, obs, mask)
-    amb_eff = generative.masked_ambiguity(amb_m, mask)
-
-    g_ref, q_ref = efe_ops.fleet_belief_efe(
-        nb, na, logc, amb_eff, q, prev, loglik, cfg, obs_mask=mask,
-        use_pallas=False)
-    g_pal, q_pal = efe_ops.fleet_belief_efe(
-        nb, na, logc, amb_eff, q, prev, loglik, cfg, obs_mask=mask,
-        use_pallas=True, interpret=True)
-    assert np.all(np.isfinite(np.asarray(g_pal)))
-    np.testing.assert_allclose(np.asarray(g_pal), np.asarray(g_ref),
-                               atol=1e-4)
-    np.testing.assert_allclose(np.asarray(q_pal), np.asarray(q_ref),
-                               atol=1e-5)
-    # oracle posterior == the mask-aware cached single-agent belief update
-    model = generative.GenerativeModel(a_counts=a_counts[0],
-                                       b_counts=b_counts[0], c_log=c_log[0],
-                                       d_prior=jnp.ones(topo.n_states)
-                                       / topo.n_states)
-    for i in range(r):
-        q_single = belief_mod.update_belief(model, q[i], prev[i], obs[i],
-                                            topo, cache=caches[i],
-                                            obs_mask=mask[i])
-        np.testing.assert_allclose(np.asarray(q_ref[i]),
-                                   np.asarray(q_single), atol=1e-6)
 
 
 # --------------------------------------------------- masked belief semantics
@@ -284,8 +177,20 @@ def test_restart_blackout_couples_mask_to_liveness():
 
 
 # --------------------------------------------------- rollout-level invariants
-@pytest.mark.parametrize("fused", [False, True], ids=["vmap", "fused"])
-def test_all_ones_mask_rollout_equals_unmasked(fused):
+def _rollout(mega, cfg, r, params, env_step, t, key, **kw):
+    """A closed-loop rollout on the per-tick reference engine (the
+    deprecated shim, which the golden pin covers) or on the mega engine."""
+    if mega:
+        return api.rollout(api.AifRouter(cfg=cfg, mega=True), None,
+                           batched.init_fluid_state(params), env_step, t, key,
+                           **kw)
+    return fleet.fleet_rollout(
+        fleet.init_fleet_state(cfg, r), batched.init_fluid_state(params),
+        env_step, t, key, cfg, **kw)
+
+
+@pytest.mark.parametrize("mega", [False, True], ids=["vmap", "mega"])
+def test_all_ones_mask_rollout_equals_unmasked(mega):
     """A degradation schedule of all ones must reproduce the mask-free
     rollout exactly: same actions, same success counters, obs_frac == 1."""
     scfg = SimConfig()
@@ -300,20 +205,16 @@ def test_all_ones_mask_rollout_equals_unmasked(fused):
             params, jnp.asarray(sc.arrival_rate),
             jnp.asarray(sc.hazard_scale), obs_valid=ov)
         assert env_step.emits_mask == (ov is not None)
-        ast, est, trace = fleet.fleet_rollout(
-            fleet.init_fleet_state(cfg, r), batched.init_fluid_state(params),
-            env_step, t, jax.random.key(42), cfg, fused=fused)
-        outs[name] = (ast, est, trace)
+        outs[name] = _rollout(mega, cfg, r, params, env_step, t,
+                              jax.random.key(42))
     # explicit override: a wrapped closure losing the emits_mask attribute
     # can still opt in via obs_masked=True (same program as auto-detect)
     env_wrapped = batched.make_env_step(
         params, jnp.asarray(sc.arrival_rate), jnp.asarray(sc.hazard_scale),
         obs_valid=np.ones((t, r, 4), np.float32))
     del env_wrapped.emits_mask
-    ast_w, est_w, tr_w = fleet.fleet_rollout(
-        fleet.init_fleet_state(cfg, r), batched.init_fluid_state(params),
-        env_wrapped, t, jax.random.key(42), cfg, fused=fused,
-        obs_masked=True)
+    ast_w, est_w, tr_w = _rollout(mega, cfg, r, params, env_wrapped, t,
+                                  jax.random.key(42), obs_masked=True)
     tr_c, tr_o = outs["clean"][2], outs["ones"][2]
     np.testing.assert_array_equal(np.asarray(tr_w.actions),
                                   np.asarray(tr_o.actions))
@@ -328,8 +229,8 @@ def test_all_ones_mask_rollout_equals_unmasked(fused):
     np.testing.assert_array_equal(np.asarray(tr_o.obs_frac), 1.0)
 
 
-@pytest.mark.parametrize("fused", [False, True], ids=["vmap", "fused"])
-def test_flaky_telemetry_rollout_stays_finite_and_degrades_gracefully(fused):
+@pytest.mark.parametrize("mega", [False, True], ids=["vmap", "mega"])
+def test_flaky_telemetry_rollout_stays_finite_and_degrades_gracefully(mega):
     """The acceptance scenario: ≥30% modality dropout through the whole
     closed loop — finite beliefs, no collapsed posteriors, sane success."""
     r, t = 3, 45
@@ -337,9 +238,8 @@ def test_flaky_telemetry_rollout_stays_finite_and_degrades_gracefully(fused):
     sc, params, env_step = _world("flaky-telemetry", r, t, seed=3)
     assert sc.obs_valid is not None
     assert 1.0 - sc.obs_valid.mean() >= 0.30
-    ast, est, trace = fleet.fleet_rollout(
-        fleet.init_fleet_state(cfg, r), batched.init_fluid_state(params),
-        env_step, t, jax.random.key(7), cfg, fused=fused)
+    ast, est, trace = _rollout(mega, cfg, r, params, env_step, t,
+                               jax.random.key(7))
     # finite, normalized beliefs at the end; no NaN anywhere in the trace
     beliefs = np.asarray(ast.belief)
     assert np.all(np.isfinite(beliefs))
@@ -356,9 +256,8 @@ def test_flaky_telemetry_rollout_stays_finite_and_degrades_gracefully(fused):
     assert np.all(res.success_rate > 0.3)
     # degradation is graceful: within 25pp of the clean run's success
     _, params_c, env_c = _world("paper-burst", r, t)
-    _, est_c, trace_c = fleet.fleet_rollout(
-        fleet.init_fleet_state(cfg, r), batched.init_fluid_state(params_c),
-        env_c, t, jax.random.key(7), cfg, fused=fused)
+    _, est_c, trace_c = _rollout(mega, cfg, r, params_c, env_c, t,
+                                 jax.random.key(7))
     res_c = batched.summarize(est_c, trace_c.env)
     gap = res_c.success_rate.mean() - res.success_rate.mean()
     assert gap < 0.25
