@@ -53,6 +53,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from repro.core import agent as agent_mod
 from repro.core import belief as belief_mod
@@ -90,6 +91,23 @@ class MegaSlots(NamedTuple):
     action: jnp.ndarray           # (R, J) int32 action in force at the tick
     dt_since_change: jnp.ndarray  # (R, J) float32 dwell age at the tick
     wcount: jnp.ndarray           # (R, J) float32 times sampled by slow steps
+
+
+def row_major_tapes(slots: MegaSlots) -> MegaSlots:
+    """State the ``q_prev``/``q_next`` tapes row-major, (R, J, S), as the
+    megakernel reads them.
+
+    XLA picks the layout of the rollout loop's tape carry from the ops that
+    touch it.  Left to itself at a long tape (J = 3,600), it lays out the
+    per-cell select of :func:`mega_quarantine` slot-minor, the carry
+    follows, and every window then copies both whole tapes row-major for
+    the kernel.  Stated on the quarantine's output, the carry stays
+    row-major, as XLA already keeps it at J = 600, and no window copies a
+    tape.
+    """
+    q_prev, q_next = with_layout_constraint((slots.q_prev, slots.q_next),
+                                            Layout((0, 1, 2)))
+    return slots._replace(q_prev=q_prev, q_next=q_next)
 
 
 class MegaCache(NamedTuple):
@@ -718,7 +736,7 @@ def mega_quarantine(state: MegaFleetState, bad: jnp.ndarray,
                           state.a_counts.shape)
     a_counts = where_r(a0, state.a_counts)
     sl = state.slots
-    slots = MegaSlots(
+    slots = row_major_tapes(MegaSlots(
         q_prev=where_r(0.0, sl.q_prev),
         q_next=where_r(0.0, sl.q_next),
         obs_bins=where_r(0, sl.obs_bins),
@@ -726,7 +744,7 @@ def mega_quarantine(state: MegaFleetState, bad: jnp.ndarray,
         action=where_r(0, sl.action),
         dt_since_change=where_r(0.0, sl.dt_since_change),
         wcount=where_r(0.0, sl.wcount),
-    )
+    ))
     if state.cache.b_base is None:
         b_base = None
     else:

@@ -48,7 +48,11 @@ last router (every op is row-local; padded rows are cropped) — per-window
 schedules and traces are (W, ..., R, trailing) with the small axes leading,
 and per-router scalars are (BR, 1) columns.  The state axis keeps its true
 width S (243 for the paper topology; Mosaic masks the partial lane tile),
-so the slot tape is read in place, never padded or copied.
+so the slot tape is read in place, never padded.  The kernel reads the
+``q_prev``/``q_next`` tapes row-major; the rollout loop carries them so
+because :func:`repro.core.mega.row_major_tapes` states that layout.  Without
+it XLA keeps a long tape (J = 3,600) slot-minor in the loop's carry and
+copies both tapes row-major for the kernel every window.
 
 Slot tape and horizon: the tape (``q_prev``, ``q_next``, ``qnproj|sumqn``
 and ``coefact``, J slots each) is folded in :data:`SLOT_CHUNK`-slot chunks.

@@ -211,6 +211,44 @@ def test_mega_watchdog_quarantine_unit():
     np.testing.assert_array_equal(np.asarray(healed.t), np.asarray(state.t))
 
 
+@pytest.mark.parametrize("use_pallas", [False, True],
+                         ids=["oracle", "kernel-interpret"])
+def test_mega_watchdog_quarantines_inside_the_rollout(use_pallas):
+    """The window loop's watchdog heals a cell that enters the rollout
+    diverged: the first window's event names that cell alone, the cell
+    ends finite, and every other cell's actions and slots are a clean
+    run's, bit for bit."""
+    params, env_step = _world("paper-burst", t=20)
+    cfg = generative.AifConfig()
+    router = api.AifRouter(cfg=cfg, mega=True, use_pallas=use_pallas)
+
+    def run(state_in):
+        state, _, trace, _ = engine._mega_rollout(
+            router, None, batched.init_fluid_state(params), env_step, 20,
+            jax.random.key(7), obs_masked=None, t0=None, state_in=state_in)
+        return state, trace
+
+    clean_state, clean = run(mega_mod.init_mega_state(cfg, R, 20))
+    poisoned = mega_mod.init_mega_state(cfg, R, 20)
+    healed_state, healed = run(
+        poisoned._replace(belief=poisoned.belief.at[1].set(jnp.nan)))
+
+    events = np.zeros((20, R), np.float32)
+    events[router.period - 1, 1] = 1.0
+    np.testing.assert_array_equal(np.asarray(healed.watchdog), events)
+    assert not np.asarray(clean.watchdog).any()
+    for leaf in jax.tree_util.tree_leaves(
+            (healed_state.belief, healed_state.a_counts,
+             healed_state.slots)):
+        assert np.isfinite(np.asarray(leaf)[1]).all()
+    keep = [0, 2, 3]
+    np.testing.assert_array_equal(np.asarray(healed.actions)[:, keep],
+                                  np.asarray(clean.actions)[:, keep])
+    for a, b in zip(healed_state.slots, clean_state.slots):
+        np.testing.assert_array_equal(np.asarray(a)[keep],
+                                      np.asarray(b)[keep])
+
+
 # ----------------------------------------------------- stop/resume bit-parity
 def test_resume_bit_identical_per_tick():
     params, env_step = _world("zone-outage")

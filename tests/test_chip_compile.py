@@ -8,6 +8,7 @@ widths (S=243, A=20) and at deployment fleet sizes.  Nothing runs; the
 numbers are checked on the chip by ``chip_smoke.py``.
 """
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -163,10 +164,65 @@ def test_mega_rollout_fits_one_chip(mega_rollout):
     _assert_fits_one_chip(mega_rollout)
 
 
-def test_hour_long_mega_rollout_fits_one_chip(one_chip):
-    """The R=256 x T=3600 rollout (one hour of 1 s windows, a tape past what
-    VMEM holds) compiles with the kernel and fits one chip's HBM."""
-    _assert_fits_one_chip(_compile_mega_rollout(one_chip, 256, 3600))
+@pytest.fixture(scope="module")
+def hour_long_rollout(one_chip):
+    """The R=256 x T=3600 mega rollout program (one hour of 1 s windows, a
+    tape past what VMEM holds), compiled for one chip."""
+    return _compile_mega_rollout(one_chip, 256, 3600)
+
+
+def test_hour_long_mega_rollout_fits_one_chip(hour_long_rollout):
+    """The hour-long rollout compiles with the kernel and fits one chip's
+    HBM."""
+    _assert_fits_one_chip(hour_long_rollout)
+
+
+_CALLEES = re.compile(
+    r"\b(?:calls|to_apply|body|condition)=(\{[^}]*\}|%?[\w.\-]+)")
+
+
+def _loop_tape_copies(compiled, r, j):
+    """The ``copy`` ops of a whole slot tape, f32[r, j, 243], that run on
+    every window: those in the body of the scan that launches the kernel
+    and in what it calls, leaving out the branches of a ``conditional``
+    (the watchdog's quarantine, taken only when a cell diverges).  The
+    copies at the program's entry and exit run once a call."""
+    comps, name = {}, None
+    for line in compiled.as_text().splitlines():
+        head = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$", line)
+        if head:
+            name = head.group(1)
+            comps[name] = []
+        elif name is not None:
+            comps[name].append(line)
+    bodies = [re.search(r"\bbody=%?([\w.\-]+)", ln).group(1)
+              for lines in comps.values() for ln in lines if " while(" in ln]
+    todo = [b for b in bodies
+            if any("tpu_custom_call" in ln for ln in comps[b])]
+    assert len(todo) == 1, bodies
+    seen = set()
+    while todo:
+        c = todo.pop()
+        if c not in seen:
+            seen.add(c)
+            todo += [n.lstrip("%") for ln in comps[c]
+                     for m in _CALLEES.finditer(ln)
+                     for n in re.findall(r"%?[\w.\-]+", m.group(1))]
+    tape = re.compile(rf"%\S+ = f32\[{r},{j},243\]\{{[^}}]*\}} copy\(")
+    return [m.group(0) for c in seen for ln in comps[c]
+            for m in [tape.search(ln)] if m]
+
+
+@pytest.mark.parametrize("rollout,r,t", [
+    ("mega_rollout", 2048, 600), ("hour_long_rollout", 256, 3600)],
+    ids=["2048-600", "256-3600"])
+def test_mega_rollout_loop_copies_no_slot_tape(request, rollout, r, t):
+    """The rollout's window loop keeps the ``q_prev``/``q_next`` tapes
+    row-major, as the megakernel reads them, so no window copies a whole
+    tape; at T=3600 XLA would otherwise lay the carry out slot-minor and
+    turn both tapes around every window."""
+    copies = _loop_tape_copies(request.getfixturevalue(rollout), r, t)
+    assert not copies, copies
 
 
 def test_mega_rollout_names_its_kernel_and_scopes(mega_rollout):
